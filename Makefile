@@ -2,7 +2,7 @@
 # to what a single-language-core framework needs).
 PY ?= python
 
-.PHONY: ci test test-all test-dist test-parity lint bench cpp docs clean opperf-check telemetry-smoke health-smoke chaos-smoke serve-smoke fleet-smoke procfleet-smoke kernels-smoke elastic-smoke export-smoke data-smoke trace-smoke quant-smoke spec-smoke disagg-smoke obsplane-smoke replay-smoke qos-smoke perf-gate
+.PHONY: ci test test-all test-dist test-parity lint bench cpp docs clean opperf-check telemetry-smoke health-smoke chaos-smoke serve-smoke fleet-smoke procfleet-smoke kernels-smoke elastic-smoke export-smoke data-smoke trace-smoke quant-smoke spec-smoke disagg-smoke obsplane-smoke replay-smoke qos-smoke chip-smoke
 
 # the one-command gate CI runs (VERDICT round-2 next-step #7): lint +
 # unit suite + 2-process dist tests + C++ package build/tests
@@ -196,12 +196,13 @@ quant-smoke:
 spec-smoke:
 	$(PY) tools/spec_smoke.py
 
-# CPU-bench regression tripwire (ROADMAP item 5): median-of-3
-# `bench.py --measure cpu` runs must stay within 15% of the checked-in
-# budget (bench_results/cpu_budget.json); re-baseline deliberately with
-# `python tools/perf_gate.py --rebaseline`
-perf-gate:
-	$(PY) tools/perf_gate.py
+# the on-chip bring-up proof (needs a TPU; exits non-zero naming the
+# platform anywhere else): BERT-base train steps + GPT-2-small serving
+# through their normal entry points in ONE process, kernels asserted in
+# the compiled HLO.  Not part of `make test` — the chip is reached only
+# through the chip tool (README "How it runs")
+chip-smoke:
+	$(PY) chip_smoke.py
 
 cpp:
 	cmake -S cpp-package -B cpp-package/build && \

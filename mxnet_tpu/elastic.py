@@ -7,7 +7,7 @@ On TPU the failure model is different and simpler to cover:
 * **preemption** — Cloud TPU sends SIGTERM with a grace window; the right
   response is save-and-exit, then the scheduler restarts the job and it
   resumes from the newest checkpoint.
-* **transient runtime errors** — tunnel/network hiccups or collective
+* **transient runtime errors** — network hiccups or collective
   timeouts surface as ``RuntimeError`` / ``MXNetError`` at the sync point
   (XLA's async dispatch defers errors, like the reference engine's
   exception propagation, `src/engine/threaded_engine.h:67`). Recovery is
@@ -342,7 +342,7 @@ def sync_flag(flag: bool) -> bool:
     preemption notice on one host checkpoints every host at the same step.
     Single-process: identity.
 
-    Failure mode (multi-host): a transient collective error (tunnel reset,
+    Failure mode (multi-host): a transient collective error (connection reset,
     coordination-service hiccup) is retried with backoff
     (`resilience.retry_with_backoff`); once the budget is exhausted the
     hosts can no longer agree on a common step, so this raises

@@ -49,15 +49,15 @@ _PARAMS = "params.npz"
 
 def export_dir() -> Optional[str]:
     """Resolve the artifact store: ``MXTPU_EXPORT_DIR``, else an
-    ``export/`` subdirectory of ``MXTPU_COMPILE_CACHE`` (artifacts live
-    next to the compiled binaries they warm), else None."""
+    ``export/`` subdirectory of the active compile cache
+    (`runtime.compile_cache_dir` — artifacts live next to the compiled
+    binaries they warm), else None."""
     d = os.environ.get("MXTPU_EXPORT_DIR")
     if d:
         return d
-    cc = os.environ.get("MXTPU_COMPILE_CACHE")
-    if cc:
-        return os.path.join(cc, "export")
-    return None
+    from ..runtime import compile_cache_dir
+    cc = compile_cache_dir()
+    return os.path.join(cc, "export") if cc else None
 
 
 def auto_capture_enabled() -> bool:

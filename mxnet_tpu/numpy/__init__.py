@@ -456,6 +456,9 @@ _DELEGATE = [
 ]
 
 _g = globals()
+# jnp.fix is deprecated in jax 0.9 (removed in 0.10): same rounding
+# toward zero as trunc
+fix = wrap_fn(jnp.trunc, "fix")
 for _name in _DELEGATE:
     if _name in _g:  # don't clobber custom impls
         continue

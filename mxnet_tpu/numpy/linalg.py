@@ -35,17 +35,10 @@ _ALIAS_NAMES = [
 
 _g = globals()
 for _name in _ALIAS_NAMES:
-    _j = getattr(jnp.linalg, _name, None)
-    if _j is not None:
-        _g[_name] = wrap_fn(_j, _name)
+    _g[_name] = wrap_fn(getattr(jnp.linalg, _name), _name)
 
 
-_matrix_transpose_w = _g.get("matrix_transpose")
-if _matrix_transpose_w is None:
-    # older jax without jnp.linalg.matrix_transpose: same semantics as
-    # the array-API definition — swap the last two axes
-    _matrix_transpose_w = wrap_fn(lambda x: jnp.swapaxes(x, -1, -2),
-                                  "matrix_transpose")
+_matrix_transpose_w = _g["matrix_transpose"]
 
 
 def matrix_transpose(x):

@@ -13,9 +13,6 @@ on the same block kernel (`mxnet_tpu/parallel/ring_attention.py`).
 """
 from __future__ import annotations
 
-import functools
-import os
-import warnings
 from typing import Optional
 
 import jax
@@ -98,10 +95,8 @@ def _use_pallas() -> bool:
         return False
     if getenv_bool("MXTPU_PALLAS_INTERPRET", False):
         return True  # kernels run through the Pallas interpreter on CPU
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    from .pallas import partitionable
+    return jax.default_backend() != "cpu" and partitionable()
 
 
 def _mask_to_bias(mask):
@@ -127,12 +122,6 @@ def _seed_from_key(key):
     return jax.lax.bitcast_convert_type(data[-1], jnp.int32)
 
 
-# per-REASON dedup (VERDICT r3 weak #7): a long-lived process that first
-# hits one legitimately-unsupported shape must not silence the warning for
-# every later, different fallback cause
-_warned_fallback_reasons = set()
-
-
 def dot_product_attention(q, k, v, mask=None, causal=False, scale=None,
                           use_flash=True, dropout_rate=0.0, dropout_key=None,
                           window=None, window_symmetric=True):
@@ -147,44 +136,28 @@ def dot_product_attention(q, k, v, mask=None, causal=False, scale=None,
     Grouped-query attention: k/v may carry g < H heads (H % g == 0) — the
     flash kernel streams them at g heads (no HBM expansion); only the XLA
     fallback materialises the repeat.
-    Set MXTPU_FLASH_STRICT=1 to raise instead of silently falling back when
-    the kernel rejects an input.
+    Where the Pallas route is active an error inside the kernel
+    propagates: the only way onto the O(L²) reference from there is
+    `flash_attention`'s own shape-eligibility branch, decided before
+    anything is traced.
     """
     if mask is not None:
         mask = _normalize_mask_4d(mask)
     if k.shape[1] != q.shape[1] and (
             k.shape[1] == 0 or q.shape[1] % k.shape[1]):
-        # validate BEFORE the flash try: an input error must not consume
-        # the one-shot "flash unavailable" warning or masquerade as a
-        # kernel rejection
         raise ValueError(f"query heads ({q.shape[1]}) must be a "
                          f"multiple of kv heads ({k.shape[1]})")
     if use_flash and _use_pallas():
-        try:
-            from .pallas.flash_attention import flash_attention
-            bias = _mask_to_bias(mask) if mask is not None else None
-            seed = None
-            if dropout_rate > 0.0 and dropout_key is not None:
-                seed = _seed_from_key(dropout_key)
-            return flash_attention(q, k, v, causal=causal, scale=scale,
-                                   bias=bias, dropout_rate=dropout_rate
-                                   if seed is not None else 0.0,
-                                   dropout_seed=seed, window=window,
-                                   window_symmetric=window_symmetric)
-        except Exception as e:
-            if getenv_bool("MXTPU_FLASH_STRICT", False):
-                raise
-            # key on type + truncated message: rejection text embedding
-            # per-request shapes must not re-warn per shape or grow the
-            # set unboundedly (cap as a backstop)
-            reason = f"{type(e).__name__}: {str(e)[:80]}"
-            if reason not in _warned_fallback_reasons \
-                    and len(_warned_fallback_reasons) < 32:
-                _warned_fallback_reasons.add(reason)
-                warnings.warn(
-                    f"flash attention unavailable ({reason}); "
-                    "using the XLA reference path. Set MXTPU_FLASH_STRICT=1 "
-                    "to raise instead.")
+        from .pallas.flash_attention import flash_attention
+        bias = _mask_to_bias(mask) if mask is not None else None
+        seed = None
+        if dropout_rate > 0.0 and dropout_key is not None:
+            seed = _seed_from_key(dropout_key)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               bias=bias, dropout_rate=dropout_rate
+                               if seed is not None else 0.0,
+                               dropout_seed=seed, window=window,
+                               window_symmetric=window_symmetric)
     if k.shape[1] != q.shape[1]:   # GQA: the einsum path needs full heads
         from .pallas.flash_attention import _expand_kv
         k, v = _expand_kv(k, v, q.shape[1])

@@ -24,21 +24,58 @@ the CPU tier-1 path and the interpret-mode parity oracle (the
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 
 __all__ = ["pallas_mode", "kernel_active", "interpret_mode",
-           "note_fused_launch", "tpu_compiler_params"]
+           "note_fused_launch", "tpu_compiler_params",
+           "partitioned_by_gspmd", "per_shard", "partitionable"]
+
+_trace_state = threading.local()
+
+
+@contextlib.contextmanager
+def _tracing_under_gspmd(flag: bool):
+    prev = getattr(_trace_state, "gspmd", False)
+    _trace_state.gspmd = flag
+    try:
+        yield
+    finally:
+        _trace_state.gspmd = prev
+
+
+def partitioned_by_gspmd(n_devices: int):
+    """Wrap the TRACE of a program GSPMD will partition over `n_devices`
+    (`ShardedTrainStep` does).  jax refuses to lower a Mosaic kernel
+    there — "Mosaic kernels cannot be automatically partitioned. Please
+    wrap the call in a shard_map" — so over more than one device every
+    auto dispatch in this package takes its jnp reference, which GSPMD
+    partitions like any other op.  Decided here, before anything is
+    traced, and visible as a compiled step with no custom calls."""
+    return _tracing_under_gspmd(n_devices > 1)
+
+
+def per_shard():
+    """Wrap the trace of a `shard_map` body (`shard_map_nocheck` does):
+    arrays there are per-device, so Mosaic kernels lower again."""
+    return _tracing_under_gspmd(False)
+
+
+def partitionable() -> bool:
+    """Can a kernel dispatched right now be lowered?  False while
+    tracing a GSPMD-partitioned program outside any shard_map — unless
+    kernels run through the interpreter, which emits plain ops."""
+    return interpret_mode() or not getattr(_trace_state, "gspmd", False)
 
 
 def tpu_compiler_params(*dimension_semantics: str):
-    """Build TPU compiler params across the jax rename
-    (``TPUCompilerParams`` -> ``CompilerParams``) — every kernel in this
-    package goes through here so one jax bump can't strand half the
-    kernel set on the dead name."""
+    """Mosaic compiler params naming each grid dimension's semantics
+    ("parallel" / "arbitrary") — the one spelling every kernel in this
+    package uses."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))
 
 
 def interpret_mode() -> bool:
@@ -65,17 +102,15 @@ def kernel_active() -> bool:
 
     ``kernel`` forces it; ``auto`` requires an actual TPU backend (see
     the module docstring for why interpret mode deliberately does not
-    count); ``reference``/``off`` never."""
+    count) and a trace the kernel can be lowered in (`partitionable`);
+    ``reference``/``off`` never."""
     mode = pallas_mode()
     if mode == "kernel":
         return True
     if mode in ("reference", "off"):
         return False
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu" and partitionable()
 
 
 def note_fused_launch(op: str) -> None:

@@ -142,6 +142,10 @@ def _hp_scalars(hp, skip):
 
 def _read_hp(refs, has_clip, has_skip):
     lr, wd, rg, t = (r[0, 0] for r in refs[:4])
+    # the rules raise constants to the step count (`beta ** t`); Mosaic
+    # legalizes pow on vectors only, so `t` enters the rule as one
+    # lane-row instead of an SMEM scalar
+    t = jnp.full((1, LANES), t, jnp.float32)
     i = 4
     cg = None
     if has_clip:

@@ -4,12 +4,14 @@ TPU-native serving kernel in the *Ragged Paged Attention* shape (PAPERS.md,
 arxiv 2604.15464): ONE launch handles a mixed continuous-batching step —
 some slots mid-prefill (a chunk of C query tokens), others decoding (one
 query token) — attending over a **paged KV pool**.  The pool stores keys and
-values as fixed-size pages `(num_pages, page_size, Hkv, D)` in HBM; each
-slot's logical context is the concatenation of the pages its page table
-names.  The kernel walks a slot's pages sequentially (online softmax, flash
-style), fetching the physical page via scalar-prefetched page-table indices
-— no (B, L_max, ...) contiguous gather is ever materialised on the TPU
-path.
+values head-major as fixed-size pages `(Hkv, num_pages, page_size, D)` in
+HBM, so one (kv head, page) is a contiguous `(page_size, D)` tile — the
+block Mosaic can DMA (a kv-head axis in the second-minor position cannot
+be squeezed out of a TPU block); each slot's logical context is the
+concatenation of the pages its page table names.  The kernel walks a
+slot's pages sequentially (online softmax, flash style), fetching the
+physical page via scalar-prefetched page-table indices — no
+(B, L_max, ...) contiguous gather is ever materialised on the TPU path.
 
 Grouped-query attention uses the same folding trick as
 `flash_attention.py`: the `rep = H // Hkv` query heads sharing a kv head
@@ -37,23 +39,13 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import interpret_mode, kernel_active
+
 MASK_VALUE = -1e30
 LANES = 128
-_WARNED_FALLBACK = False
 
 __all__ = ["ragged_paged_attention", "paged_attention_reference",
-           "gather_pages", "MASK_VALUE"]
-
-
-def _interpret() -> bool:
-    from ...base import getenv_bool
-    return getenv_bool("MXTPU_PALLAS_INTERPRET", False)
-
-
-def _force_reference() -> bool:
-    import os
-    return os.environ.get("MXTPU_PAGED_ATTENTION", "").strip().lower() \
-        == "reference"
+           "gather_pages", "kernel_tileable", "MASK_VALUE", "LANES"]
 
 
 # ---------------------------------------------------------------------------
@@ -108,19 +100,19 @@ def _dense_attend(q, kc, vc, q_pos, ctx_len=None, window=None, scale=None):
 def gather_pages(pool, page_tables, scales=None):
     """Materialise each slot's logical context from the paged pool.
 
-    pool: (num_pages, page_size, Hkv, D); page_tables: (B, max_pages)
+    pool: (Hkv, num_pages, page_size, D); page_tables: (B, max_pages)
     int32 (unallocated entries may point anywhere — callers mask by
-    ctx_len).  Returns (B, max_pages * page_size, Hkv, D).
+    ctx_len).  Returns (B, Hkv, max_pages * page_size, D).
 
-    `scales` (num_pages, page_size, Hkv) dequantizes an int8 pool on the
+    `scales` (Hkv, num_pages, page_size) dequantizes an int8 pool on the
     fly — only the gathered context is dequantized, never the whole pool.
     """
-    g = pool[page_tables]                       # (B, maxp, ps, Hkv, D)
-    B, maxp, ps, Hkv, D = g.shape
-    g = g.reshape(B, maxp * ps, Hkv, D)
+    g = pool[:, page_tables]                    # (Hkv, B, maxp, ps, D)
+    Hkv, B, maxp, ps, D = g.shape
+    g = g.reshape(Hkv, B, maxp * ps, D).transpose(1, 0, 2, 3)
     if scales is not None:
-        sc = scales[page_tables].reshape(B, maxp * ps, Hkv, 1)
-        g = g.astype(jnp.float32) * sc
+        sc = scales[:, page_tables].reshape(Hkv, B, maxp * ps, 1)
+        g = g.astype(jnp.float32) * sc.transpose(1, 0, 2, 3)
     return g
 
 
@@ -131,14 +123,9 @@ def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
     run `_dense_attend`.  CPU tier-1 path and the kernel's test oracle."""
     B, H, C, D = q.shape
     q_pos = start_pos[:, None] + jnp.arange(C)[None, :]
-    kc = gather_pages(kpool, page_tables, k_scales)
-    vc = gather_pages(vpool, page_tables, v_scales)
     dt = out_dtype or q.dtype
-    kc = kc.astype(dt)
-    vc = vc.astype(dt)
-    # (B, L, Hkv, D) -> (B, Hkv, L, D)
-    kc = kc.transpose(0, 2, 1, 3)
-    vc = vc.transpose(0, 2, 1, 3)
+    kc = gather_pages(kpool, page_tables, k_scales).astype(dt)
+    vc = gather_pages(vpool, page_tables, v_scales).astype(dt)
     return _dense_attend(q.astype(dt), kc, vc, q_pos, ctx_len=ctx_lens,
                          window=window, scale=scale)
 
@@ -147,22 +134,22 @@ def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_rpa_kernel(n_kv_heads, scale, chunk, page_size, window):
-    """Build the kernel body with static head-count/shape parameters.
+def _make_rpa_kernel(scale, chunk, rep, window):
+    """Build the kernel body with static shape parameters.
 
-    One (slot·kv-head, page) grid step: rows are the GQA fold — row r =
+    One (slot, kv-head, page) grid step: rows are the GQA fold — row r =
     (query-head-in-group r // chunk, chunk token r % chunk), so every
     row's query position is ``start + r % chunk``.  Pages walk
     sequentially (innermost grid dim) with flash-style online softmax in
-    VMEM scratch."""
+    VMEM scratch.  All elementwise math is f32 (v5e has no bf16 VPU):
+    q/k/v go to the MXU as stored and accumulate in f32."""
     from jax.experimental import pallas as pl
 
     def kernel(pt_ref, ctx_ref, start_ref, q_ref, k_ref, v_ref,
                o_ref, m_scr, l_scr, acc_scr):
-        bh = pl.program_id(0)
-        pi = pl.program_id(1)
-        n_pages = pl.num_programs(1)
-        b = bh // n_kv_heads
+        b = pl.program_id(0)
+        pi = pl.program_id(2)
+        n_pages = pl.num_programs(2)
 
         rows, d = q_ref.shape
         ps = k_ref.shape[0]
@@ -186,7 +173,9 @@ def _make_rpa_kernel(n_kv_heads, scale, chunk, page_size, window):
             # position pi * ps + j
             r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            qpos = start + r % chunk
+            # (without a GQA fold rows past the chunk are sublane padding
+            # the wrapper slices off, so the modulo is skipped)
+            qpos = start + (r if rep == 1 else jax.lax.rem(r, chunk))
             kpos = pi * ps + c
             keep = (kpos < ctx) & (kpos <= qpos)
             if window is not None:
@@ -230,52 +219,45 @@ def _lanes(x, n):
     return jnp.tile(x, (1, n // LANES))
 
 
-def _compiler_params(pltpu, **kw):
-    """jax renamed TPUCompilerParams -> CompilerParams across versions;
-    accept either so the kernel runs on both sides of the rename."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
-def _rpa_pallas(q, kpool, vpool, page_tables, ctx_lens, start_pos,
+def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
                 window, scale):
-    """Launch the Pallas kernel (shapes pre-validated by the wrapper)."""
+    """Launch the Pallas kernel over the stacked ``(n_layers, Hkv, pages,
+    ps, D)`` pools (shapes pre-validated by the wrapper).  The layer is
+    picked in the K/V index map, so XLA never materialises a per-layer
+    slice of the pool to feed the custom call."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, C, D = q.shape
-    n_pages_pool, ps, Hkv, _ = kpool.shape
+    _, Hkv, _, ps, _ = kpool.shape
     maxp = page_tables.shape[1]
     rep = H // Hkv
     rows = rep * C
 
-    # fold query heads onto rows: (B, H, C, D) -> (B, Hkv, rep*C, D)
-    qf = q.reshape(B, Hkv, rep, C, D).reshape(B, Hkv, rows, D)
-    # pad rows to the sublane minimum so tiny decode batches still tile
-    min_rows = 8
+    # fold query heads onto rows: (B, H, C, D) -> (B, Hkv, rep*C, D),
+    # padded to the dtype's sublane tile so tiny decode batches still tile
+    qf = q.reshape(B, Hkv, rows, D)
+    min_rows = 8 * max(1, 4 // q.dtype.itemsize)
     pad = (-rows) % min_rows
     if pad:
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     rows_p = rows + pad
-    qf = qf.reshape(B * Hkv, rows_p, D)
 
-    kernel = _make_rpa_kernel(Hkv, scale, C, ps, window)
+    def q_map(b, h, pi, pt, ctx, st):
+        return (b, h, 0, 0)
+
+    def kv_map(b, h, pi, pt, ctx, st):
+        return (layer, h, pt[b, pi], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B * Hkv, maxp),
+        grid=(B, Hkv, maxp),
         in_specs=[
-            pl.BlockSpec((None, rows_p, D),
-                         lambda bh, pi, pt, ctx, st: (bh, 0, 0)),
-            pl.BlockSpec((None, ps, None, D),
-                         lambda bh, pi, pt, ctx, st:
-                         (pt[bh // Hkv, pi], 0, bh % Hkv, 0)),
-            pl.BlockSpec((None, ps, None, D),
-                         lambda bh, pi, pt, ctx, st:
-                         (pt[bh // Hkv, pi], 0, bh % Hkv, 0)),
+            pl.BlockSpec((None, None, rows_p, D), q_map),
+            pl.BlockSpec((None, None, None, ps, D), kv_map),
+            pl.BlockSpec((None, None, None, ps, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, rows_p, D),
-                               lambda bh, pi, pt, ctx, st: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, rows_p, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((rows_p, LANES), jnp.float32),
             pltpu.VMEM((rows_p, LANES), jnp.float32),
@@ -283,79 +265,81 @@ def _rpa_pallas(q, kpool, vpool, page_tables, ctx_lens, start_pos,
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        _make_rpa_kernel(scale, C, rep, window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, rows_p, D), q.dtype),
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret_mode(),
+        name="ragged_paged_attention",
     )(page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       start_pos.astype(jnp.int32), qf, kpool, vpool)
-    out = out.reshape(B, Hkv, rows_p, D)[:, :, :rows]
-    return out.reshape(B, Hkv, rep, C, D).reshape(B, H, C, D)
+    return out[:, :, :rows].reshape(B, H, C, D)
+
+
+def kernel_tileable(page_size: int, head_dim: int) -> bool:
+    """Can the kernel tile this pool?  The page is the K/V block's
+    sublane dim (multiple of 8) and the score tile's lane dim; the
+    lane-replicated softmax stats slice (<= LANES) or tile (multiple of
+    LANES) to both the page and the head dim."""
+    def lanes_ok(n):
+        return n <= LANES or n % LANES == 0
+    return page_size % 8 == 0 and lanes_ok(page_size) and lanes_ok(head_dim)
 
 
 def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                            start_pos, window=None, scale=None,
-                           k_scales=None, v_scales=None, use_kernel=None):
+                           k_scales=None, v_scales=None, use_kernel=None,
+                           layer=None):
     """Mixed prefill/decode attention over a paged KV pool — one launch.
 
     q: (B, H, C, D) chunk queries (C = 1 for a pure-decode step);
-    kpool/vpool: (num_pages, page_size, Hkv, D); page_tables:
+    kpool/vpool: (Hkv, num_pages, page_size, D) — or, with ``layer=li``,
+    the stacked (n_layers, Hkv, num_pages, page_size, D) pools of which
+    layer `li` is read (likewise the scale planes); page_tables:
     (B, max_pages) int32 physical-page ids per logical page; ctx_lens:
     (B,) valid context length INCLUDING this chunk's tokens (already
     written to the pool); start_pos: (B,) absolute position of each
     slot's first chunk token.  Rows past a slot's real token count
     produce causally-valid garbage the caller must ignore.
 
-    Dispatches to the Pallas kernel on TPU (or under
-    ``MXTPU_PALLAS_INTERPRET=1``) when the shapes tile; otherwise — and
-    for int8 pools (``k_scales``/``v_scales``) — runs the gather-based
-    reference path.  ``MXTPU_PAGED_ATTENTION=reference`` forces the
-    reference path everywhere.
+    fp pools run the Pallas kernel wherever the package's ``MXTPU_PALLAS``
+    policy makes kernels active (a TPU backend, or ``kernel`` mode); a
+    pool the kernel cannot tile is a configuration error there, not a
+    slow path.  int8 pools (``k_scales``/``v_scales``) and every other
+    backend run the gather-based reference.
     """
     B, H, C, D = q.shape
-    ps = kpool.shape[1]
-    Hkv = kpool.shape[2]
+    if layer is None:
+        kpool, vpool, layer = kpool[None], vpool[None], 0
+        k_scales = None if k_scales is None else k_scales[None]
+        v_scales = None if v_scales is None else v_scales[None]
+    Hkv, ps = kpool.shape[1], kpool.shape[3]
     if H % Hkv:
         raise ValueError(f"query heads ({H}) must be a multiple of pool "
                          f"kv heads ({Hkv})")
     quantized = k_scales is not None or v_scales is not None
     if use_kernel is None:
-        interpret = _interpret()
-        on_tpu = jax.default_backend() == "tpu"
-        min_ps = 8 if interpret else LANES
-        d_ok = D <= LANES or D % LANES == 0
-        # _lanes slices (<= LANES) or tiles (multiple of LANES) the
-        # lane-replicated softmax stats — anything else can't tile
-        ps_ok = ps >= min_ps and (ps <= LANES or ps % LANES == 0)
-        use_kernel = ((on_tpu or interpret) and not quantized
-                      and not _force_reference()
-                      and ps_ok and d_ok)
-        if on_tpu and not use_kernel and not quantized \
-                and not _force_reference():
-            global _WARNED_FALLBACK
-            if not _WARNED_FALLBACK:
-                _WARNED_FALLBACK = True
-                import logging
-                logging.getLogger(__name__).warning(
-                    "ragged_paged_attention: falling back to the dense "
-                    "gather reference on TPU (page_size=%d or head_dim=%d "
-                    "untileable) — every step materialises the full "
-                    "padded context; set MXTPU_SERVE_PAGE_SIZE to %d (or "
-                    "a multiple of it) to use the Pallas kernel",
-                    ps, D, LANES)
+        use_kernel = not quantized and kernel_active()
     if use_kernel:
         if quantized:
             raise ValueError("the Pallas paged-attention kernel takes an "
                              "fp pool; int8 pools use the reference path")
-        return _rpa_pallas(q, kpool, vpool, page_tables, ctx_lens,
+        if not kernel_tileable(ps, D):
+            raise ValueError(
+                f"paged-attention kernel cannot tile page_size={ps}, "
+                f"head_dim={D}: the page size must be a multiple of 8 and "
+                f"both must be <= {LANES} or a multiple of {LANES} "
+                "(ServeConfig.page_size / MXTPU_SERVE_PAGE_SIZE)")
+        return _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens,
                            start_pos, window,
                            scale if scale is not None
                            else 1.0 / math.sqrt(D))
     return paged_attention_reference(
-        q, kpool, vpool, page_tables, ctx_lens, start_pos, window=window,
-        scale=scale, k_scales=k_scales, v_scales=v_scales)
+        q, kpool[layer], vpool[layer], page_tables, ctx_lens, start_pos,
+        window=window, scale=scale,
+        k_scales=None if k_scales is None else k_scales[layer],
+        v_scales=None if v_scales is None else v_scales[layer])
 
 
 # ---------------------------------------------------------------------------
@@ -402,8 +386,8 @@ def _at_build(config, shapes, dtype):
     rng = _np.random.RandomState(0)
     dt = jnp.bfloat16 if "16" in str(dtype) else jnp.float32
     q = jnp.asarray(rng.randn(b, h, 1, d), dt)
-    kpool = jnp.asarray(rng.randn(n_pages, ps, hkv, d), dt)
-    vpool = jnp.asarray(rng.randn(n_pages, ps, hkv, d), dt)
+    kpool = jnp.asarray(rng.randn(hkv, n_pages, ps, d), dt)
+    vpool = jnp.asarray(rng.randn(hkv, n_pages, ps, d), dt)
     pt = jnp.asarray(
         1 + _np.arange(b * maxp).reshape(b, maxp), jnp.int32)
     ctx_lens = jnp.full((b,), ctx, jnp.int32)

@@ -60,12 +60,6 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    """Static size of a named mesh axis, trace-safe inside shard_map.
-
-    jax 0.4.x has no ``lax.axis_size``; ``lax.psum(1, axis)`` of a
-    Python literal folds to a concrete int (usable in ``range()`` for
-    ppermute permutations), which is the classic idiom the newer API
-    replaced.  One compat point for every SP/PP collective."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a named mesh axis, trace-safe inside shard_map (a
+    concrete int, usable in ``range()`` for ppermute permutations)."""
+    return lax.axis_size(axis_name)

@@ -12,6 +12,7 @@ mesh axis consumed by `NamedSharding` rules and `shard_map` collectives:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as _onp
@@ -99,7 +100,7 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
     annotation, which jax's `check_vma=True` default rejects inside a
     mapped body (the kernel would silently fall back to O(L²) reference
     attention on the SP path). Single switch point for every SP/PP
-    shard_map in the package; older jax without the kwarg falls through.
+    shard_map in the package.
 
     TRADE-OFF (ADVICE r3): the switch is body-wide — it also silences
     the replication checker for the collectives surrounding the kernel
@@ -109,24 +110,13 @@ def shard_map_nocheck(fn, mesh, in_specs, out_specs):
     the single-device path (tests/unittest/test_parallel.py ring/Ulysses
     equivalence, tests/dist/).  Revisit if jax grows per-region vma
     control."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        # jax 0.4.x keeps shard_map under experimental (the top-level
-        # name landed later) — this was the "shard_map incompat" tier-1
-        # failure class carried since the seed
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    from ..ops.pallas import per_shard
+
+    @functools.wraps(fn)
+    def body(*args):
+        # arrays are per-device in here: kernels a surrounding GSPMD
+        # trace switched off (ops.pallas.partitioned_by_gspmd) lower
+        with per_shard():
+            return fn(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        pass
-    try:
-        # jax 0.4.x spells the same switch check_rep (the rename came
-        # with the vma terminology); without it the Pallas flash kernel
-        # trips "No replication rule for pallas_call" under shard_map
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)

@@ -29,6 +29,7 @@ from ..gluon.block import Block, functional_call
 from ..gluon.parameter import Parameter
 from ..optimizer import Optimizer
 from ..ops.fused_optim import HpScalarCache
+from ..ops import pallas as _pallas
 from ..ops.pallas import fused_optimizer as _fused_opt
 from .. import health as _health
 from .. import profiler as _profiler
@@ -229,8 +230,9 @@ class ShardedTrainStep:
         nothing sharded: the chunk pack concatenates leaves, which on a
         sharded layout would make GSPMD all-gather the tree every step
         (TODO(tpu): a segment-aware sharded pack, ROADMAP §5)."""
-        if not _fused_opt.kernel_route(self.optimizer):
-            return False
+        with _pallas.partitioned_by_gspmd(self.mesh.size):
+            if not _fused_opt.kernel_route(self.optimizer):
+                return False
         if self.mesh.size == 1:
             return True
         if self.zero or self.fsdp:
@@ -408,12 +410,18 @@ class ShardedTrainStep:
 
         outer = self
 
-        def step(pvals, opt_state, hp, key, *batch):
-            # this body runs once per TRACE of the jitted step — the hook
-            # counts compilations and warns (with the drifted avals) on a
-            # silent retrace, the dtype-drift failure mode noted below
-            outer._note_trace((pvals, opt_state, hp, key) + tuple(batch))
+        def step(*args):
+            # runs once per TRACE of the jitted step — the hook counts
+            # compilations and warns (with the drifted avals) on a silent
+            # retrace, the dtype-drift failure mode noted below
+            outer._note_trace(args)
+            # GSPMD partitions this program over the mesh: over more than
+            # one device Mosaic kernels cannot be lowered outside a
+            # shard_map, so their dispatch is decided here, up front
+            with _pallas.partitioned_by_gspmd(mesh.size):
+                return body(*args)
 
+        def body(pvals, opt_state, hp, key, *batch):
             def compute_loss(diff_vals, mkey, *mb):
                 pv = dict(pvals)
                 pv.update(diff_vals)
@@ -590,8 +598,8 @@ class ShardedTrainStep:
     # around it: batch placement moves to DevicePrefetcher threads
     # (place_batch), hyperparameter scalars stay device-resident (_hp),
     # dispatch() returns without fetching the loss, and warmup() AOT-
-    # compiles so step 1 (and, with MXTPU_COMPILE_CACHE, a restarted
-    # process) never trace-compiles inline.
+    # compiles so step 1 (and, with the persistent compile cache, a
+    # restarted process) never trace-compiles inline.
 
     def _note_trace(self, args) -> None:
         """Runs at trace time (the step body is python-executed once per
@@ -731,9 +739,9 @@ class ShardedTrainStep:
     def warmup(self, *batch, rng_key=None, artifact=None):
         """AOT warm start: trace + compile the step for this batch's avals
         WITHOUT executing it (`.lower().compile()`), so the first real
-        step runs at steady-state speed.  With ``MXTPU_COMPILE_CACHE`` set
-        (see `runtime.enable_compile_cache`) the XLA binary is served from
-        the persistent cache on a restart — the multi-minute BERT compile
+        step runs at steady-state speed.  With the persistent compile
+        cache on (`runtime.enable_compile_cache`) the XLA binary is served
+        from it on a restart — the multi-minute BERT compile
         happens once per cluster, not once per process.  Returns the
         compile wall-time in seconds (also kept as `compile_seconds`).
 

@@ -297,8 +297,13 @@ def dense_kv_fn(kcache, vcache, pos, window: Optional[int] = None):
 
     def kv_fn(li, q, k_new, v_new):
         from ..ops.pallas.paged_attention import _dense_attend
-        kc = lax.dynamic_update_slice_in_dim(kcache[li], k_new, t0, axis=2)
-        vc = lax.dynamic_update_slice_in_dim(vcache[li], v_new, t0, axis=2)
+        # the cache holds the model dtype; activations may be wider (the
+        # f32 norm parameters of a bf16 model promote them) — store like
+        # the paged pool does, in the cache's dtype
+        kc = lax.dynamic_update_slice_in_dim(
+            kcache[li], k_new.astype(kcache.dtype), t0, axis=2)
+        vc = lax.dynamic_update_slice_in_dim(
+            vcache[li], v_new.astype(vcache.dtype), t0, axis=2)
         new_k.append(kc)
         new_v.append(vc)
         return _dense_attend(q, kc, vc, pos, window=window)

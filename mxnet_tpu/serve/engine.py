@@ -7,10 +7,10 @@ ragged paged attention, LM head, sample — is ONE jitted program with the
 pool buffers **donated** (in-place page updates, zero per-step device
 allocation).  Two variants compile at `warmup()`: the mixed
 prefill+decode step at the prefill-chunk width and the steady-state
-pure-decode step at C=1; with ``MXTPU_COMPILE_CACHE`` set both come back
-from the persistent compile cache on restart (the TVM-flavored "serving
-path as a compiled, cached artifact" — the AOT-export layer of ROADMAP
-item 3 will load these same programs from disk).
+pure-decode step at C=1; with the persistent compile cache on
+(`runtime.enable_compile_cache`) both come back from it on restart (the
+TVM-flavored "serving path as a compiled, cached artifact" — the
+AOT-export layer loads these same programs from disk).
 
 Instrumented from day one: compile/journal events, per-step histograms,
 page-occupancy gauges (via the scheduler), and a ``serve.step`` heartbeat
@@ -64,16 +64,16 @@ def _env_int(name, default):
 
 def _default_page_size() -> int:
     """MXTPU_SERVE_PAGE_SIZE wins; otherwise the paged-attention
-    autotuner's persisted recommendation for this device, else 16
-    (`tune("paged_attention", ...)` — docs/perf.md)."""
+    autotuner's persisted recommendation for this device
+    (`tune("paged_attention", ...)` — docs/perf.md), else the lane width
+    on a TPU (one page = one full score tile of the kernel) and 16 on
+    the reference path."""
     explicit = _env_int("MXTPU_SERVE_PAGE_SIZE", 0)
     if explicit:
         return explicit
-    try:
-        from ..ops.pallas.paged_attention import recommended_page_size
-        return recommended_page_size(16)
-    except Exception:
-        return 16
+    from ..ops.pallas.paged_attention import recommended_page_size, LANES
+    return recommended_page_size(
+        LANES if jax.default_backend() == "tpu" else 16)
 
 
 @dataclass
@@ -416,14 +416,11 @@ class InferenceEngine:
         return tu.tree_map_with_path(spec, self.P)
 
     def _pool_specs(self):
-        """PartitionSpecs for the pool arrays: K/V pages shard the
-        kv-head dim (axis 3); the int8 per-vector scale planes shard
-        their trailing kv-head dim."""
+        """PartitionSpecs for the pool arrays: K/V pages and the int8
+        per-vector scale planes all shard their kv-head dim (axis 1)."""
         from jax.sharding import PartitionSpec as PS
-        return tuple(
-            PS(None, None, None, "tp", None) if a.ndim == 5
-            else PS(None, None, None, "tp")
-            for a in self.pools.as_tuple())
+        return tuple(PS(None, "tp", *([None] * (a.ndim - 2)))
+                     for a in self.pools.as_tuple())
 
     def _tp_shard_weights(self) -> None:
         from jax.sharding import NamedSharding
@@ -542,8 +539,8 @@ class InferenceEngine:
         """AOT-compile the mixed prefill step and the C=1 decode step
         (``.lower().compile()`` — no step executed, the
         `ShardedTrainStep.warmup` idiom).  Returns total compile seconds;
-        with ``MXTPU_COMPILE_CACHE`` set the binaries come back from the
-        persistent cache on a warm start.
+        with the persistent compile cache on the binaries come back
+        from it on a warm start.
 
         ``artifact=<path>`` (or an auto-matched artifact under the
         export dir — docs/export.md) skips the AOT lower entirely: both
@@ -839,7 +836,7 @@ class InferenceEngine:
         covers every fork."""
         if self._cow_fn is None:
             self._cow_fn = jax.jit(
-                lambda a, s, d: a.at[:, d].set(a[:, s]),
+                lambda a, s, d: a.at[:, :, d].set(a[:, :, s]),
                 donate_argnums=(0,))
         s = jnp.int32(src)
         d = jnp.int32(dst)
@@ -854,12 +851,12 @@ class InferenceEngine:
     def export_pages(self, page_ids) -> dict:
         """Host copies of the listed physical pages, every pool array
         (K + V + scale planes): ``{name: ndarray[..., n_pages, ...]}``
-        with the page dim at axis 1.  The prefill side of a cross-
+        with the page dim at axis 2.  The prefill side of a cross-
         process handoff — the fleet ships these as binary wire blobs."""
         ids = onp.asarray(page_ids, onp.int32)
         with self._device_lock:
             return {name: onp.asarray(
-                        jax.device_get(self.pools.arrays[name][:, ids]))
+                        jax.device_get(self.pools.arrays[name][:, :, ids]))
                     for name in self.pools.names}
 
     def install_pages(self, page_ids, arrays: dict) -> None:
@@ -870,7 +867,7 @@ class InferenceEngine:
         (pool aval, page count) covers repeated handoffs."""
         if getattr(self, "_install_fn", None) is None:
             self._install_fn = jax.jit(
-                lambda a, ids, vals: a.at[:, ids].set(vals),
+                lambda a, ids, vals: a.at[:, :, ids].set(vals),
                 donate_argnums=(0,))
         ids = jnp.asarray(page_ids, jnp.int32)
         with self._device_lock:

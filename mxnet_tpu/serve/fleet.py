@@ -60,6 +60,8 @@ import time
 from collections import OrderedDict, deque
 from typing import List, Optional, Tuple
 
+import jax
+
 from ..base import MXNetError
 from ..resilience import fault_point
 from .. import health as _health
@@ -528,7 +530,10 @@ class ProcessReplica(Replica):
                # the spec dir is fleet-wide; role/tp specialize it
                # per worker (disaggregation)
                "--role", self.engine.role,
-               "--tp", str(self.engine.tp)]
+               "--tp", str(self.engine.tp),
+               # the worker refuses to serve from any other platform
+               # (a chip it could not claim leaves it on the CPU)
+               "--platform", jax.default_backend()]
         self.proc = subprocess.Popen(cmd, env=worker_env())
         try:
             control, events, hello = listener.wait(

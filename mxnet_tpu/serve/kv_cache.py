@@ -2,7 +2,9 @@
 
 The serving analogue of the reference's memory pool (`src/storage/`): all
 KV memory for all concurrent requests lives in ONE preallocated device pool
-of fixed-size pages, `(n_layers, num_pages, page_size, Hkv, D)` per tensor.
+of fixed-size pages, `(n_layers, Hkv, num_pages, page_size, D)` per tensor
+(head-major: one kv head's page is a contiguous `(page_size, D)` tile, the
+block the paged-attention kernel DMAs).
 A sequence owns an ordered list of physical pages (its *page table*);
 logical token position ``p`` lives in page ``table[p // page_size]`` at
 offset ``p % page_size``.  Admission, growth, and eviction are pure
@@ -397,8 +399,8 @@ class KVPools:
 
     Arrays (one K + one V, plus scale planes when quantized):
 
-    - ``k``/``v``: (n_layers, num_pages, page_size, Hkv, D) `dtype`
-    - ``k_scale``/``v_scale``: (n_layers, num_pages, page_size, Hkv)
+    - ``k``/``v``: (n_layers, Hkv, num_pages, page_size, D) `dtype`
+    - ``k_scale``/``v_scale``: (n_layers, Hkv, num_pages, page_size)
       float32 (int8 pools only; one symmetric scale per stored vector)
 
     The arrays are exposed as a flat tuple (`as_tuple`) so the engine can
@@ -421,7 +423,7 @@ class KVPools:
     def create(cls, n_layers: int, num_pages: int, page_size: int,
                n_kv_heads: int, head_dim: int, dtype="float32") -> "KVPools":
         quantized = str(dtype) == "int8"
-        shape = (n_layers, num_pages, page_size, n_kv_heads, head_dim)
+        shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         store_dt = jnp.int8 if quantized else jnp.dtype(dtype)
         arrays = {"k": jnp.zeros(shape, store_dt),
                   "v": jnp.zeros(shape, store_dt)}
@@ -476,34 +478,36 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
         pos = start_pos[:, None] + jnp.arange(C)[None, :]      # (B, C)
         logical = jnp.minimum(pos // ps, page_tables.shape[1] - 1)
         phys = jnp.take_along_axis(page_tables, logical, axis=1)
-        flat = phys * ps + pos % ps                            # (B, C)
         active = jnp.arange(C)[None, :] < num_tokens[:, None]
-        flat = jnp.where(active, flat, NULL_PAGE * ps)
-        idx = flat.reshape(B * C)
+        # one D-vector per (token, kv head): the scatter form that costs
+        # least next to the Pallas call (decode step 25.4 ms against
+        # 37.1 ms for a (Hkv, D)-window scatter — my chip run, PR 21).
+        # XLA:TPU still relayouts the whole pool around every layer's
+        # kernel either way; writing K/V inside the kernel is the fix
+        # (PERF.md §7)
+        head = jnp.tile(jnp.arange(Hkv), B * C)
+        page = jnp.repeat(
+            jnp.where(active, phys, NULL_PAGE).reshape(B * C), Hkv)
+        off = jnp.repeat((pos % ps).reshape(B * C), Hkv)
 
         def scatter(name, new):
-            # (B, Hkv, C, D) -> per-token rows (B*C, Hkv, D)
-            rows = new.transpose(0, 2, 1, 3).reshape(B * C, Hkv, D)
-            pool = pools[name][li]
-            flat_pool = pool.reshape(pool.shape[0] * ps, Hkv, D)
+            # (B, Hkv, C, D) -> (token, head)-major vectors, scattered
+            # straight into the stacked pool (in place under donation)
+            vecs = new.transpose(0, 2, 1, 3).reshape(B * C * Hkv, D)
             if quantized:
                 from ..contrib.quantization import quantize_kv
-                rows, scales = quantize_kv(rows)
-                sp = pools[name + "_scale"][li]
-                flat_sp = sp.reshape(sp.shape[0] * ps, Hkv)
-                flat_sp = flat_sp.at[idx].set(scales)
-                pools[name + "_scale"] = pools[name + "_scale"].at[li].set(
-                    flat_sp.reshape(sp.shape))
-            flat_pool = flat_pool.at[idx].set(rows.astype(flat_pool.dtype))
-            pools[name] = pools[name].at[li].set(
-                flat_pool.reshape(pool.shape))
+                vecs, scales = quantize_kv(vecs)
+                pools[name + "_scale"] = pools[name + "_scale"].at[
+                    li, head, page, off].set(scales)
+            pools[name] = pools[name].at[li, head, page, off].set(
+                vecs.astype(pools[name].dtype))
 
         scatter("k", k_new)
         scatter("v", v_new)
         return ragged_paged_attention(
-            q, pools["k"][li], pools["v"][li], page_tables, ctx_lens,
-            start_pos, window=window,
-            k_scales=pools["k_scale"][li] if quantized else None,
-            v_scales=pools["v_scale"][li] if quantized else None)
+            q, pools["k"], pools["v"], page_tables, ctx_lens,
+            start_pos, window=window, layer=li,
+            k_scales=pools["k_scale"] if quantized else None,
+            v_scales=pools["v_scale"] if quantized else None)
 
     return kv_fn

@@ -135,10 +135,14 @@ class Worker:
 
     def __init__(self, name: str, host: str, port: int, spec_dir: str,
                  seed: int = 0, role: Optional[str] = None,
-                 tp: Optional[int] = None):
+                 tp: Optional[int] = None,
+                 platform: Optional[str] = None):
         self.name = name
         self.spec_dir = spec_dir
         self.seed = seed
+        #: the fleet parent's JAX platform (--platform): this worker
+        #: must land on the same one or die (see `_check_platform`)
+        self.platform = platform or None
         #: per-worker overrides of the fleet-wide spec (disaggregation:
         #: one spec dir serves every role; --role/--tp specialize it)
         self.role_override = role or None
@@ -503,10 +507,32 @@ class Worker:
             return {"queued": rids}
         raise MXNetError(f"unknown wire verb {verb!r}")
 
+    def _check_platform(self) -> str:
+        """Die loudly when this process is not on its parent's platform.
+        A chip belongs to one process: a worker that could not claim it
+        (the parent, or a sibling, holds it) is left on the CPU by JAX
+        with one log line — it must never serve from there."""
+        import jax
+        got = jax.default_backend()
+        if self.platform and got != self.platform:
+            msg = (f"worker {self.name} (pid {os.getpid()}) is on "
+                   f"platform {got!r} but its fleet parent runs on "
+                   f"{self.platform!r}: a chip belongs to one process, so "
+                   "a worker that cannot claim it refuses to serve "
+                   "(docs/serving.md \"Process fleet\")")
+            self._send({"ev": "fatal", "error": msg})
+            raise MXNetError(msg)
+        return got
+
     # -- main loop ------------------------------------------------------
     def run(self) -> int:
         threading.Thread(target=self._control_loop, daemon=True,
                          name="worker-control").start()
+        if self._check_platform() != "cpu":
+            # accelerator compiles run minutes; CPU workers (the test
+            # fleets) compile toys and write no cache
+            from ..runtime import enable_compile_cache
+            enable_compile_cache()
         model, sc = load_spec(self.spec_dir)
         if self.role_override or self.tp_override:
             sc = dataclasses.replace(
@@ -565,10 +591,13 @@ def main(argv=None) -> int:
                          "(prefill | decode | both)")
     ap.add_argument("--tp", type=int, default=0,
                     help="override ServeConfig.tp from the spec")
+    ap.add_argument("--platform", default="",
+                    help="the parent's jax.default_backend(); the worker "
+                         "exits unless it lands on the same platform")
     args = ap.parse_args(argv)
     worker = Worker(args.name, args.host, args.port, args.spec,
                     seed=args.seed, role=args.role or None,
-                    tp=args.tp or None)
+                    tp=args.tp or None, platform=args.platform or None)
     rc = worker.run()
     # a worker that lost its parent exits quietly — the stack is noise
     return 0 if worker._lost_parent.is_set() else rc

@@ -58,6 +58,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from . import telemetry as _tele
+from .base import MXNetError
 
 __all__ = [
     "Span", "SpanContext", "Tracer", "CostAccountant", "ClockSync",
@@ -596,18 +597,22 @@ def export_chrome(path: Optional[str] = None,
 # cost accounting
 # ---------------------------------------------------------------------------
 
-# bf16 peak matmul flops by TPU device kind (the bench.py table, shared
-# so the MFU gauge and the bench agree on the denominator)
+# Peak bf16 matmul FLOP/s per chip, matched by substring of
+# `device_kind` — the ONE table the MFU gauge and the bench divide by.
+# Source: Google Cloud TPU documentation, the per-version system
+# architecture pages ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM;
+# "TPU v5p": 459; "TPU v4": 275; "TPU v6e" / Trillium: 918).  A device
+# that is not in the table is an error, never a default.
 _PEAK_FLOPS = (
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12), ("v5", 459e12),
     ("v4", 275e12), ("v6", 918e12), ("trillium", 918e12),
 )
-_DEFAULT_PEAK = 197e12
 
 
 def peak_flops(device_kind: str) -> float:
-    """Peak bf16 FLOP/s for a device-kind string (conservative default
-    for unknown kinds); ``MXTPU_PEAK_TFLOPS`` overrides everything."""
+    """Peak bf16 FLOP/s for a device-kind string; ``MXTPU_PEAK_TFLOPS``
+    overrides the table.  An unknown kind raises — a utilization figure
+    over a guessed denominator is worse than none."""
     env = os.environ.get(ENV_PEAK_TFLOPS, "").strip()
     if env:
         try:
@@ -618,7 +623,9 @@ def peak_flops(device_kind: str) -> float:
     for key, val in _PEAK_FLOPS:
         if key in kind:
             return val
-    return _DEFAULT_PEAK
+    raise MXNetError(
+        f"no published peak FLOP/s for device kind {device_kind!r}: add it "
+        f"to tracing._PEAK_FLOPS with its source, or set {ENV_PEAK_TFLOPS}")
 
 
 def projected_peak_flops() -> Tuple[float, str]:

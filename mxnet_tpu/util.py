@@ -48,13 +48,7 @@ def x64_scope(enabled: bool = True):
 
     Wraps JAX's scoped `enable_x64` config state; compiled functions are
     cached separately per setting, so toggling is jit-safe."""
-    # jax >= 0.4.30 removed the top-level alias; the scoped context
-    # lives in jax.experimental (this was the whole "x64 incompat"
-    # tier-1 failure class carried since the seed)
-    scope = getattr(jax, "enable_x64", None)
-    if scope is None:
-        from jax.experimental import enable_x64 as scope
-    return scope(bool(enabled))
+    return jax.enable_x64(bool(enabled))
 
 
 def npz_encode_entry(out: dict, key: str, arr) -> None:

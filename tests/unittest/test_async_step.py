@@ -251,22 +251,51 @@ def test_warmup_fallback_on_aval_drift(caplog):
                for r in caplog.records)
 
 
-def test_compile_cache_env_round_trip(tmp_path, monkeypatch):
-    from mxnet_tpu import runtime
-    monkeypatch.delenv("MXTPU_COMPILE_CACHE", raising=False)
-    assert runtime.enable_compile_cache() is None
-    cache = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE", cache)
-    got = runtime.enable_compile_cache()
-    assert got == cache
-    assert runtime.compile_cache_dir() == cache
-    assert jax.config.jax_compilation_cache_dir == cache
-    step = _make_step()
-    xs, ys = _data()
-    step.warmup(xs, ys)
-    float(step(xs, ys))
+def test_compile_cache_resolution(tmp_path, monkeypatch):
+    """The one placement rule (runtime.py): JAX_COMPILATION_CACHE_DIR set
+    -> that directory, and our code never touches jax's cache-dir config
+    (JAX reads the variable itself); unset -> <checkout>/.jax_cache, set
+    in code.  Nothing is active (and autotune/export derive no
+    directory) until an entry point enables it."""
     import os
-    assert os.path.isdir(cache)
+    import types
+    import mxnet_tpu
+    from mxnet_tpu import runtime, telemetry
+    from mxnet_tpu.export import export_dir
+    from mxnet_tpu.ops.pallas import autotune
+    updates, made = [], []
+    monkeypatch.setattr(runtime, "jax", types.SimpleNamespace(
+        config=types.SimpleNamespace(
+            update=lambda k, v: updates.append((k, v)))))
+    monkeypatch.setattr(runtime.os, "makedirs",
+                        lambda p, exist_ok=False: made.append(p))
+    monkeypatch.setattr(telemetry, "install_compile_cache_listener",
+                        lambda: True)
+    monkeypatch.setattr(runtime, "_enabled", False)
+    for var in ("JAX_COMPILATION_CACHE_DIR", "MXTPU_AUTOTUNE_CACHE",
+                "MXTPU_EXPORT_DIR"):
+        monkeypatch.delenv(var, raising=False)
+    assert runtime.compile_cache_dir() is None
+    assert autotune.cache_dir() is None and export_dir() is None
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    assert runtime.enable_compile_cache() == placed
+    assert runtime.compile_cache_dir() == placed
+    assert not made
+    assert "jax_compilation_cache_dir" not in dict(updates)
+    assert dict(updates)["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert autotune.cache_dir() == os.path.join(placed, "autotune")
+    assert export_dir() == os.path.join(placed, "export")
+
+    del updates[:]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(mxnet_tpu.__file__))
+    default = os.path.join(checkout, ".jax_cache")
+    assert runtime.enable_compile_cache() == default
+    assert runtime.compile_cache_dir() == default
+    assert made == [default]
+    assert dict(updates)["jax_compilation_cache_dir"] == default
 
 
 # -- CPU overlap smoke benchmark (acceptance criterion) ----------------

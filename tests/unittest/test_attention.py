@@ -96,33 +96,18 @@ def test_masked_softmax():
     assert abs(gv[1].sum() - 1) < 1e-5
 
 
-def test_flash_fallback_warns_per_reason(monkeypatch):
-    """VERDICT r3 weak #7: the fallback warning dedups per REASON — a
-    second, different failure cause still warns; a repeat of the same
-    cause does not."""
-    import warnings as _w
+def test_flash_kernel_error_propagates(monkeypatch):
+    """Where the Pallas route is active, an error inside the flash kernel
+    is an error — never a warning plus the O(L²) reference path."""
     from mxnet_tpu.ops import attention as _att
     import mxnet_tpu.ops.pallas.flash_attention as _fa
     import jax.numpy as jnp
 
+    def boom(*a, **k):
+        raise ValueError("kernel rejected the input")
+
     monkeypatch.setattr(_att, "_use_pallas", lambda: True)
-    monkeypatch.setattr(_att, "_warned_fallback_reasons", set())
+    monkeypatch.setattr(_fa, "flash_attention", boom)
     q = jnp.ones((1, 2, 8, 4), jnp.float32)
-
-    def raiser(msg):
-        def f(*a, **k):
-            raise ValueError(msg)
-        return f
-
-    monkeypatch.setattr(_fa, "flash_attention", raiser("cause A"))
-    with _w.catch_warnings(record=True) as rec:
-        _w.simplefilter("always")
+    with pytest.raises(ValueError, match="kernel rejected"):
         _att.dot_product_attention(q, q, q)
-        _att.dot_product_attention(q, q, q)      # same reason: no repeat
-    assert sum("cause A" in str(r.message) for r in rec) == 1
-
-    monkeypatch.setattr(_fa, "flash_attention", raiser("cause B"))
-    with _w.catch_warnings(record=True) as rec2:
-        _w.simplefilter("always")
-        _att.dot_product_attention(q, q, q)      # NEW reason: warns again
-    assert sum("cause B" in str(r.message) for r in rec2) == 1

@@ -300,7 +300,7 @@ def test_sync_flags_retry_semantics_survive_timeout_wrapper(monkeypatch):
     from jax.experimental import multihost_utils
 
     def always_down(x):
-        raise RuntimeError("tunnel reset (injected)")
+        raise RuntimeError("transient collective error (injected)")
 
     monkeypatch.setattr(elastic, "_SYNC_BASE_DELAY", 0.001)
     monkeypatch.setattr(jax, "process_count", lambda: 2)

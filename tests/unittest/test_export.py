@@ -454,7 +454,13 @@ def test_load_block_runs_from_artifact_alone(tmp_path):
     lb = load_block(path)
     got = lb(ids)
     want = model(ids)
-    assert bool(jnp.all(got._data == want._data))
+    # structure exactly; values to f32 round-off — the artifact's
+    # StableHLO and the live block are compiled as different programs,
+    # so XLA may fuse (and round) them differently in the last ulp
+    assert got.shape == want.shape and got.dtype == want.dtype
+    onp.testing.assert_allclose(onp.asarray(got._data),
+                                onp.asarray(want._data),
+                                rtol=1e-5, atol=1e-6)
     # params ride in the artifact
     assert os.path.isfile(os.path.join(path, "params.npz"))
     # kind guard

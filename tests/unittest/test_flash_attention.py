@@ -252,7 +252,6 @@ def test_masked_batch_stays_on_flash_path(monkeypatch):
         raise AssertionError("reference path used for masked batch")
 
     monkeypatch.setattr(att, "reference_attention", boom)
-    monkeypatch.setenv("MXTPU_FLASH_STRICT", "1")
     b, l, e, heads = 2, 64, 32, 4
     x = mx.np.array(onp.random.RandomState(0).rand(b, l, e), dtype="float32")
     mask = mx.np.array(

@@ -222,10 +222,9 @@ def test_sp_paths_keep_flash_kernel(monkeypatch):
     from mxnet_tpu.parallel.ring_attention import ring_attention
     from mxnet_tpu.parallel.ulysses import ulysses_attention
 
-    monkeypatch.setenv("MXTPU_FLASH_STRICT", "1")
     # run the real kernel code through the Pallas interpreter on CPU
-    # (without this the dispatch skips the kernel on cpu backends and
-    # the strict flag guards nothing)
+    # (without this the dispatch skips the kernel on cpu backends); a
+    # kernel that fails to trace under shard_map raises
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     rng = onp.random.RandomState(0)
     q = jnp.asarray(rng.randn(2, 4, 64, 16).astype("float32"))

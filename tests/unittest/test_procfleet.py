@@ -527,3 +527,21 @@ def test_spec_roundtrip(tmp_path):
         for k in L0:
             onp.testing.assert_array_equal(onp.asarray(L0[k]),
                                            onp.asarray(L1[k]))
+
+
+def test_worker_refuses_to_serve_off_its_parents_platform():
+    """A worker that ended up on another platform than its fleet parent
+    (a chip belongs to one process: JAX leaves a process that could not
+    claim it on the CPU with one log line) reports a fatal event and
+    raises before it builds an engine."""
+    from mxnet_tpu.serve.worker import Worker
+    w = Worker.__new__(Worker)
+    w.name, w.platform, sent = "r0", "tpu", []
+    w._send = sent.append
+    with pytest.raises(MXNetError, match="'cpu' but its fleet parent "
+                                         "runs on 'tpu'"):
+        w._check_platform()
+    assert sent and sent[0]["ev"] == "fatal"
+    w.platform, sent[:] = "cpu", []
+    w._check_platform()              # same platform: nothing to report
+    assert not sent

@@ -198,7 +198,7 @@ def test_sync_flag_raises_after_retry_budget(monkeypatch):
     from mxnet_tpu import elastic
 
     def always_down(x):
-        raise RuntimeError("tunnel reset (injected)")
+        raise RuntimeError("transient collective error (injected)")
 
     monkeypatch.setattr(elastic, "_SYNC_BASE_DELAY", 0.001)
     monkeypatch.setattr(jax, "process_count", lambda: 2)

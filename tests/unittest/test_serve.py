@@ -76,8 +76,8 @@ def test_page_allocator_guards():
 def _paged_setup(rng, B, H, Hkv, C, D, ps, npages, maxp):
     import jax.numpy as jnp
     q = jnp.asarray(rng.randn(B, H, C, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(npages, ps, Hkv, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(npages, ps, Hkv, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(Hkv, npages, ps, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(Hkv, npages, ps, D), jnp.float32)
     # distinct physical pages per slot, shuffled (non-contiguous layout)
     perm = rng.permutation(npages - 1)[:B * maxp] + 1
     pt = jnp.asarray(perm.reshape(B, maxp), jnp.int32)
@@ -89,14 +89,15 @@ def _dense_oracle(q, kp, vp, pt, ctx, start, window=None):
     import jax
     import jax.numpy as jnp
     B, H, C, D = q.shape
-    ps, Hkv = kp.shape[1], kp.shape[2]
+    Hkv, ps = kp.shape[0], kp.shape[2]
     maxp = pt.shape[1]
     L = maxp * ps
-    kc = kp[pt].reshape(B, L, Hkv, D)
-    vc = vp[pt].reshape(B, L, Hkv, D)
+    # head-major pool (Hkv, pages, ps, D) -> (B, Hkv, L, D)
+    kc = kp[:, pt].reshape(Hkv, B, L, D).transpose(1, 0, 2, 3)
+    vc = vp[:, pt].reshape(Hkv, B, L, D).transpose(1, 0, 2, 3)
     rep = H // Hkv
-    kfull = jnp.repeat(kc, rep, axis=2).transpose(0, 2, 1, 3)
-    vfull = jnp.repeat(vc, rep, axis=2).transpose(0, 2, 1, 3)
+    kfull = jnp.repeat(kc, rep, axis=1)
+    vfull = jnp.repeat(vc, rep, axis=1)
     s = jnp.einsum("bhcd,bhtd->bhct", q, kfull) / onp.sqrt(D)
     t_idx = jnp.arange(L)[None, None, None, :]
     pos = (start[:, None] + jnp.arange(C))[:, None, :, None]
@@ -153,34 +154,20 @@ def test_paged_kernel_matches_reference_interpret(window, monkeypatch):
                                     rtol=2e-5, atol=2e-5)
 
 
-def test_untileable_page_size_falls_back_to_reference(monkeypatch):
+def test_untileable_page_size_is_an_error_on_the_kernel_route(monkeypatch):
     """page_size > 128 but not a multiple of 128 cannot tile the kernel's
-    lane-replicated stats — the auto gate must take the reference path
-    instead of crashing at trace time."""
+    lane-replicated stats: where the kernel is the route (a TPU, or
+    MXTPU_PALLAS=kernel) that is a configuration error, never a silent
+    detour through the dense-gather reference."""
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas import paged_attention as pa
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     rng = onp.random.RandomState(4)
     q, kp, vp, pt = _paged_setup(rng, 2, 2, 2, 1, 8, 192, 5, 2)
     start = jnp.asarray([0, 3], jnp.int32)
-    ctx = start + 1
-    out = pa.ragged_paged_attention(q, kp, vp, pt, ctx, start)  # auto gate
-    ref = pa.paged_attention_reference(q, kp, vp, pt, ctx, start)
-    onp.testing.assert_allclose(out, ref, rtol=1e-6)
-
-
-def test_paged_attention_env_forces_reference(monkeypatch):
-    import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas import paged_attention as pa
-    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("MXTPU_PAGED_ATTENTION", "reference")
-    rng = onp.random.RandomState(2)
-    q, kp, vp, pt = _paged_setup(rng, 2, 2, 2, 1, 8, 8, 8, 2)
-    start = jnp.asarray([0, 3], jnp.int32)
-    ctx = start + 1
-    out = pa.ragged_paged_attention(q, kp, vp, pt, ctx, start)
-    ref = pa.paged_attention_reference(q, kp, vp, pt, ctx, start)
-    onp.testing.assert_allclose(out, ref, rtol=1e-6)
+    with pytest.raises(ValueError, match="cannot tile page_size=192"):
+        pa.ragged_paged_attention(q, kp, vp, pt, start + 1, start)
 
 
 # ---------------------------------------------------------------------------

@@ -434,7 +434,9 @@ def test_enable_compile_cache_installs_listener(tmp_path, monkeypatch):
     calls = []
     monkeypatch.setattr(tele, "install_compile_cache_listener",
                         lambda: calls.append(1) or True)
-    assert runtime.enable_compile_cache(str(tmp_path / "cc")) is not None
+    monkeypatch.setattr(runtime, "_enabled", False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    assert runtime.enable_compile_cache() == str(tmp_path / "cc")
     assert calls == [1]
 
 

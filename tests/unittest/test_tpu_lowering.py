@@ -221,3 +221,105 @@ def test_flash_block_size_variants_lower_for_tpu(bq, bk):
 
     txt = _lower_for_tpu(train, q, q, q)
     assert txt.count("tpu_custom_call") == 3
+
+
+# ---------------------------------------------------------------------------
+# serving: the paged-attention kernel and the fused serve step (PR 21 — the
+# kernel had only ever run in interpret mode and could not lower at all)
+# ---------------------------------------------------------------------------
+
+GPT2_SMALL = dict(H=12, Hkv=12, D=64)      # 12 x 768, 12 heads
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("C", [1, 16])
+def test_paged_attention_kernel_lowers_for_tpu_at_gpt2_shapes(dtype, C):
+    """`ragged_paged_attention(use_kernel=True)` at GPT-2-small widths,
+    the default TPU page size, decode (C = 1) and a prefill chunk: the
+    head-major pool makes one (kv head, page) a (page_size, D) tile
+    Mosaic can block — the old (pages, ps, Hkv, D) pool squeezed Hkv in
+    the second-minor position and failed right here."""
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        LANES, kernel_tileable, ragged_paged_attention)
+    H, Hkv, D = (GPT2_SMALL[k] for k in ("H", "Hkv", "D"))
+    B, ps, n_layers = 8, LANES, 2
+    assert kernel_tileable(ps, D)
+    maxp = 1024 // ps
+    q = jnp.ones((B, H, C, D), dtype)
+    pool = jnp.ones((n_layers, Hkv, B * maxp + 1, ps, D), dtype)
+    pt = jnp.zeros((B, maxp), jnp.int32)
+    ctx = jnp.full((B,), 40, jnp.int32)
+
+    def f(q, kp, vp, pt, ctx, start):
+        return ragged_paged_attention(q, kp, vp, pt, ctx, start,
+                                      use_kernel=True, layer=1)
+
+    txt = _lower_for_tpu(f, q, pool, pool, pt, ctx, ctx - C)
+    assert txt.count("tpu_custom_call") == 1
+
+
+def test_gpt2_small_serve_step_lowers_with_paged_kernel(monkeypatch):
+    """The GPT-2-small-WIDTH serve step as a TPU would trace it (the
+    dispatch consults `jax.default_backend`): the default page size is
+    one the kernel accepts, and both compiled widths carry one
+    paged-attention custom call per layer — on the parent the step
+    lowered with ZERO custom calls (page size 16 < 128 took the dense
+    reference behind one warning)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from mxnet_tpu.ops.pallas.paged_attention import LANES
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+
+    # GPT-2-small widths (768 hidden, 12 heads of 64); depth and vocab are
+    # cut — neither shapes the attention call this test pins
+    n_layers = 1
+    cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=n_layers,
+                    vocab_size=1024)
+    model = GPTForCausalLM(cfg)
+    model.initialize()
+    model(mx.np.array([[1, 2]], dtype="int32"))    # eager init on the cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = InferenceEngine(model, ServeConfig(max_len=1024))
+    assert eng.serve_config.page_size == LANES
+    for C in eng._step_widths():
+        txt = eng._step_fn(C).trace(*eng._step_avals(C)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert txt.count("tpu_custom_call") == n_layers, (C, txt.count(
+            "tpu_custom_call"))
+
+
+def test_gspmd_mesh_step_takes_references_and_shard_map_keeps_kernels(
+        monkeypatch):
+    """jax refuses to lower a Mosaic kernel in a program GSPMD partitions
+    over several devices ("cannot be automatically partitioned"), so a
+    `ShardedTrainStep` on a multi-device mesh decides for the jnp
+    references up front; inside a shard_map body kernels lower again."""
+    from mxnet_tpu.ops import pallas as _pallas
+    from mxnet_tpu.ops import attention as _att
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _pallas.kernel_active() and _att._use_pallas()
+    with _pallas.partitioned_by_gspmd(4):
+        assert not _pallas.kernel_active() and not _att._use_pallas()
+        with _pallas.per_shard():
+            assert _pallas.kernel_active() and _att._use_pallas()
+        with _pallas.partitioned_by_gspmd(1):      # one device: no GSPMD
+            assert _pallas.kernel_active()
+    assert _pallas.kernel_active()
+
+
+def test_chip_smoke_refuses_to_run_off_the_chip(tmp_path):
+    """`chip_smoke.py` anywhere but on a TPU: non-zero exit naming the
+    platform it found, no result line."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr and "not a TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout

@@ -17,6 +17,7 @@ import mxnet_tpu as mx  # noqa: F401
 from mxnet_tpu import optimizer as opt
 from mxnet_tpu import telemetry as tele
 from mxnet_tpu import tracing
+from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.parallel import (DevicePrefetcher, make_mesh,
                                 make_sharded_train_step)
@@ -207,7 +208,8 @@ def test_cost_accountant_records_and_estimates():
 def test_peak_flops_table_and_env_override(monkeypatch):
     assert tracing.peak_flops("TPU v4") == 275e12
     assert tracing.peak_flops("TPU v5 lite") == 197e12
-    assert tracing.peak_flops("unknown accelerator") == 197e12
+    with pytest.raises(MXNetError, match="no published peak"):
+        tracing.peak_flops("unknown accelerator")
     monkeypatch.setenv("MXTPU_PEAK_TFLOPS", "100")
     assert tracing.peak_flops("TPU v4") == 100e12
     monkeypatch.delenv("MXTPU_PEAK_TFLOPS")

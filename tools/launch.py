@@ -12,7 +12,11 @@ environment `jax.distributed.initialize` reads:
 
 - `--launcher local` spawns N copies of the command on this machine (the
   reference's single-machine multi-process test trick,
-  `tests/nightly/test_distributed_training-gpu.sh:25-38`).
+  `tests/nightly/test_distributed_training-gpu.sh:25-38`).  It is the
+  CPU-TEST launcher (`JAX_PLATFORMS=cpu`): on a TPU host every one of the
+  N processes would try to claim every chip, and a chip belongs to one
+  process — there ONE process drives all chips of the host (a mesh over
+  `jax.devices()`), and multi-host jobs run one process per host (`ssh`).
 - `--launcher ssh -H hostfile` prints/execs ssh commands per host.
 """
 from __future__ import annotations
