@@ -12,6 +12,8 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
+
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from .layers import FusedSelfAttention, check_max_position
@@ -99,10 +101,17 @@ class BertLayer(HybridBlock):
         self.dropout = nn.Dropout(cfg.dropout)
 
     def forward(self, x, attn_mask=None):
-        x = self.attn_norm(x + self.attention(x, attn_mask))
-        y = npx.gelu(self.ffn_intermediate(x))
-        y = self.dropout(self.ffn_output(y))
-        return self.ffn_norm(x + y)
+        # `jax.named_scope`s (trace-time only): stable `mx.*` block
+        # names in the compiled step's op metadata
+        with jax.named_scope("mx.attn"):
+            a = self.attention(x, attn_mask)
+        with jax.named_scope("mx.norm"):
+            x = self.attn_norm(x + a)
+        with jax.named_scope("mx.ffn"):
+            y = npx.gelu(self.ffn_intermediate(x))
+            y = self.dropout(self.ffn_output(y))
+        with jax.named_scope("mx.norm"):
+            return self.ffn_norm(x + y)
 
 
 class BertModel(HybridBlock):
@@ -128,12 +137,13 @@ class BertModel(HybridBlock):
     def forward(self, input_ids, token_types=None, valid_length=None):
         b, l = input_ids.shape
         check_max_position(l, self.cfg.max_position)
-        pos = npx.arange_like(input_ids, axis=1).astype("int32")
-        x = self.word_embed(input_ids)
-        x = x + self.position_embed(pos.reshape(1, l))
-        if token_types is not None:
-            x = x + self.token_type_embed(token_types)
-        x = self.embed_dropout(self.embed_norm(x))
+        with jax.named_scope("mx.embed"):
+            pos = npx.arange_like(input_ids, axis=1).astype("int32")
+            x = self.word_embed(input_ids)
+            x = x + self.position_embed(pos.reshape(1, l))
+            if token_types is not None:
+                x = x + self.token_type_embed(token_types)
+            x = self.embed_dropout(self.embed_norm(x))
 
         mask = None
         if valid_length is not None:
@@ -154,7 +164,8 @@ class BertModel(HybridBlock):
                     policy=remat_pol)
             else:
                 x = layer(x, mask)
-        pooled = self.pooler(x[:, 0])
+        with jax.named_scope("mx.pooler"):
+            pooled = self.pooler(x[:, 0])
         return x, pooled
 
 
@@ -184,13 +195,17 @@ class BertForPretraining(HybridBlock):
     def forward(self, input_ids, token_types=None, valid_length=None,
                 masked_positions=None):
         seq, pooled = self.bert(input_ids, token_types, valid_length)
-        if masked_positions is not None:
-            # (b, l, h) -> (b, m, h) gather of the masked slots
-            seq = np.take_along_axis(
-                seq, np.expand_dims(masked_positions.astype("int32"), -1),
-                axis=1)
-        mlm = self.mlm_decoder(self.mlm_norm(npx.gelu(self.mlm_dense(seq))))
-        nsp = self.nsp_classifier(pooled)
+        with jax.named_scope("mx.mlm_head"):
+            if masked_positions is not None:
+                # (b, l, h) -> (b, m, h) gather of the masked slots
+                seq = np.take_along_axis(
+                    seq,
+                    np.expand_dims(masked_positions.astype("int32"), -1),
+                    axis=1)
+            mlm = self.mlm_decoder(
+                self.mlm_norm(npx.gelu(self.mlm_dense(seq))))
+        with jax.named_scope("mx.nsp_head"):
+            nsp = self.nsp_classifier(pooled)
         return mlm, nsp
 
     @staticmethod

@@ -303,6 +303,7 @@ def _flash_fwd(q, k, v, bias, seed, scale, causal, block_q, block_k,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=_interpret(),
+        name="mx_flash_attn_fwd",
     )(*args)
     return out.reshape(b, h, lq, d), lse
 
@@ -498,6 +499,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, g, scale, causal,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=interpret,
+        name="mx_flash_attn_bwd_dq",
     )(*args)
 
     # dkv grid: k-blocks parallel, q-blocks sequential innermost
@@ -533,6 +535,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, g, scale, causal,
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=interpret,
+        name="mx_flash_attn_bwd_dkv",
     )(*args2)
 
     return (dq.reshape(b, h, lq, d), dk.reshape(b, h, lk, d),

@@ -169,6 +169,7 @@ def _norm_pallas(x2, res2, gamma, beta, eps, rms, block_rows=None):
         out_shape=out_shape if has_res else out_shape[0],
         compiler_params=_compiler_params(pltpu),
         interpret=interpret_mode(),
+        name="mx_fused_norm",
     )(*args)
     if has_res:
         y, s = outs

@@ -208,9 +208,10 @@ def _run_elementwise_chunk(optimizer, w_flat, g_flat, slot_flats,
 
     br = _block_rows(total, w_flat.dtype)
     rows = ((max(1, (total + LANES - 1) // LANES) + br - 1) // br) * br
-    w2 = _to_grid(w_flat, rows)
-    g2 = _to_grid(g_flat, rows)
-    s2 = [_to_grid(s, rows) for s in slot_flats]
+    with jax.named_scope("mx.optimizer.pack"):
+        w2 = _to_grid(w_flat, rows)
+        g2 = _to_grid(g_flat, rows)
+        s2 = [_to_grid(s, rows) for s in slot_flats]
 
     hp_arrs, has_clip, has_skip = _hp_scalars(hp, skip)
     n_state = len(s2)
@@ -230,9 +231,11 @@ def _run_elementwise_chunk(optimizer, w_flat, g_flat, slot_flats,
         out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=interpret_mode(),
+        name="mx_fused_opt_update",
     )(*hp_arrs, w2, g2, *s2)
-    nw = outs[0].reshape(-1)[:total]
-    ns = [o.reshape(-1)[:total] for o in outs[1:]]
+    with jax.named_scope("mx.optimizer.pack"):
+        nw = outs[0].reshape(-1)[:total]
+        ns = [o.reshape(-1)[:total] for o in outs[1:]]
     return nw, ns
 
 
@@ -323,6 +326,7 @@ def _run_lamb_leaf(optimizer, w, g, s_old, hp, skip):
                    jax.ShapeDtypeStruct((nb, LANES), f32)],
         compiler_params=_compiler_params(),
         interpret=interpret_mode(),
+        name="mx_fused_lamb_moments",
     )(*hp_arrs, w2, g2, m2, v2)
 
     # scalar glue (device-side, a handful of flops — mirrors _rule)
@@ -343,6 +347,7 @@ def _run_lamb_leaf(optimizer, w, g, s_old, hp, skip):
         out_shape=jax.ShapeDtypeStruct((rows, LANES), w.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret_mode(),
+        name="mx_fused_lamb_apply",
     )(*hp_arrs, ratio.astype(f32).reshape(1, 1), w2, r2)]
     nw = nw2.reshape(-1)[:total].reshape(w.shape)
     nm = m_new2.reshape(-1)[:total].reshape(w.shape)
@@ -402,26 +407,30 @@ def apply_updates(optimizer, params: Dict[str, Any],
     for (_, slot_dtypes, treedef), members in groups.items():
         sizes = [params[n].size for n, _, _ in members]
         total = sum(sizes)
-        w_flat = jnp.concatenate(
-            [params[n].ravel() for n, _, _ in members])
-        g_flat = jnp.concatenate(
-            [grads[n].ravel() for n, _, _ in members])
         n_state = len(slot_dtypes)
-        slot_flats = [
-            jnp.concatenate([lv[k].ravel() for _, lv, _ in members])
-            for k in range(n_state)]
+        # `mx.optimizer.pack`: the copies around the kernel (concatenate
+        # and pad in, slice out), under one name in the op metadata
+        with jax.named_scope("mx.optimizer.pack"):
+            w_flat = jnp.concatenate(
+                [params[n].ravel() for n, _, _ in members])
+            g_flat = jnp.concatenate(
+                [grads[n].ravel() for n, _, _ in members])
+            slot_flats = [
+                jnp.concatenate([lv[k].ravel() for _, lv, _ in members])
+                for k in range(n_state)]
         nw, ns = _run_elementwise_chunk(
             optimizer, w_flat, g_flat, slot_flats,
             [jnp.dtype(d) for d in slot_dtypes], treedef, hp, skip,
             total)
-        off = 0
-        for (n, _, td), size in zip(members, sizes):
-            shape = params[n].shape
-            out_p[n] = nw[off:off + size].reshape(shape)
-            out_s[n] = td.unflatten(
-                [ns[k][off:off + size].reshape(shape)
-                 for k in range(n_state)])
-            off += size
+        with jax.named_scope("mx.optimizer.pack"):
+            off = 0
+            for (n, _, td), size in zip(members, sizes):
+                shape = params[n].shape
+                out_p[n] = nw[off:off + size].reshape(shape)
+                out_s[n] = td.unflatten(
+                    [ns[k][off:off + size].reshape(shape)
+                     for k in range(n_state)])
+                off += size
     return out_p, out_s
 
 
@@ -500,6 +509,7 @@ def _build(config, shapes, dtype):
                                             jnp.float32)],
             compiler_params=_compiler_params(),
             interpret=interpret_mode(),
+            name="mx_fused_opt_autotune",
         )(*hp_arrs, w2, g2, *s2)
         return outs
 

@@ -142,6 +142,7 @@ def _gather_rows_pallas(src, idx, scale=None):
         out_shape=jax.ShapeDtypeStruct((r, h), src.dtype),
         compiler_params=_compiler_params(pltpu),
         interpret=interpret_mode(),
+        name="mx_moe_dispatch",
     )(*args)
 
 

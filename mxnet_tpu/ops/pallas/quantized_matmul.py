@@ -347,6 +347,7 @@ def _qmm_pallas(x2, q, scale, bits: int, k: int, blocks=None):
         scratch_shapes=[_vmem((bm, bn), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret_mode(),
+        name="mx_quant_matmul",
     )(xpad, qpad, spad)
     return out[:M, :N]
 

@@ -114,6 +114,7 @@ def _xent_fwd(x, labels, block_n, block_v):
         ],
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret(),
+        name="mx_softmax_xent_fwd",
     )(x, lab)
     return loss[:, 0], lse[:, 0]
 
@@ -137,6 +138,7 @@ def _xent_bwd(x, labels, lse, g, block_n, block_v):
         out_shape=jax.ShapeDtypeStruct((n, v), x.dtype),
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret(),
+        name="mx_softmax_xent_bwd",
     )(x, lab, lse2, g2)
 
 

@@ -428,7 +428,8 @@ class ShardedTrainStep:
                 model_args = mb if n_model is None else mb[:n_model]
                 out, aux = functional_call(block, pv, *model_args,
                                            training=True, rng_key=mkey)
-                loss = loss_fn(out, *mb)
+                with jax.named_scope("mx.loss"):
+                    loss = loss_fn(out, *mb)
                 # a loss_fn written in mx.np ops returns a wrapped scalar;
                 # unwrap so value_and_grad sees a jax value
                 loss = getattr(loss, "_data", loss)
@@ -537,12 +538,14 @@ class ShardedTrainStep:
             # dp-sharded representation is storage-only
             states = {n: outer._unpack_state_tree(n, opt_state[n])
                       for n in diff_names}
-            upd_p, new_s = _fused_opt.apply_updates(
-                optimizer, {n: pvals[n] for n in diff_names}, grads,
-                states, hp, skip,
-                use_kernel=outer._fused_opt_kernel)
-            new_s = {n: outer._pack_state_tree(n, new_s[n], constrain=True)
-                     for n in new_s}
+            with jax.named_scope("mx.optimizer"):
+                upd_p, new_s = _fused_opt.apply_updates(
+                    optimizer, {n: pvals[n] for n in diff_names}, grads,
+                    states, hp, skip,
+                    use_kernel=outer._fused_opt_kernel)
+                new_s = {n: outer._pack_state_tree(n, new_s[n],
+                                                   constrain=True)
+                         for n in new_s}
             new_p.update(upd_p)
             if skip is not None:
                 aux = {k: jnp.where(skip, pvals[k], v) if k in pvals else v

@@ -10,7 +10,9 @@ every imperative op dispatched through `apply_op` is timed (the reference
 equivalently wraps each engine op when profiling is on,
 `src/engine/threaded_engine.cc:288`), and user scopes
 (`ProfileTask`/`scope`) record into the same table. User scopes map to
-`jax.profiler.TraceAnnotation` for the trace view.
+`jax.profiler.TraceAnnotation` for the trace view, through
+`tracing.annotation` (the one place the program writes into the
+profiler's trace).
 """
 from __future__ import annotations
 
@@ -21,6 +23,8 @@ import time
 from typing import Optional
 
 import jax
+
+from . import tracing as _tracing
 
 __all__ = [
     "set_config", "start", "stop", "pause", "resume", "dump", "dumps",
@@ -231,7 +235,7 @@ class scope:
         self._t0 = None
 
     def __enter__(self):
-        self._t = jax.profiler.TraceAnnotation(self._name)
+        self._t = _tracing.annotation(self._name)
         self._t.__enter__()
         self._t0 = time.perf_counter()
         return self

@@ -230,38 +230,48 @@ def transformer_step(P: dict, cfg, tok, pos,
             return x
         return jax.lax.all_gather(x, tp_axis, axis=axis, tiled=True)
 
-    h = gather_rows(P["embed"], tok)                     # (B, C, E)
-    if not use_rope:
-        h = h + P["pos"][pos]
+    # `jax.named_scope`s (trace-time only) give the blocks stable names
+    # in the compiled program's op metadata, whatever shapes they take
+    with jax.named_scope("mx.serve.embed"):
+        h = gather_rows(P["embed"], tok)                 # (B, C, E)
+        if not use_rope:
+            h = h + P["pos"][pos]
     for li, L in enumerate(P["layers"]):
-        a = layer_norm(h, L["ln1_g"], L["ln1_b"], eps)
-        qkv = matmul_nt(a, L["wqkv"]) + L["bqkv"]
-        q = qkv[..., :El].reshape(B, C, Hl, D).transpose(0, 2, 1, 3)
-        k = qkv[..., El:El + kvwl].reshape(
-            B, C, Hkvl, D).transpose(0, 2, 1, 3)
-        v = qkv[..., El + kvwl:].reshape(
-            B, C, Hkvl, D).transpose(0, 2, 1, 3)
-        if use_rope:
-            from ..ops.attention import rope_rotate
-            # same rotation helper as the full forward; cached keys are
-            # stored pre-rotated.  Rotation is per-head-dim, identical
-            # for every head — shard-local heads rotate exactly as the
-            # same heads do at tp=1.
-            q = rope_rotate(q, pos[:, None, :], cfg.rope_theta)
-            k = rope_rotate(k, pos[:, None, :], cfg.rope_theta)
+        with jax.named_scope("mx.serve.qkv"):
+            a = layer_norm(h, L["ln1_g"], L["ln1_b"], eps)
+            qkv = matmul_nt(a, L["wqkv"]) + L["bqkv"]
+            q = qkv[..., :El].reshape(B, C, Hl, D).transpose(0, 2, 1, 3)
+            k = qkv[..., El:El + kvwl].reshape(
+                B, C, Hkvl, D).transpose(0, 2, 1, 3)
+            v = qkv[..., El + kvwl:].reshape(
+                B, C, Hkvl, D).transpose(0, 2, 1, 3)
+            if use_rope:
+                from ..ops.attention import rope_rotate
+                # same rotation helper as the full forward; cached keys
+                # are stored pre-rotated.  Rotation is per-head-dim,
+                # identical for every head — shard-local heads rotate
+                # exactly as the same heads do at tp=1.
+                q = rope_rotate(q, pos[:, None, :], cfg.rope_theta)
+                k = rope_rotate(k, pos[:, None, :], cfg.rope_theta)
+        # the paged kv_fn scopes its own halves (`mx.serve.pool_write`,
+        # `mx.serve.paged_attn`)
         ctx = kv_fn(li, q, k, v)                          # (B, Hl, C, D)
-        # all-gather the head axis (contiguous head blocks -> original
-        # order), then the out-proj runs its full contraction against
-        # the local OUT-dim rows of wo; gather the partial out columns
-        ctx = gather(ctx, 1)
-        attn = matmul_nt(ctx.transpose(0, 2, 1, 3).reshape(B, C, E),
-                         L["wo"])
-        h = h + gather(attn, -1) + L["bo"]
-        f = layer_norm(h, L["ln2_g"], L["ln2_b"], eps)
-        inter = jax.nn.gelu(matmul_nt(f, L["w1"]) + L["b1"])
-        h = h + gather(matmul_nt(gather(inter, -1), L["w2"]), -1) \
-            + L["b2"]
-    return layer_norm(h, P["lnf_g"], P["lnf_b"], eps)
+        with jax.named_scope("mx.serve.attn_out"):
+            # all-gather the head axis (contiguous head blocks ->
+            # original order), then the out-proj runs its full
+            # contraction against the local OUT-dim rows of wo; gather
+            # the partial out columns
+            ctx = gather(ctx, 1)
+            attn = matmul_nt(ctx.transpose(0, 2, 1, 3).reshape(B, C, E),
+                             L["wo"])
+            h = h + gather(attn, -1) + L["bo"]
+        with jax.named_scope("mx.serve.mlp"):
+            f = layer_norm(h, L["ln2_g"], L["ln2_b"], eps)
+            inter = jax.nn.gelu(matmul_nt(f, L["w1"]) + L["b1"])
+            h = h + gather(matmul_nt(gather(inter, -1), L["w2"]), -1) \
+                + L["b2"]
+    with jax.named_scope("mx.serve.final_norm"):
+        return layer_norm(h, P["lnf_g"], P["lnf_b"], eps)
 
 
 def lm_logits(P: dict, h, tp: int = 1, tp_axis: Optional[str] = None):

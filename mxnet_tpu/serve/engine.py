@@ -244,6 +244,9 @@ class InferenceEngine:
         self._key = jax.random.PRNGKey(seed)
         self.compile_seconds = None
         self._steps_executed = 0
+        #: `perf_counter` instant the last step's executable call
+        #: returned (`_execute`): the scheduler's launch/wait boundary
+        self.launched_ts = 0.0
         self._note_weight_bytes()
         _health.beat("serve.step")   # announce the heartbeat name early
 
@@ -469,14 +472,16 @@ class InferenceEngine:
             h = transformer_step(P, cfg, tok, pos, kv_fn,
                                  tp=tp, tp_axis=tp_axis)
             B = tok.shape[0]
-            last = h[jnp.arange(B), jnp.maximum(num_tokens - 1, 0)]
-            logits = lm_logits(P, last, tp, tp_axis)          # (B, V)
-            greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            filtered = _filter_logits(
-                logits.astype(jnp.float32) / temps[:, None], top_k, top_p)
-            sampled = jax.random.categorical(
-                key, filtered, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(greedy_mask, greedy_tok, sampled)
+            with jax.named_scope("mx.serve.sample"):
+                last = h[jnp.arange(B), jnp.maximum(num_tokens - 1, 0)]
+                logits = lm_logits(P, last, tp, tp_axis)      # (B, V)
+                greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                filtered = _filter_logits(
+                    logits.astype(jnp.float32) / temps[:, None],
+                    top_k, top_p)
+                sampled = jax.random.categorical(
+                    key, filtered, axis=-1).astype(jnp.int32)
+                nxt = jnp.where(greedy_mask, greedy_tok, sampled)
             if spec_k > 0:
                 # speculative verification: the greedy argmax at the
                 # TAIL fed positions (B, T), T = min(C, k+1) — the emit
@@ -800,7 +805,11 @@ class InferenceEngine:
         """Run one fused step (called by the scheduler); returns
         ``(next_token[B], all_tok)`` as host numpy — `all_tok` is the
         (B, C) per-position greedy argmax when speculation is enabled,
-        else None."""
+        else None.  Leaves in ``self.launched_ts`` the `perf_counter`
+        instant the executable's call returned: the boundary between
+        the step's ``launch`` phase (key split, seven host-to-device
+        transfers, dispatch) and its ``wait`` (blocking on the tokens),
+        which the scheduler reads after the call."""
         ex = self._execs.get(C)
         if ex is None:
             ex = self._compile(C)
@@ -810,22 +819,25 @@ class InferenceEngine:
             from ..resilience import fault_point
             fault_point("tp_collective")
         self._steps_executed += 1
-        self._key, sub = jax.random.split(self._key)
-        with self._device_lock:
-            out = ex(
-                self.P, self.pools.as_tuple(), jnp.asarray(tok),
-                jnp.asarray(num_tokens), jnp.asarray(start_pos),
-                jnp.asarray(tables), jnp.asarray(ctx_lens),
-                jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
-            if self.serve_config.spec_tokens > 0:
-                out_pools, nxt, all_tok = out
-            else:
-                (out_pools, nxt), all_tok = out, None
-            # rebind the donated pool buffers to the step's outputs
-            self.pools = self.pools.replace(out_pools)
-        return (onp.asarray(jax.device_get(nxt)),
-                None if all_tok is None
-                else onp.asarray(jax.device_get(all_tok)))
+        with _trace.annotation("serve.step.launch"):
+            self._key, sub = jax.random.split(self._key)
+            with self._device_lock:
+                out = ex(
+                    self.P, self.pools.as_tuple(), jnp.asarray(tok),
+                    jnp.asarray(num_tokens), jnp.asarray(start_pos),
+                    jnp.asarray(tables), jnp.asarray(ctx_lens),
+                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
+                if self.serve_config.spec_tokens > 0:
+                    out_pools, nxt, all_tok = out
+                else:
+                    (out_pools, nxt), all_tok = out, None
+                # rebind the donated pool buffers to the step's outputs
+                self.pools = self.pools.replace(out_pools)
+            self.launched_ts = time.perf_counter()
+        with _trace.annotation("serve.step.wait"):
+            return (onp.asarray(jax.device_get(nxt)),
+                    None if all_tok is None
+                    else onp.asarray(jax.device_get(all_tok)))
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device-copy ONE physical page (every layer, K + V + scale
@@ -964,4 +976,8 @@ class InferenceEngine:
             "spec": self.scheduler.spec_stats(),
             "prefix_cache": (None if self.prefix_index is None
                              else self.prefix_index.stats()),
+            # where the last steps' host time went (median and max a
+            # phase; the five longest steps with their phase split) —
+            # stamped on every step, traced or not
+            **self.scheduler.phase_stats(),
         }

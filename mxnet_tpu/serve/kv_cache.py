@@ -474,6 +474,16 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     ps = page_size
 
     def kv_fn(li, q, k_new, v_new):
+        with jax.named_scope("mx.serve.pool_write"):
+            write(li, k_new, v_new)
+        with jax.named_scope("mx.serve.paged_attn"):
+            return ragged_paged_attention(
+                q, pools["k"], pools["v"], page_tables, ctx_lens,
+                start_pos, window=window, layer=li,
+                k_scales=pools["k_scale"] if quantized else None,
+                v_scales=pools["v_scale"] if quantized else None)
+
+    def write(li, k_new, v_new):
         B, Hkv, C, D = k_new.shape
         pos = start_pos[:, None] + jnp.arange(C)[None, :]      # (B, C)
         logical = jnp.minimum(pos // ps, page_tables.shape[1] - 1)
@@ -504,10 +514,5 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
 
         scatter("k", k_new)
         scatter("v", v_new)
-        return ragged_paged_attention(
-            q, pools["k"], pools["v"], page_tables, ctx_lens,
-            start_pos, window=window, layer=li,
-            k_scales=pools["k_scale"] if quantized else None,
-            v_scales=pools["v_scale"] if quantized else None)
 
     return kv_fn

@@ -37,6 +37,7 @@ replica death bit-identical and never re-emit a token.
 from __future__ import annotations
 
 import itertools
+import statistics
 import threading
 import time
 from collections import deque
@@ -46,6 +47,7 @@ import numpy as onp
 
 from ..base import MXNetError
 from ..resilience import fault_point
+from .. import health as _health
 from .. import telemetry as _tele
 from .. import tracing as _trace
 from . import qos as _qos
@@ -233,8 +235,7 @@ def expire_request(req: ServeRequest, where: str,
 def deliver_token(req: ServeRequest, token: int,
                   replica: Optional[str] = None) -> bool:
     """Mirror ONE streamed token onto a request handle: append, TTFT
-    bookkeeping, telemetry, the `on_token` callback, and the
-    ``serve.stream`` span.  Returns True when this token completed the
+    bookkeeping, telemetry, the `on_token` callback.  Returns True when this token completed the
     request (``max_new_tokens`` reached or EOS) — the caller owns the
     finish.  Shared by the in-process scheduler's emit path and the
     process fleet's parent-side stream ledger (`ProcessReplica`), so a
@@ -256,7 +257,6 @@ def deliver_token(req: ServeRequest, token: int,
     if _tele.enabled():
         _tele.counter("serve_tokens_generated_total",
                       "Tokens generated across all requests").inc()
-    ts0 = time.perf_counter() if req._span is not None else 0.0
     if req.on_token is not None:
         try:
             req.on_token(token, req)
@@ -264,11 +264,6 @@ def deliver_token(req: ServeRequest, token: int,
             import logging
             logging.getLogger(__name__).exception(
                 "serve: on_token callback failed (request %d)", req.id)
-    if req._span is not None:
-        _trace.get_tracer("serve").record_span(
-            "serve.stream", ts0, time.perf_counter(),
-            parent=req._span.context(), track=f"serve req {req.id}",
-            request_id=req.id, token_index=len(req.tokens) - 1)
     return len(req.tokens) >= req.max_new_tokens or (
         req.eos_token_id is not None and token == req.eos_token_id)
 
@@ -338,6 +333,10 @@ class ContinuousBatchingScheduler:
     `submit` is thread-safe; stepping is single-threaded by design (one
     device stream)."""
 
+    #: the phases of one `step`, in order: the ``serve.step.<phase>``
+    #: spans tile ``serve.step`` (docs/observability.md)
+    STEP_PHASES = ("admit", "plan", "launch", "wait", "emit")
+
     def __init__(self, engine):
         self.engine = engine
         cfg = engine.serve_config
@@ -379,7 +378,13 @@ class ContinuousBatchingScheduler:
         self.tokens_emitted = 0      # tokens streamed (all requests)
         self.prefix_hit_tokens = 0   # prompt tokens attached from cache
         self.cow_forks = 0           # shared pages forked before a write
-        self._span_prefix_hit = 0    # admitted since the last step span
+        # per-step phase log (`phase_stats`): each step's clock stamps at
+        # its phase boundaries + its counts, traced or not; running
+        # totals (`_totals`) give a step its counts by difference
+        self._phase_log: deque = deque(maxlen=1024)
+        self._phase_lock = threading.Lock()
+        self._n_admitted = self._n_evicted = 0
+        self._n_expired = self._n_finished = 0
         #: replica identity in a fleet (None outside one): tags request
         #: journal events, step spans, and the per-replica gauges
         self.name: Optional[str] = None
@@ -476,9 +481,9 @@ class ContinuousBatchingScheduler:
     # "serve.queue" (submit -> admit, re-opened on eviction), one
     # "serve.prefill_chunk"/"serve.decode"/"serve.first_decode" span per
     # fused step the request took part in (tagged with slot and page
-    # ids), and a "serve.stream" span per emitted token.  All sites
-    # guard on _trace.enabled(): tracing off costs two None attributes
-    # per request.
+    # ids; the callbacks' time is inside the step's "serve.step.emit"
+    # span).  All sites guard on _trace.enabled(): tracing off costs two
+    # None attributes per request.
 
     def _trace_submit(self, req: ServeRequest) -> None:
         if not _trace.enabled():
@@ -625,6 +630,7 @@ class ContinuousBatchingScheduler:
                     self._slots[idx] = slot
                     req.state = "running"
                     self.handoffs_in += 1
+                    self._n_admitted += 1
                     self._trace_admit(req, idx, len(slot.pages))
                     self._telemetry_request(req, "adopted", slot=idx,
                                             pages=len(slot.pages),
@@ -668,10 +674,10 @@ class ContinuousBatchingScheduler:
                         req.tenant,
                         len(seq) + req.max_new_tokens - len(req.tokens))
             req.state = "running"
+            self._n_admitted += 1
             if hit:
                 req.prefix_hits += hit
                 self.prefix_hit_tokens += hit
-                self._span_prefix_hit += hit
                 if _tele.enabled():
                     _tele.counter(
                         "serve_prefix_hit_tokens_total",
@@ -696,6 +702,7 @@ class ContinuousBatchingScheduler:
         self._release_slot(slot)
         req.state = "queued"
         req.evictions += 1
+        self._n_evicted += 1
         self._trace_requeue(req, reason)
         with self._lock:
             self._queue.appendleft(req)
@@ -820,6 +827,7 @@ class ContinuousBatchingScheduler:
             self._update_gauges()
 
     def _expire_req(self, req: ServeRequest, where: str) -> None:
+        self._n_expired += 1
         expire_request(req, where, replica=self.name)
 
     # ------------------------------------------------------------------
@@ -827,118 +835,47 @@ class ContinuousBatchingScheduler:
         """Run one fused serving step over the active slots.  Returns
         False when there was nothing to do (no actives, empty queue).
 
-        The host-side halves (plan/admit before, emit after) hold
+        The host-side halves (admit/plan before, emit after) hold
         ``_step_lock``; the device call runs outside it so a fleet
-        supervisor can `salvage()` a replica whose step has wedged."""
+        supervisor can `salvage()` a replica whose step has wedged.
+
+        Every step reads the clock at its phase boundaries
+        (`STEP_PHASES`: admit → plan → launch → wait → emit) and keeps
+        the stamps with the step's counts in a bounded log
+        (`phase_stats`, `engine.stats()["step_phases"]`) — where a stall
+        went, in a run nobody traced.  Only while `tracing.capturing()`
+        do the same stamps become a ``serve.step`` span tiled by five
+        ``serve.step.<phase>`` children, in the ``serve`` ring and as
+        annotations in the profiler's own trace."""
+        t_in = time.perf_counter()
+        with _trace.annotation("serve.step"):
+            return self._step(t_in)
+
+    def _step(self, t_in: float) -> bool:
         with self._step_lock:
             if self._abandoned:
                 return False
-            self._expire_deadlines()
-            self._admit()
-            actives = [s for s in self._slots if s is not None]
-            if not actives:
+            with _trace.annotation("serve.step.admit"):
+                queued = len(self._queue)
+                before = self._totals()
+                self._expire_deadlines()
+                self._admit()
+            t_admit = time.perf_counter()
+            with _trace.annotation("serve.step.plan"):
+                batch = self._plan()
+            if batch is None:
                 self._update_gauges()
                 return False
+            C, plan, actives, arrays = batch
 
-            # plan the chunk width: any slot with >1 pending token
-            # prefills, so the step runs at the prefill chunk width; a
-            # pure-decode round runs the C=1 program — unless the
-            # drafter proposed tokens, in which case it runs the k+1
-            # verification width (no padded-lane compute otherwise).
-            pending = {s.slot_idx: len(s.req._sequence()) - s.ctx
-                       for s in actives}
-            any_prefill = any(p > 1 for p in pending.values())
-
-            # speculative drafts: any GREEDY slot whose feed reaches the
-            # end of its sequence this round (pure decode, or the last
-            # prefill chunk with spare width) carries up to k proposed
-            # tokens after its real feed — verified by the same launch
-            spec_k = self.engine.serve_config.spec_tokens
-            drafter = self.engine.drafter
-            proposals = {}
-            if spec_k > 0 and drafter is not None:
-                cmax = self.prefill_chunk if any_prefill else spec_k + 1
-                for s in actives:
-                    req = s.req
-                    p = pending[s.slot_idx]
-                    if not req.greedy or not 1 <= p <= cmax - 1:
-                        continue
-                    seq = req._sequence()
-                    k_eff = min(spec_k, cmax - p,
-                                req.max_new_tokens - len(req.tokens) - 1,
-                                self.max_len - len(seq))
-                    if k_eff <= 0:
-                        continue
-                    d = drafter.propose(seq, k_eff)
-                    if d:
-                        proposals[s.slot_idx] = \
-                            [int(t) for t in d[:k_eff]]
-            if any_prefill:
-                C = self.prefill_chunk
-            elif proposals:
-                C = spec_k + 1
-            else:
-                C = 1
-
-            # capacity: every slot must hold its chunk's tokens (drafts
-            # included — rejected ones roll back through the free list
-            # after verification); slots that cannot (even after
-            # evicting younger actives) are evicted themselves this
-            # round.  The COW guard then forks any still-shared page in
-            # the write range before the step scatters into it.
-            for s in sorted(actives, key=lambda s: s.admit_seq):
-                if self._slots[s.slot_idx] is not s:
-                    continue      # already evicted by a victim search
-                nt = min(pending[s.slot_idx], C) \
-                    + len(proposals.get(s.slot_idx, ()))
-                if not self._ensure_capacity(s, s.ctx + nt) or \
-                        not self._cow_guard(s, s.ctx, s.ctx + nt - 1):
-                    self._evict(s, reason="no_capacity")
-            actives = [s for s in self._slots if s is not None]
-            if not actives:
-                self._update_gauges()
-                return False
-
-            B = self.max_slots
-            tok = onp.zeros((B, C), onp.int32)
-            num_tokens = onp.zeros(B, onp.int32)
-            start_pos = onp.zeros(B, onp.int32)
-            tables = onp.zeros((B, self.max_pages_per_seq), onp.int32)
-            ctx_lens = onp.zeros(B, onp.int32)
-            temps = onp.ones(B, onp.float32)
-            greedy = onp.ones(B, bool)
-            plan = {}
-            for s in actives:
-                seq = s.req._sequence()
-                nt_seq = min(len(seq) - s.ctx, C)
-                draft = proposals.get(s.slot_idx, []) \
-                    if s.ctx + nt_seq == len(seq) else []
-                feed = seq[s.ctx:s.ctx + nt_seq] + draft
-                nt = len(feed)
-                i = s.slot_idx
-                tok[i, :nt] = feed
-                num_tokens[i] = nt
-                start_pos[i] = s.ctx
-                tables[i] = s.table
-                ctx_lens[i] = s.ctx + nt
-                temps[i] = s.req.temperature
-                greedy[i] = s.req.greedy
-                plan[i] = {"slot": s, "feed": feed, "nt": nt,
-                           "nt_seq": nt_seq, "ctx0": s.ctx,
-                           "draft": len(draft), "emitted": 0,
-                           "consume": s.ctx + nt_seq == len(seq)}
-                s.ctx += nt
-
-        t0 = time.perf_counter()
+        t_plan = time.perf_counter()
         try:
             # chaos point (docs/resilience.md): MXTPU_FAULT_SPEC
             # `replica_step` simulates a replica dying mid-step on live
             # traffic — slot.ctx has already advanced past tokens that
             # will never land, the hardest failover shape
             fault_point("replica_step")
-            next_tokens, all_tok = self.engine._execute(
-                tok, num_tokens, start_pos, tables, ctx_lens, temps,
-                greedy, C)
+            next_tokens, all_tok = self.engine._execute(*arrays, C)
         except Exception as exc:
             with self._step_lock:
                 if self._abandoned:
@@ -954,117 +891,310 @@ class ContinuousBatchingScheduler:
                 # untouched — the driver catches this raise and the fleet
                 # salvages them onto a surviving replica
             raise
-        t1 = time.perf_counter()
+        t_wait = time.perf_counter()
+        # the launch/wait boundary is the engine's stamp (a stand-in
+        # `_execute` that leaves none reads as all wait)
+        t_launch = min(max(self.engine.launched_ts, t_plan), t_wait)
         with self._step_lock:
             if self._abandoned:
                 # salvaged mid-execute: the requests now live on another
                 # replica — emitting here would double-stream tokens
                 return False
-            step_ms = (t1 - t0) * 1e3
-            self._steps += 1
-            from .. import health as _health
-            _health.beat("serve.step")
-            if _tele.enabled():
-                _tele.histogram(
-                    "serve_step_ms",
-                    "Wall time per fused serving step (prefill or decode)"
-                ).observe(step_ms)
-                _tele.counter("serve_steps_total",
-                              "Fused serving steps executed").inc()
-                # FLOP attribution: this width's executable cost +
-                # measured wall -> mfu_estimate{program="serve_step"}
-                _trace.note_step_cost(
-                    f"serve_step_c{C}@{id(self.engine):x}", step_ms / 1e3)
-
-            # register just-prefilled prompts in the prefix cache BEFORE
-            # emitting (emits can finish a request and release its
-            # pages): the slot's pages hold the complete prompt KV once
-            # the write cursor passed the prompt
-            index = self.engine.prefix_index
-            if index is not None:
-                for s in actives:
-                    if s.prefix_inserted or \
-                            self._slots[s.slot_idx] is not s:
-                        continue
-                    if s.ctx >= len(s.req.prompt):
-                        index.insert(s.req.prompt, s.pages)
-                        s.prefix_inserted = True
-
-            # snapshot span parents before emitting: finishing a request
-            # closes its root span, but the post-hoc phase spans below
-            # still decompose its timeline
-            parents = {}
-            if _trace.enabled():
-                for i, pl in plan.items():
-                    req = pl["slot"].req
-                    parents[i] = (None if req._span is None
-                                  else req._span.context(),
-                                  bool(req.tokens))
-
-            # distribute tokens in admission order (stable streaming).
-            # A speculating slot emits its whole accepted run — the fed
-            # position's greedy token, then each draft that matched it —
-            # and rolls its write cursor back past the rejected rest.
-            drafted_step = accepted_step = emitted_total = 0
-            for s in sorted(actives, key=lambda s: s.admit_seq):
-                i = s.slot_idx
-                pl = plan[i]
-                if not pl["consume"]:
-                    continue      # mid-prefill: logits discarded
-                if self._slots[i] is not s:
-                    continue      # expired/terminated while executing
-                if all_tok is not None and s.req.greedy:
-                    feed, nt = pl["feed"], pl["nt"]
-                    # all_tok column t holds fed position nt - T + t
-                    # (the engine computes the verify argmax only for
-                    # the tail T = min(C, k+1) positions — all the emit
-                    # loop can ever read)
-                    T = all_tok.shape[1]
-                    emitted = 0
-                    for j in range(pl["nt_seq"] - 1, nt):
-                        tokj = int(all_tok[i, j - nt + T])
-                        self._emit(s, tokj)
-                        emitted += 1
-                        if self._slots[i] is not s or s.req.done():
-                            break      # finished (max_new / eos)
-                        if j + 1 < nt and feed[j + 1] != tokj:
-                            break      # draft rejected: stop the run
-                    pl["emitted"] = emitted
-                    drafted_step += pl["draft"]
-                    accepted_step += emitted - 1
-                    if pl["draft"] and drafter is not None:
-                        drafter.note_result(pl["draft"], emitted - 1)
-                    if self._slots[i] is s:
-                        # roll back past rejected drafts: the cursor
-                        # returns to the last ACCEPTED token's position
-                        # and the pages holding only rejected KV go
-                        # back to the free list
-                        s.ctx = pl["ctx0"] + pl["nt_seq"] + emitted - 1
-                        self._trim_pages(s)
-                else:
-                    self._emit(s, int(next_tokens[i]))
-                    pl["emitted"] = 1
-                emitted_total += pl["emitted"]
-            self.tokens_emitted += emitted_total
-            self.spec_proposed += drafted_step
-            self.spec_accepted += accepted_step
-            if _tele.enabled() and drafted_step:
-                _tele.counter(
-                    "serve_spec_proposed_total",
-                    "Draft tokens fed for verification").inc(drafted_step)
-                if accepted_step > 0:
-                    _tele.counter(
-                        "serve_spec_accepted_total",
-                        "Draft tokens accepted (matched the greedy "
-                        "continuation)").inc(accepted_step)
-            if self.engine.role == "prefill":
-                self._detach_prefilled(actives)
-            if _trace.enabled():
-                self._trace_step(plan, parents, t0, t1, C,
-                                 drafted_step, accepted_step,
-                                 emitted_total)
-            self._update_gauges()
+            with _trace.annotation("serve.step.emit"):
+                step_ms = (t_wait - t_plan) * 1e3
+                self._steps += 1
+                _health.beat("serve.step")
+                if _tele.enabled():
+                    _tele.histogram(
+                        "serve_step_ms",
+                        "Wall time per fused serving step (prefill or "
+                        "decode)").observe(step_ms)
+                    _tele.counter("serve_steps_total",
+                                  "Fused serving steps executed").inc()
+                    # FLOP attribution: this width's executable cost +
+                    # measured wall -> mfu_estimate{program="serve_step"}
+                    _trace.note_step_cost(
+                        f"serve_step_c{C}@{id(self.engine):x}",
+                        step_ms / 1e3)
+                drafted, accepted, emitted = self._emit_step(
+                    plan, actives, next_tokens, all_tok, t_plan, t_wait)
+                self._update_gauges()
+            self._note_step(
+                C, (t_in, t_admit, t_plan, t_launch, t_wait,
+                    time.perf_counter()),
+                {"active": len(plan),
+                 "tokens_fed": sum(pl["nt"] for pl in plan.values()),
+                 "emitted": emitted, "drafted": drafted,
+                 "accepted": accepted, "queue_depth": queued,
+                 "h2d_bytes": sum(a.nbytes for a in arrays),
+                 **{k: now - was for k, was, now in zip(
+                     self._COUNTED, before, self._totals())}})
         return True
+
+    #: a step's counts taken as differences of running totals
+    _COUNTED = ("admitted", "evicted", "expired", "finished",
+                "prefix_hit", "cow_forks")
+
+    def _totals(self) -> tuple:
+        return (self._n_admitted, self._n_evicted, self._n_expired,
+                self._n_finished, self.prefix_hit_tokens, self.cow_forks)
+
+    def _plan(self):
+        """Build one step's numpy batch over the active slots: the chunk
+        width, speculative drafts, page capacity (evicting where the
+        pool is dry), the copy-on-write guard, and the per-slot feeds
+        and page tables.  Returns ``(C, plan, actives, arrays)`` —
+        `arrays` in `engine._execute`'s order — or None when no slot is
+        left to run.  Holding ``_step_lock``."""
+        actives = [s for s in self._slots if s is not None]
+        if not actives:
+            return None
+
+        # plan the chunk width: any slot with >1 pending token
+        # prefills, so the step runs at the prefill chunk width; a
+        # pure-decode round runs the C=1 program — unless the
+        # drafter proposed tokens, in which case it runs the k+1
+        # verification width (no padded-lane compute otherwise).
+        pending = {s.slot_idx: len(s.req._sequence()) - s.ctx
+                   for s in actives}
+        any_prefill = any(p > 1 for p in pending.values())
+
+        # speculative drafts: any GREEDY slot whose feed reaches the
+        # end of its sequence this round (pure decode, or the last
+        # prefill chunk with spare width) carries up to k proposed
+        # tokens after its real feed — verified by the same launch
+        spec_k = self.engine.serve_config.spec_tokens
+        drafter = self.engine.drafter
+        proposals = {}
+        if spec_k > 0 and drafter is not None:
+            cmax = self.prefill_chunk if any_prefill else spec_k + 1
+            for s in actives:
+                req = s.req
+                p = pending[s.slot_idx]
+                if not req.greedy or not 1 <= p <= cmax - 1:
+                    continue
+                seq = req._sequence()
+                k_eff = min(spec_k, cmax - p,
+                            req.max_new_tokens - len(req.tokens) - 1,
+                            self.max_len - len(seq))
+                if k_eff <= 0:
+                    continue
+                d = drafter.propose(seq, k_eff)
+                if d:
+                    proposals[s.slot_idx] = \
+                        [int(t) for t in d[:k_eff]]
+        if any_prefill:
+            C = self.prefill_chunk
+        elif proposals:
+            C = spec_k + 1
+        else:
+            C = 1
+
+        # capacity: every slot must hold its chunk's tokens (drafts
+        # included — rejected ones roll back through the free list
+        # after verification); slots that cannot (even after
+        # evicting younger actives) are evicted themselves this
+        # round.  The COW guard then forks any still-shared page in
+        # the write range before the step scatters into it.
+        for s in sorted(actives, key=lambda s: s.admit_seq):
+            if self._slots[s.slot_idx] is not s:
+                continue      # already evicted by a victim search
+            nt = min(pending[s.slot_idx], C) \
+                + len(proposals.get(s.slot_idx, ()))
+            if not self._ensure_capacity(s, s.ctx + nt) or \
+                    not self._cow_guard(s, s.ctx, s.ctx + nt - 1):
+                self._evict(s, reason="no_capacity")
+        actives = [s for s in self._slots if s is not None]
+        if not actives:
+            return None
+
+        B = self.max_slots
+        tok = onp.zeros((B, C), onp.int32)
+        num_tokens = onp.zeros(B, onp.int32)
+        start_pos = onp.zeros(B, onp.int32)
+        tables = onp.zeros((B, self.max_pages_per_seq), onp.int32)
+        ctx_lens = onp.zeros(B, onp.int32)
+        temps = onp.ones(B, onp.float32)
+        greedy = onp.ones(B, bool)
+        plan = {}
+        for s in actives:
+            seq = s.req._sequence()
+            nt_seq = min(len(seq) - s.ctx, C)
+            draft = proposals.get(s.slot_idx, []) \
+                if s.ctx + nt_seq == len(seq) else []
+            feed = seq[s.ctx:s.ctx + nt_seq] + draft
+            nt = len(feed)
+            i = s.slot_idx
+            tok[i, :nt] = feed
+            num_tokens[i] = nt
+            start_pos[i] = s.ctx
+            tables[i] = s.table
+            ctx_lens[i] = s.ctx + nt
+            temps[i] = s.req.temperature
+            greedy[i] = s.req.greedy
+            plan[i] = {"slot": s, "feed": feed, "nt": nt,
+                       "nt_seq": nt_seq, "ctx0": s.ctx,
+                       "draft": len(draft), "emitted": 0,
+                       "consume": s.ctx + nt_seq == len(seq)}
+            s.ctx += nt
+        return C, plan, actives, (tok, num_tokens, start_pos, tables,
+                                  ctx_lens, temps, greedy)
+
+    def _emit_step(self, plan, actives, next_tokens, all_tok,
+                   t_plan: float, t_wait: float):
+        """After the device step: register just-prefilled prompts in the
+        prefix cache, hand each slot its token(s) (`on_token` fires
+        here), roll rejected drafts back, detach prefilled slots on a
+        prefill-role engine.  Returns ``(drafted, accepted, emitted)``
+        of this step.  Holding ``_step_lock``."""
+        # register just-prefilled prompts in the prefix cache BEFORE
+        # emitting (emits can finish a request and release its
+        # pages): the slot's pages hold the complete prompt KV once
+        # the write cursor passed the prompt
+        index = self.engine.prefix_index
+        if index is not None:
+            for s in actives:
+                if s.prefix_inserted or \
+                        self._slots[s.slot_idx] is not s:
+                    continue
+                if s.ctx >= len(s.req.prompt):
+                    index.insert(s.req.prompt, s.pages)
+                    s.prefix_inserted = True
+
+        # snapshot span parents before emitting: finishing a request
+        # closes its root span, but the post-hoc phase spans below
+        # still decompose its timeline
+        parents = {}
+        if _trace.enabled():
+            for i, pl in plan.items():
+                req = pl["slot"].req
+                parents[i] = (None if req._span is None
+                              else req._span.context(),
+                              bool(req.tokens))
+
+        # distribute tokens in admission order (stable streaming).
+        # A speculating slot emits its whole accepted run — the fed
+        # position's greedy token, then each draft that matched it —
+        # and rolls its write cursor back past the rejected rest.
+        drafter = self.engine.drafter
+        drafted_step = accepted_step = emitted_total = 0
+        for s in sorted(actives, key=lambda s: s.admit_seq):
+            i = s.slot_idx
+            pl = plan[i]
+            if not pl["consume"]:
+                continue      # mid-prefill: logits discarded
+            if self._slots[i] is not s:
+                continue      # expired/terminated while executing
+            if all_tok is not None and s.req.greedy:
+                feed, nt = pl["feed"], pl["nt"]
+                # all_tok column t holds fed position nt - T + t
+                # (the engine computes the verify argmax only for
+                # the tail T = min(C, k+1) positions — all the emit
+                # loop can ever read)
+                T = all_tok.shape[1]
+                emitted = 0
+                for j in range(pl["nt_seq"] - 1, nt):
+                    tokj = int(all_tok[i, j - nt + T])
+                    self._emit(s, tokj)
+                    emitted += 1
+                    if self._slots[i] is not s or s.req.done():
+                        break      # finished (max_new / eos)
+                    if j + 1 < nt and feed[j + 1] != tokj:
+                        break      # draft rejected: stop the run
+                pl["emitted"] = emitted
+                drafted_step += pl["draft"]
+                accepted_step += emitted - 1
+                if pl["draft"] and drafter is not None:
+                    drafter.note_result(pl["draft"], emitted - 1)
+                if self._slots[i] is s:
+                    # roll back past rejected drafts: the cursor
+                    # returns to the last ACCEPTED token's position
+                    # and the pages holding only rejected KV go
+                    # back to the free list
+                    s.ctx = pl["ctx0"] + pl["nt_seq"] + emitted - 1
+                    self._trim_pages(s)
+            else:
+                self._emit(s, int(next_tokens[i]))
+                pl["emitted"] = 1
+            emitted_total += pl["emitted"]
+        self.tokens_emitted += emitted_total
+        self.spec_proposed += drafted_step
+        self.spec_accepted += accepted_step
+        if _tele.enabled() and drafted_step:
+            _tele.counter(
+                "serve_spec_proposed_total",
+                "Draft tokens fed for verification").inc(drafted_step)
+            if accepted_step > 0:
+                _tele.counter(
+                    "serve_spec_accepted_total",
+                    "Draft tokens accepted (matched the greedy "
+                    "continuation)").inc(accepted_step)
+        if self.engine.role == "prefill":
+            self._detach_prefilled(actives)
+        if _trace.enabled():
+            self._trace_requests(plan, parents, t_plan, t_wait)
+        return drafted_step, accepted_step, emitted_total
+
+    def _note_step(self, C: int, stamps, counts: dict) -> None:
+        """Keep one step's phase stamps and counts in the bounded log
+        and, only while `tracing.capturing()`, record them as the
+        ``serve.step`` span and its five ``serve.step.<phase>`` children
+        (`tracing.record_phases`: the children tile the parent)."""
+        with self._phase_lock:
+            self._phase_log.append((self._steps, C, stamps, counts))
+        if not _trace.capturing():
+            return
+        rep = {} if self.name is None else {"replica": self.name}
+        on_step = ("active", "tokens_fed", "emitted", "admitted",
+                   "evicted", "expired", "drafted", "accepted",
+                   "prefix_hit")
+        _trace.record_phases(
+            _trace.get_tracer("serve"), "serve.step", self.STEP_PHASES,
+            stamps,
+            track=("serve steps" if self.name is None
+                   else f"serve steps {self.name}"),
+            tags={"step": self._steps, "chunk": C,
+                  **{k: counts[k] for k in on_step}, **rep},
+            phase_tags={
+                "admit": {k: counts[k]
+                          for k in ("queue_depth", "admitted")},
+                "plan": {k: counts[k] for k in ("cow_forks", "evicted")},
+                "launch": {"h2d_bytes": counts["h2d_bytes"]},
+                "emit": {k: counts[k] for k in ("emitted", "finished")}})
+
+    def phase_stats(self, slowest: int = 5) -> dict:
+        """Where the last steps' host time went, from the bounded phase
+        log (filled on every step, traced or not):
+        ``{"step_phases": {"steps": n, "step": {"median_ms", "max_ms"},
+        "<phase>": {...}}, "slowest_steps": [{"step", "chunk", "ms",
+        "<phase>_ms", ...counts}]}`` — the `slowest` longest steps with
+        their phase split."""
+        with self._phase_lock:
+            log = list(self._phase_log)
+
+        def split(stamps):
+            return [(b - a) * 1e3 for a, b in zip(stamps, stamps[1:])]
+
+        def summary(xs):
+            return {"median_ms": round(statistics.median(xs), 4),
+                    "max_ms": round(max(xs), 4)}
+        phases = {"steps": len(log)}
+        if log:
+            phases["step"] = summary(
+                [(st[-1] - st[0]) * 1e3 for _, _, st, _ in log])
+            for name, xs in zip(self.STEP_PHASES,
+                                zip(*(split(st) for _, _, st, _ in log))):
+                phases[name] = summary(xs)
+        longest = sorted(log, key=lambda r: r[2][0] - r[2][-1])[:slowest]
+        return {
+            "step_phases": phases,
+            "slowest_steps": [
+                {"step": step, "chunk": C,
+                 "ms": round((st[-1] - st[0]) * 1e3, 4),
+                 **{f"{name}_ms": round(ms, 4) for name, ms in
+                    zip(self.STEP_PHASES, split(st))},
+                 **counts}
+                for step, C, st, counts in longest]}
 
     def _detach_prefilled(self, actives) -> None:
         """role='prefill' (disaggregation — docs/serving.md): every slot
@@ -1092,25 +1222,18 @@ class ContinuousBatchingScheduler:
                                     pages=len(s.pages), ctx=int(s.ctx),
                                     generated=len(req.tokens))
 
-    def _trace_step(self, plan, parents, t0: float, t1: float, C: int,
-                    drafted: int, accepted: int, emitted: int) -> None:
-        """Post-hoc spans for one fused step: a scheduler-level
-        "serve.step" span (tagged with the step's speculation and
-        prefix-cache outcomes — the `diagnose --trace` rollup columns)
-        plus one per-request phase span (all slots share the device
-        step's wall window — the spans decompose each request's OWN
-        timeline, not the device's).  Runs AFTER emission, so the
-        parent span contexts and pre-emit token counts come from the
-        `parents` snapshot."""
+    def _trace_requests(self, plan, parents, t0: float,
+                        t1: float) -> None:
+        """Post-hoc per-request phase spans for one fused step, behind
+        `tracing.enabled()` alone (a profiler capture must not add one
+        object per live slot to the step it measures): all slots share
+        the device step's wall window `t0..t1` (launch start → tokens
+        read back) — the spans decompose each request's OWN timeline,
+        not the device's.  Runs AFTER emission, so the parent span
+        contexts and pre-emit token counts come from the `parents`
+        snapshot."""
         tr = _trace.get_tracer("serve")
         rep = {} if self.name is None else {"replica": self.name}
-        track = "serve steps" if self.name is None \
-            else f"serve steps {self.name}"
-        prefix_hit, self._span_prefix_hit = self._span_prefix_hit, 0
-        tr.record_span("serve.step", t0, t1, track=track,
-                       step=self._steps, chunk=C, active=len(plan),
-                       emitted=emitted, drafted=drafted,
-                       accepted=accepted, prefix_hit=prefix_hit, **rep)
         for i, pl in plan.items():
             s = pl["slot"]
             req = s.req
@@ -1296,6 +1419,7 @@ class ContinuousBatchingScheduler:
         self._release_slot(slot)
         if self._abandoned or req._epoch != slot.epoch:
             return          # salvaged mid-step: the survivor finishes it
+        self._n_finished += 1
         finish_request(req, replica=self.name)
 
     # ------------------------------------------------------------------

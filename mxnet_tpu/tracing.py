@@ -11,7 +11,10 @@ performance attribution"):
   Chrome/Perfetto ``trace_event`` JSON (:func:`export_chrome` — open the
   file in https://ui.perfetto.dev or chrome://tracing).  Instrumentation
   sites live in the serve scheduler (the full request lifecycle:
-  queue → admit → prefill chunks → decode steps → stream → finish),
+  queue → admit → prefill chunks → decode steps → finish;
+  and one ``serve.step`` span per scheduler step covering the WHOLE
+  call, tiled by its five phases ``serve.step.admit`` / ``.plan`` /
+  ``.launch`` / ``.wait`` / ``.emit`` — :func:`record_phases`),
   the serve fleet/router (``serve.route`` per dispatch,
   ``serve.failover`` per replica death, ``serve.shed`` per rejection —
   phase spans carry a ``replica`` tag so `tools/diagnose.py --trace`
@@ -41,7 +44,11 @@ number (the entry carries ``projected=True``).
 Gating contract (the `telemetry.enabled()` idiom): span creation sites
 guard on one module-level bool (:func:`enabled` — ``MXTPU_TRACE``), so
 a run without tracing pays one boolean read and ZERO allocations per
-step.  Cost capture happens once per compile (never on the hot path)
+step.  The serving step's phase spans follow the wider
+:func:`capturing` gate instead: ``MXTPU_TRACE`` **or** a live
+``jax.profiler`` session — whoever takes a device trace gets the
+program's phases beside the device ops (:func:`annotation` writes them
+into the profiler's own trace) without setting any switch.  Cost capture happens once per compile (never on the hot path)
 and is always on — it is how `bench.py` gets a defensible MFU proxy
 without any env vars set.
 """
@@ -49,6 +56,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import itertools
 import json
 import logging
@@ -57,12 +65,15 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import telemetry as _tele
 from .base import MXNetError
 
 __all__ = [
     "Span", "SpanContext", "Tracer", "CostAccountant", "ClockSync",
-    "enabled", "enable", "disable", "get_tracer", "tracers", "span",
+    "enabled", "enable", "disable", "capturing", "annotation",
+    "record_phases", "get_tracer", "tracers", "span",
     "trace_dir", "export_chrome", "chrome_events", "reset",
     "span_to_wire", "note_remote_process", "remote_processes",
     "account", "record_executable", "cost_features_of", "estimate_mfu",
@@ -426,6 +437,52 @@ def enabled() -> bool:
     """One global read — the zero-cost fast path every span site guards
     on (`MXTPU_TRACE`)."""
     return _enabled
+
+
+def capturing() -> bool:
+    """True while somebody is looking: span collection is on
+    (``MXTPU_TRACE`` / :func:`enable`) **or** a ``jax.profiler`` session
+    is capturing (``TraceAnnotation.is_enabled()`` — true exactly
+    between ``start_trace`` and ``stop_trace``).  The gate of the
+    serving step's phase spans: a device trace always comes with the
+    program's own phases on its clock, and an untraced run records
+    nothing."""
+    return _enabled or _TraceAnnotation.is_enabled()
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **tags):
+    """``with annotation("serve.step.plan"): ...`` — while
+    :func:`capturing`, a ``jax.profiler.TraceAnnotation``: a host span in
+    the PROFILER's own trace (the ``.xplane.pb``), on the clock of the
+    device ops.  Otherwise one shared null context (no allocation).
+    The one place the program writes into the profiler's trace
+    (`profiler.scope` goes through here too)."""
+    return _TraceAnnotation(name, **tags) if capturing() \
+        else _NO_ANNOTATION
+
+
+def record_phases(tracer: "Tracer", name: str, phases, stamps,
+                  track: Optional[str] = None, tags=None,
+                  phase_tags=None) -> Span:
+    """Post-hoc spans of one multi-phase operation from clock stamps
+    already taken: a parent `name` over ``stamps[0]..stamps[-1]`` and one
+    child ``<name>.<phase>`` per consecutive pair, so the children tile
+    the parent by construction (no gap, no overlap).  `tags` go on the
+    parent, ``phase_tags[phase]`` on that child."""
+    if len(stamps) != len(phases) + 1:
+        raise ValueError(f"{len(phases)} phases need {len(phases) + 1} "
+                         f"stamps, got {len(stamps)}")
+    parent = tracer.record_span(name, stamps[0], stamps[-1], track=track,
+                                **(tags or {}))
+    phase_tags = phase_tags or {}
+    for i, phase in enumerate(phases):
+        tracer.record_span(f"{name}.{phase}", stamps[i], stamps[i + 1],
+                           parent=parent, track=track,
+                           **phase_tags.get(phase, {}))
+    return parent
 
 
 def get_tracer(name: str) -> Tracer:

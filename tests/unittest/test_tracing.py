@@ -449,3 +449,287 @@ def test_prometheus_nan_gauge_spelling():
     text = tele.to_prometheus()
     assert "nan_g NaN" in text
     _parse_prometheus(text)   # grammar accepts it
+
+
+# ---------------------------------------------------------------------------
+# the serving step's phase spans: always stamped, recorded while capturing()
+# ---------------------------------------------------------------------------
+
+STEP_SPANS = ("serve.step", "serve.step.admit", "serve.step.plan",
+              "serve.step.launch", "serve.step.wait", "serve.step.emit")
+
+
+def _phase_engine():
+    from mxnet_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    cfg = GPTConfig(vocab_size=64, hidden_size=16, num_layers=1,
+                    num_heads=2, intermediate_size=32, max_position=32,
+                    dropout=0.0)
+    model = GPTForCausalLM(cfg)
+    model.initialize()
+    model(mx.np.array([[1, 2]], dtype="int32"))
+    eng = InferenceEngine(model, ServeConfig(
+        max_len=24, max_slots=2, num_pages=9, page_size=4,
+        prefill_chunk=4))
+    eng.warmup()
+    return eng
+
+
+def _drive(eng, prompts=((1, 2, 3, 4, 5, 6), (7, 8, 9)), max_new=3):
+    """Run the prompts to idle; returns (tokens delivered, plan feeds per
+    step as the scheduler built them, steps run)."""
+    delivered = []
+    feeds = []
+    plan = eng.scheduler._plan
+
+    def spy():
+        out = plan()
+        if out is not None:
+            feeds.append(sum(pl["nt"] for pl in out[1].values()))
+        return out
+    eng.scheduler._plan = spy
+    for p in prompts:
+        eng.submit(list(p), max_new_tokens=max_new,
+                   on_token=lambda t, r: delivered.append(t))
+    steps = eng.run_until_idle()
+    eng.scheduler._plan = plan
+    return delivered, feeds, steps
+
+
+def _check_step_spans(spans, feeds, delivered, steps):
+    """One `serve.step` a step, five children that tile it to 50 us,
+    `tokens_fed` the plan's feeds and `emitted` the tokens delivered."""
+    parents = [s for s in spans if s.name == "serve.step"]
+    assert len(parents) == steps == len(feeds)
+    for parent, fed in zip(parents, feeds):
+        kids = sorted((s for s in spans if s.parent_id == parent.span_id),
+                      key=lambda s: s.t0)
+        assert [k.name for k in kids] == list(STEP_SPANS[1:])
+        edges = [parent.t0] + [k.t1 for k in kids]
+        assert abs(kids[0].t0 - parent.t0) < 50e-6
+        assert abs(kids[-1].t1 - parent.t1) < 50e-6
+        for a, b in zip(kids, kids[1:]):
+            assert abs(a.t1 - b.t0) < 50e-6          # no gap, no overlap
+        assert edges == sorted(edges)
+        assert parent.tags["tokens_fed"] == fed
+        assert kids[2].tags["h2d_bytes"] > 0
+        assert kids[0].tags["admitted"] == parent.tags["admitted"]
+        assert kids[4].tags["emitted"] == parent.tags["emitted"]
+    assert sum(p.tags["emitted"] for p in parents) == len(delivered)
+    assert sum(p.tags["admitted"] for p in parents) == 2
+    assert sum(p.tags["finished"]
+               for p in spans if p.name == "serve.step.emit") == 2
+    assert [p.tags["step"] for p in parents] == list(
+        range(parents[0].tags["step"], parents[0].tags["step"] + steps))
+
+
+def test_untraced_step_allocates_no_span_and_still_fills_step_phases():
+    """(a) With no profiler session and tracing off a step adds ZERO
+    spans to the `serve` ring, and `stats()` still says where the steps'
+    time went."""
+    assert not tracing.capturing()
+    eng = _phase_engine()
+    delivered, feeds, steps = _drive(eng)
+    assert tracing.get_tracer("serve").spans() == []
+    st = eng.stats()
+    phases = st["step_phases"]
+    assert phases["steps"] == steps
+    for name in ("step",) + eng.scheduler.STEP_PHASES:
+        assert phases[name]["max_ms"] >= phases[name]["median_ms"] >= 0
+    # the phases of a step add up to the step
+    slow = st["slowest_steps"]
+    assert 1 <= len(slow) <= 5
+    assert slow == sorted(slow, key=lambda r: -r["ms"])
+    for r in slow:
+        split = sum(r[f"{p}_ms"] for p in eng.scheduler.STEP_PHASES)
+        assert abs(split - r["ms"]) < 1e-2
+        assert r["chunk"] in (1, 4) and r["tokens_fed"] > 0
+    assert sum(feeds) == sum(
+        c["tokens_fed"] for _, _, _, c in eng.scheduler._phase_log)
+    json.dumps(st["step_phases"]), json.dumps(slow)   # wire-safe
+
+
+@pytest.mark.parametrize("gate", ["profiler", "enable"])
+def test_step_spans_tile_the_step_while_capturing(gate, tmp_path):
+    """(b)+(c) Inside a `jax.profiler` session, and separately under
+    `tracing.enable()`, every step yields one `serve.step` and five
+    children that tile it; the per-request fan-out appears under
+    `tracing.enable()` and NOT under a profiler session alone."""
+    eng = _phase_engine()
+    if gate == "profiler":
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+        try:
+            assert tracing.capturing() and not tracing.enabled()
+            delivered, feeds, steps = _drive(eng)
+        finally:
+            jax.profiler.stop_trace()
+        assert not tracing.capturing()
+    else:
+        tracing.enable()
+        assert tracing.capturing()
+        delivered, feeds, steps = _drive(eng)
+    spans = tracing.get_tracer("serve").spans()
+    _check_step_spans(spans, feeds, delivered, steps)
+    fan_out = [s for s in spans if s.name in (
+        "serve.prefill_chunk", "serve.first_decode", "serve.decode",
+        "serve.request", "serve.queue")]
+    if gate == "profiler":
+        assert fan_out == []
+        assert {s.name for s in spans} == set(STEP_SPANS)
+    else:
+        assert {"serve.request", "serve.queue",
+                "serve.prefill_chunk"} <= {s.name for s in fan_out}
+        # the per-token `serve.stream` span went: `serve.step.emit` holds
+        # the callbacks' time
+        assert "serve.stream" not in {s.name for s in spans}
+    # after the capture: stamped, not recorded
+    n = len(spans)
+    tracing.disable()
+    _drive(eng, prompts=((1, 2),), max_new=2)
+    assert len(tracing.get_tracer("serve").spans()) == n
+
+
+def test_record_phases_tiles_by_construction():
+    tr = tracing.get_tracer("t")
+    parent = tracing.record_phases(
+        tr, "op", ("a", "b"), (1.0, 1.5, 4.0), tags={"n": 1},
+        phase_tags={"b": {"bytes": 7}})
+    a, b = [s for s in tr.spans() if s.parent_id == parent.span_id]
+    assert (parent.t0, parent.t1, parent.tags) == (1.0, 4.0, {"n": 1})
+    assert (a.name, a.t0, a.t1) == ("op.a", 1.0, 1.5)
+    assert (b.name, b.t0, b.t1, b.tags) == ("op.b", 1.5, 4.0, {"bytes": 7})
+    assert a.trace_id == b.trace_id == parent.trace_id
+    with pytest.raises(ValueError, match="stamps"):
+        tracing.record_phases(tr, "op", ("a", "b"), (1.0, 2.0))
+
+
+# ---------------------------------------------------------------------------
+# stable names on the device side: `mx.*` scopes and kernel `name=`s
+# ---------------------------------------------------------------------------
+
+def _lowered_text(jitted, *avals):
+    return jitted.trace(*avals).lower().as_text(debug_info=True)
+
+
+def _has_scope(txt, scope):
+    """The scope as one component of an op's location path: `a/scope/op`,
+    or `jvp(scope)` / `transpose(jvp(scope))` under autodiff."""
+    import re
+    return re.search(r'[/("]' + re.escape(scope) + r'[/)"]', txt)
+
+
+def test_serve_step_lowers_with_every_mx_serve_scope():
+    """(d) `transformer_step` inside the engine's fused step: every
+    `mx.serve.*` block name reaches the lowered program's locations."""
+    eng = _phase_engine()
+    txt = _lowered_text(eng._step_fn(1), *eng._step_avals(1))
+    for scope in ("mx.serve.embed", "mx.serve.qkv", "mx.serve.pool_write",
+                  "mx.serve.paged_attn", "mx.serve.attn_out",
+                  "mx.serve.mlp", "mx.serve.final_norm",
+                  "mx.serve.sample"):
+        assert _has_scope(txt, scope), scope
+
+
+def test_bert_train_step_lowers_with_every_mx_scope():
+    """(d) The BERT pretraining step: model blocks, the loss and the
+    optimizer update (with its packing) carry their `mx.*` names."""
+    from mxnet_tpu.gluon.block import HybridBlock
+    from mxnet_tpu.models.bert import BertConfig, BertForPretraining
+
+    class Pretrain(HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.model = BertForPretraining(BertConfig(
+                vocab_size=64, hidden_size=16, num_layers=1, num_heads=2,
+                intermediate_size=32, max_position=16, dropout=0.0))
+
+        def forward(self, ids, mpos):
+            return self.model(ids, masked_positions=mpos)
+
+    def loss_fn(out, ids, mpos, labels):
+        mlm, nsp = out
+        lse = jax.nn.logsumexp(mlm.astype(jnp.float32), axis=-1)
+        picked = jnp.take_along_axis(
+            mlm.astype(jnp.float32), labels[..., None], -1)[..., 0]
+        return jnp.mean(lse - picked) + jnp.mean(nsp.astype(jnp.float32))
+
+    model = Pretrain()
+    model.initialize()
+    ids = mx.np.array(onp.ones((2, 8)), dtype="int32")
+    mpos = mx.np.array(onp.zeros((2, 2)), dtype="int32")
+    labels = mx.np.array(onp.ones((2, 2)), dtype="int32")
+    model(ids, mpos)
+    mesh = make_mesh({"dp": 1}, jax.devices()[:1])
+    step = make_sharded_train_step(
+        model, opt.Adam(learning_rate=1e-3), loss_fn, mesh,
+        num_model_args=2)
+    step._fused_opt_kernel = True        # the packed path, traced only
+    batch_vals = step._prepare_batch((ids, mpos, labels))
+    args = (step.pvals, step.opt_state, step._hp(),
+            jax.random.PRNGKey(0)) + tuple(batch_vals)
+    avals = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+    txt = jax.jit(step._step_fn.__wrapped__).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    step._release_trace_guard()
+    for scope in ("mx.embed", "mx.attn", "mx.ffn", "mx.norm", "mx.pooler",
+                  "mx.mlm_head", "mx.nsp_head", "mx.loss", "mx.optimizer",
+                  "mx.optimizer.pack"):
+        assert _has_scope(txt, scope), scope
+    assert "mx_fused_opt_update" in txt
+
+
+PALLAS_NAMES = {
+    "flash_attention.py": ["mx_flash_attn_fwd", "mx_flash_attn_bwd_dq",
+                           "mx_flash_attn_bwd_dkv"],
+    "fused_norm.py": ["mx_fused_norm"],
+    "fused_optimizer.py": ["mx_fused_opt_update", "mx_fused_lamb_moments",
+                           "mx_fused_lamb_apply", "mx_fused_opt_autotune"],
+    "moe_dispatch.py": ["mx_moe_dispatch"],
+    "paged_attention.py": ["ragged_paged_attention"],
+    "quantized_matmul.py": ["mx_quant_matmul"],
+    "softmax_xent.py": ["mx_softmax_xent_fwd", "mx_softmax_xent_bwd"],
+}
+
+
+def _pallas_call_names(path):
+    """`name=` of every `pallas_call(...)` in a module (None: unnamed)."""
+    import ast
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    out = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and getattr(
+                node.func, "attr", getattr(node.func, "id", "")) \
+                == "pallas_call":
+            kw = {k.arg: k.value for k in node.keywords}
+            name = kw.get("name")
+            out.append(name.value if isinstance(name, ast.Constant)
+                       else None)
+    return out
+
+
+def _pallas_dir():
+    import os
+    from mxnet_tpu.ops import pallas
+    return os.path.dirname(pallas.__file__)
+
+
+@pytest.mark.parametrize("module,name", [
+    (m, n) for m, names in sorted(PALLAS_NAMES.items()) for n in names])
+def test_pallas_call_site_has_its_stable_name(module, name):
+    import os
+    names = _pallas_call_names(os.path.join(_pallas_dir(), module))
+    assert names.count(name) == 1, (module, names)
+
+
+def test_every_pallas_call_is_named_and_names_are_distinct():
+    import glob
+    import os
+    found = []
+    for path in sorted(glob.glob(os.path.join(_pallas_dir(), "*.py"))):
+        names = _pallas_call_names(path)
+        assert None not in names, f"{path}: a pallas_call without name="
+        found += names
+    assert len(found) == len(set(found)) == sum(
+        len(v) for v in PALLAS_NAMES.values())

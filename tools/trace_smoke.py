@@ -108,6 +108,12 @@ def main() -> int:
     if not spans:
         return fail("exported trace has no complete spans")
 
+    # every scheduler step: one serve.step tiled by its five phases
+    steps = [s for s in spans if s["name"] == "serve.step"]
+    phases = {p: sum(s["name"] == f"serve.step.{p}" for s in spans)
+              for p in ("admit", "plan", "launch", "wait", "emit")}
+    if not steps or set(phases.values()) != {len(steps)}:
+        return fail(f"{len(steps)} serve.step spans but phases {phases}")
     by_req: dict = {}
     for s in spans:
         rid = (s.get("args") or {}).get("request_id")
@@ -120,7 +126,7 @@ def main() -> int:
                     f"request ids seen: {sorted(by_req)}")
     for rid, ss in by_req.items():
         names = {s["name"] for s in ss}
-        need = {"serve.request", "serve.queue", "serve.stream"}
+        need = {"serve.request", "serve.queue"}
         if not need <= names:
             return fail(f"request {rid} span tree incomplete: {names}")
         if not ({"serve.prefill_chunk", "serve.first_decode"} & names):
