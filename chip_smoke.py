@@ -299,7 +299,9 @@ def _paged_kernel_parity(eng) -> dict:
              for b in range(B)], jnp.int32)
         ctx = start + C
         kern = jax.jit(lambda *a: ragged_paged_attention(
-            *a, use_kernel=True))(q, kp, vp, pt, ctx, start)
+            *a, use_kernel=True,
+            page_in_lanes=eng.pools.pages_in_lanes()))(
+                q, kp, vp, pt, ctx, start)
         ref = jax.jit(paged_attention_reference)(q, kp, vp, pt, ctx, start)
         errs[f"c{C}"] = round(_bf16_close(
             kern, ref, f"paged attention C={C}"), 5)
@@ -381,11 +383,15 @@ def leg_serve(dev, cache_dir: str, cfg, max_len: int, prompt_lens,
     hlo = {C: execs[C].as_text() for C in widths}
     calls = {f"c{C}": hlo[C].count("tpu_custom_call") for C in widths}
     assert all(n >= min_custom_calls for n in calls.values()), calls
-    # information: whole-pool relayout copies XLA put around the kernel
-    # (0 = the donated pool is updated in place, as the engine intends)
+    # whole-pool relayout copies XLA put around the kernels: none, the
+    # donated pools pass through custom calls alone, in the layout this
+    # device keeps them in (PR 28; 24 a width before)
     out["pool_relayout_copies"] = {
         f"c{C}": _count_copies_of(hlo[C], eng.pools.arrays["k"])
         for C in widths}
+    assert not any(out["pool_relayout_copies"].values()), \
+        out["pool_relayout_copies"]
+    out["pages_in_lanes"] = eng.pools.pages_in_lanes()
     out["kernel_parity_max_abs"] = _paged_kernel_parity(eng)
 
     rng = onp.random.RandomState(SEED)
@@ -455,7 +461,7 @@ def main() -> int:
     gpt = GPTConfig(dtype="bfloat16", dropout=0.0)
     _emit(leg_serve(dev, cache_dir, gpt, max_len=1024,
                     prompt_lens=(16, 96, 256, 512, 16, 96, 256, 512),
-                    min_custom_calls=gpt.num_layers))
+                    min_custom_calls=2 * gpt.num_layers))
     gc.collect()
 
     if jax.device_count() >= 4:

@@ -13,6 +13,15 @@ slot's pages sequentially (online softmax, flash style), fetching the
 physical page via scalar-prefetched page-table indices — no
 (B, L_max, ...) contiguous gather is ever materialised on the TPU path.
 
+The chunk's new K/V rows enter the pool through a second kernel,
+`paged_kv_write`, whose pool operands are aliased to its results: on the
+kernel route the pools are operands and results of custom calls only, so
+XLA:TPU has no layout of its own to give them and nothing to copy.  Both
+kernels take the pool in either page orientation (`pages_in_lanes`): as
+``(page_size, D)`` tiles, or — where the device keeps a page's rows in
+lanes, as a v5e does for D < 128 — as the ``(D, page_size)`` tiles of the
+transposed view, which is a bitcast there.
+
 Grouped-query attention uses the same folding trick as
 `flash_attention.py`: the `rep = H // Hkv` query heads sharing a kv head
 stack along the row axis, so K/V pages stream once per kv head.
@@ -39,13 +48,15 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import interpret_mode, kernel_active
+from . import interpret_mode, kernel_active, tpu_compiler_params
 
 MASK_VALUE = -1e30
 LANES = 128
 
 __all__ = ["ragged_paged_attention", "paged_attention_reference",
-           "gather_pages", "kernel_tileable", "MASK_VALUE", "LANES"]
+           "paged_kv_write", "paged_kernel_route", "pages_in_lanes",
+           "gather_pages",
+           "kernel_tileable", "MASK_VALUE", "LANES"]
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +145,7 @@ def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_rpa_kernel(scale, chunk, rep, window):
+def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
     """Build the kernel body with static shape parameters.
 
     One (slot, kv-head, page) grid step: rows are the GQA fold — row r =
@@ -142,8 +153,13 @@ def _make_rpa_kernel(scale, chunk, rep, window):
     row's query position is ``start + r % chunk``.  Pages walk
     sequentially (innermost grid dim) with flash-style online softmax in
     VMEM scratch.  All elementwise math is f32 (v5e has no bf16 VPU):
-    q/k/v go to the MXU as stored and accumulate in f32."""
+    q/k/v go to the MXU as stored and accumulate in f32.  With
+    `page_in_lanes` the K/V blocks are (D, page_size) tiles (see
+    `pages_in_lanes`): K^T feeds the score matmul as it lies and V^T is
+    contracted over its lane dim, the form q.K^T has otherwise."""
     from jax.experimental import pallas as pl
+
+    nt_dims = (((1,), (1,)), ((), ()))      # contract both minor dims
 
     def kernel(pt_ref, ctx_ref, start_ref, q_ref, k_ref, v_ref,
                o_ref, m_scr, l_scr, acc_scr):
@@ -152,7 +168,7 @@ def _make_rpa_kernel(scale, chunk, rep, window):
         n_pages = pl.num_programs(2)
 
         rows, d = q_ref.shape
-        ps = k_ref.shape[0]
+        ps = k_ref.shape[1 if page_in_lanes else 0]
 
         @pl.when(pi == 0)
         def _init():
@@ -166,9 +182,12 @@ def _make_rpa_kernel(scale, chunk, rep, window):
         def _step():
             qb = q_ref[...]
             kb = k_ref[...]
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            if page_in_lanes:
+                s = jax.lax.dot(qb, kb, preferred_element_type=jnp.float32)
+            else:
+                s = jax.lax.dot_general(
+                    qb, kb, nt_dims, preferred_element_type=jnp.float32)
+            s = s * scale
             # row r -> query position start + r % chunk; col j -> key
             # position pi * ps + j
             r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -192,8 +211,13 @@ def _make_rpa_kernel(scale, chunk, rep, window):
             l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
             m_scr[...] = m_next
             vb = v_ref[...]
-            acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + jax.lax.dot(
-                p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+            pb = p.astype(vb.dtype)
+            if page_in_lanes:
+                pv = jax.lax.dot_general(
+                    pb, vb, nt_dims, preferred_element_type=jnp.float32)
+            else:
+                pv = jax.lax.dot(pb, vb, preferred_element_type=jnp.float32)
+            acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + pv
 
         # skip pages entirely past the slot's context (the ragged win:
         # a decode slot with 40 tokens touches 3 pages, not max_pages)
@@ -220,7 +244,7 @@ def _lanes(x, n):
 
 
 def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
-                window, scale):
+                window, scale, page_in_lanes=False):
     """Launch the Pallas kernel over the stacked ``(n_layers, Hkv, pages,
     ps, D)`` pools (shapes pre-validated by the wrapper).  The layer is
     picked in the K/V index map, so XLA never materialises a per-layer
@@ -249,13 +273,18 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
     def kv_map(b, h, pi, pt, ctx, st):
         return (layer, h, pt[b, pi], 0, 0)
 
+    kv_block = (None, None, None, ps, D)
+    if page_in_lanes:
+        kpool, vpool = _lane_view(kpool), _lane_view(vpool)
+        kv_block = (None, None, None, D, ps)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, Hkv, maxp),
         in_specs=[
             pl.BlockSpec((None, None, rows_p, D), q_map),
-            pl.BlockSpec((None, None, None, ps, D), kv_map),
-            pl.BlockSpec((None, None, None, ps, D), kv_map),
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(kv_block, kv_map),
         ],
         out_specs=pl.BlockSpec((None, None, rows_p, D), q_map),
         scratch_shapes=[
@@ -265,7 +294,7 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
         ],
     )
     out = pl.pallas_call(
-        _make_rpa_kernel(scale, C, rep, window),
+        _make_rpa_kernel(scale, C, rep, window, page_in_lanes),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -287,10 +316,203 @@ def kernel_tileable(page_size: int, head_dim: int) -> bool:
     return page_size % 8 == 0 and lanes_ok(page_size) and lanes_ok(head_dim)
 
 
+def paged_kernel_route(quantized: bool) -> bool:
+    """Does the serving step take the Pallas kernels for this pool?  One
+    predicate for the attention and for the K/V write before it: fp pools
+    wherever the ``MXTPU_PALLAS`` policy makes kernels active; int8 pools
+    (scale planes) and every other backend take the references."""
+    return not quantized and kernel_active()
+
+
+# ---------------------------------------------------------------------------
+# K/V write: the chunk's new rows go into the pool inside a Pallas call
+# whose pool operands are aliased to its outputs, so that on the kernel
+# route the pools are operands and results of custom calls only.  An XLA
+# scatter beside the attention call made XLA:TPU relayout the WHOLE pool
+# before and after every layer (96% of a decode step; PERF.md, PR 28).
+# ---------------------------------------------------------------------------
+
+def pages_in_lanes(pool) -> bool:
+    """Does the device keep this (concrete) pool's pages with their rows
+    in lanes?  XLA:TPU's default layout of a ``(..., page_size, D)`` array
+    with D < 128 <= page_size is ``{3,4,2,1,0}``: D second-minor, the
+    page's rows minor, i.e. a page lies in HBM as an unpadded (D,
+    page_size) tile.  A Mosaic call takes its operands row-major, so fed
+    the pool as it is shaped it costs a whole-pool relayout at each end of
+    the step; fed the transposed VIEW (a bitcast of that layout) it costs
+    none.  The kernels take either orientation (`page_in_lanes=`); the
+    engine asks here, once, which one its pool has."""
+    try:
+        order = tuple(pool.format.layout.major_to_minor)
+    except AttributeError:      # nothing with a layout to ask (a tracer)
+        return False
+    n = len(order)
+    return n >= 2 and order[-2:] == (n - 1, n - 2)
+
+
+def _lane_view(pool):
+    """(..., page_size, D) -> (..., D, page_size): a bitcast where
+    `pages_in_lanes` holds (and back: the swap is its own inverse)."""
+    return jnp.swapaxes(pool, -1, -2)
+
+
+def _kv_write_tile(page_size: int, dtype, page_in_lanes: bool) -> int:
+    """Page rows one grid step reads, merges and writes back.  Rows in
+    sublanes: the dtype's sublane tile (8 rows of f32, 16 of bf16, which
+    packs two rows a sublane) where it divides the page.  Rows in lanes:
+    one 128-lane tile.  Else the whole page."""
+    t = LANES if page_in_lanes else \
+        8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return t if page_size % t == 0 else page_size
+
+
+def _make_kv_write_kernel(tile, page_in_lanes):
+    """One (slot, tile) grid step: the aligned `tile`-row piece of the
+    slot's page that holds some of the chunk's rows is read for all kv
+    heads, the new rows are merged in under an iota mask (never a one-row
+    store: bf16 rows share a sublane) and the tile is written back."""
+    from jax.experimental import pallas as pl
+
+    def kernel(pt_ref, start_ref, nt_ref, kn_ref, vn_ref, kin_ref, vin_ref,
+               ko_ref, vo_ref):
+        b = pl.program_id(0)
+        start = start_ref[b]
+        nt = nt_ref[b]
+        g = start // tile + pl.program_id(1)
+        g_last = (start + jnp.maximum(nt, 1) - 1) // tile
+        base = g * tile - start       # tile row r holds chunk row base + r
+        pairs = ((kn_ref, kin_ref, ko_ref), (vn_ref, vin_ref, vo_ref))
+
+        def merge_sublanes():
+            # rows in sublanes: select each of the tile's new rows in turn
+            row = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape, 1)
+
+            def body(c, tiles):
+                hit = row == c - base
+                return tuple(
+                    jnp.where(hit, n_ref[:, pl.ds(c, 1), :], t)
+                    for (n_ref, _, _), t in zip(pairs, tiles))
+
+            tiles = jax.lax.fori_loop(
+                jnp.maximum(base, 0), jnp.minimum(base + tile, nt), body,
+                tuple(i_ref[...].astype(jnp.float32)
+                      for _, i_ref, _ in pairs))
+            for (_, _, o_ref), t in zip(pairs, tiles):
+                o_ref[...] = t.astype(o_ref.dtype)
+
+        def merge_lanes():
+            # rows in lanes: a new row is a column, and columns move on
+            # the MXU.  (Hkv * D, Cp) new^T times a (Cp, tile) one-hot
+            # drops each column at its lane, exactly: every product is
+            # x * 1 or x * 0 (f32 at HIGHEST: three bf16 pieces of x)
+            hkv, d, cp = kn_ref.shape
+            c = jax.lax.broadcasted_iota(jnp.int32, (cp, tile), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (cp, tile), 1)
+            onehot = ((c == base + lane) & (c < nt)).astype(kn_ref.dtype)
+            src = base + jax.lax.broadcasted_iota(
+                jnp.int32, ko_ref.shape, 2)
+            new_here = (src >= 0) & (src < nt)
+            exact = jax.lax.Precision.HIGHEST \
+                if kn_ref.dtype == jnp.float32 else None
+            for n_ref, i_ref, o_ref in pairs:
+                placed = jax.lax.dot(
+                    n_ref[...].reshape(hkv * d, cp), onehot,
+                    precision=exact, preferred_element_type=jnp.float32)
+                o_ref[...] = jnp.where(
+                    new_here, placed.reshape(hkv, d, tile),
+                    i_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+        # steps past the chunk's last tile keep that tile's block index
+        # (see `pool_map`), so its buffer stays resident: touch nothing
+        pl.when(g <= g_last)(merge_lanes if page_in_lanes
+                             else merge_sublanes)
+
+    return kernel
+
+
+def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
+                   start_pos, num_tokens, null_page: int = 0,
+                   page_in_lanes: bool = False):
+    """Write a chunk's new K/V rows into layer `layer` of the stacked
+    pools, in place: returns the (k, v) pools, aliased to the operands.
+
+    kpool/vpool: (n_layers, Hkv, num_pages, page_size, D); k_new/v_new:
+    (B, Hkv, C, D); page_tables: (B, max_pages); start_pos/num_tokens:
+    (B,).  Row ``c < num_tokens[b]`` of slot b lands at offset
+    ``(start_pos[b] + c) % page_size`` of page ``page_tables[b,
+    min((start_pos[b] + c) // page_size, max_pages - 1)]``, cast to the
+    pool's dtype; rows past `num_tokens` are dropped and an idle slot
+    (``num_tokens == 0``) reads and rewrites a tile of `null_page` alone.
+    Every other byte of the pool is left as it was.  Page ids, starts and
+    counts arrive by scalar prefetch and the layer is picked in the index
+    map, as in `_rpa_pallas`; `page_in_lanes` as in `pages_in_lanes`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hkv, C, D = k_new.shape
+    ps = kpool.shape[3]
+    maxp = page_tables.shape[1]
+    tile = _kv_write_tile(ps, kpool.dtype, page_in_lanes)
+    # tiles a chunk of C rows can straddle (C = 16 on 16-row tiles: two,
+    # maybe on two pages); the surplus steps of a chunk that straddles
+    # fewer repeat its last block index and are skipped in the kernel
+    n_tiles = (C + tile - 2) // tile + 1
+
+    def new_map(b, j, pt, st, nt):
+        return (b, 0, 0, 0)
+
+    def pool_map(b, j, pt, st, nt):
+        g = jnp.minimum(st[b] // tile + j,
+                        (st[b] + jnp.maximum(nt[b], 1) - 1) // tile)
+        page = pt[b, jnp.minimum(g * tile // ps, maxp - 1)]
+        page = jnp.where(nt[b] > 0, page, null_page)
+        in_page = g % (ps // tile)
+        return ((layer, 0, page, 0, in_page) if page_in_lanes
+                else (layer, 0, page, in_page, 0))
+
+    if page_in_lanes:
+        # the pool's dtype here already (the one-hot product is exact),
+        # columns for rows, the chunk padded to a sublane tile of either
+        # dtype: the matmul's contraction dim
+        cp = -(-C // 16) * 16
+        k_new, v_new = (
+            jnp.pad(_lane_view(x.astype(kpool.dtype)),
+                    ((0, 0), (0, 0), (0, 0), (0, cp - C)))
+            for x in (k_new, v_new))
+        kpool, vpool = _lane_view(kpool), _lane_view(vpool)
+        new_spec = pl.BlockSpec((None, Hkv, D, cp), new_map)
+        pool_spec = pl.BlockSpec((None, Hkv, None, D, tile), pool_map)
+    else:
+        # the merge runs in f32 (v5e has no bf16 VPU): f32 -> pool dtype
+        # is the round-to-nearest-even of the scatter route's astype
+        k_new, v_new = (x.astype(jnp.float32) for x in (k_new, v_new))
+        new_spec = pl.BlockSpec((None, Hkv, C, D), new_map)
+        pool_spec = pl.BlockSpec((None, Hkv, None, tile, D), pool_map)
+    kpool, vpool = pl.pallas_call(
+        _make_kv_write_kernel(tile, page_in_lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, n_tiles),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec]),
+        out_shape=[jax.ShapeDtypeStruct(kpool.shape, kpool.dtype),
+                   jax.ShapeDtypeStruct(vpool.shape, vpool.dtype)],
+        # operands count the three prefetched scalars: 5, 6 are the pools
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=tpu_compiler_params("arbitrary", "arbitrary"),
+        interpret=interpret_mode(),
+        name="paged_kv_write",
+    )(page_tables.astype(jnp.int32), start_pos.astype(jnp.int32),
+      num_tokens.astype(jnp.int32), k_new, v_new, kpool, vpool)
+    if page_in_lanes:
+        kpool, vpool = _lane_view(kpool), _lane_view(vpool)
+    return kpool, vpool
+
+
 def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                            start_pos, window=None, scale=None,
                            k_scales=None, v_scales=None, use_kernel=None,
-                           layer=None):
+                           layer=None, page_in_lanes=False):
     """Mixed prefill/decode attention over a paged KV pool — one launch.
 
     q: (B, H, C, D) chunk queries (C = 1 for a pure-decode step);
@@ -320,7 +542,7 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                          f"kv heads ({Hkv})")
     quantized = k_scales is not None or v_scales is not None
     if use_kernel is None:
-        use_kernel = not quantized and kernel_active()
+        use_kernel = paged_kernel_route(quantized)
     if use_kernel:
         if quantized:
             raise ValueError("the Pallas paged-attention kernel takes an "
@@ -334,7 +556,7 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
         return _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens,
                            start_pos, window,
                            scale if scale is not None
-                           else 1.0 / math.sqrt(D))
+                           else 1.0 / math.sqrt(D), page_in_lanes)
     return paged_attention_reference(
         q, kpool[layer], vpool[layer], page_tables, ctx_lens, start_pos,
         window=window, scale=scale,

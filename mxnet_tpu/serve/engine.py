@@ -2,7 +2,7 @@
 
 Wraps a causal-LM ``HybridBlock`` (``GPTForCausalLM``) the way
 `ShardedTrainStep` wraps training: the whole serving iteration — embed a
-ragged chunk of tokens for every slot, scatter new K/V into the paged pool,
+ragged chunk of tokens for every slot, write new K/V into the paged pool,
 ragged paged attention, LM head, sample — is ONE jitted program with the
 pool buffers **donated** (in-place page updates, zero per-step device
 allocation).  Two variants compile at `warmup()`: the mixed
@@ -452,6 +452,7 @@ class InferenceEngine:
         window = getattr(cfg, "window", None)
         quantized = self.quantized
         pool_names = self.pools.names
+        page_in_lanes = self.pools.pages_in_lanes()
         top_k, top_p = sc.top_k, sc.top_p
         max_pos = cfg.max_position
         spec_k = sc.spec_tokens
@@ -464,7 +465,8 @@ class InferenceEngine:
             pools = dict(zip(pool_names, pools_t))
             kv_fn = make_paged_kv_fn(pools, page_tables, start_pos,
                                      num_tokens, ctx_lens, ps, quantized,
-                                     window=window)
+                                     window=window,
+                                     page_in_lanes=page_in_lanes)
             # padded rows may run past the table; clamp for the embedding
             # gather only (writes are masked, attention rows are ignored)
             pos = jnp.minimum(start_pos[:, None] + jnp.arange(C)[None, :],

@@ -16,6 +16,12 @@ Page 0 is reserved as the **null page**: masked writes (padded chunk rows,
 inactive slots) are scattered there and no allocation ever returns it, so
 the jitted step needs no host-side branching on raggedness.
 
+The step writes a chunk's new K/V where it attends (`make_paged_kv_fn`):
+on the kernel route by the Pallas call `paged_kv_write`, pools aliased in
+and out, so that the donated pools pass through custom calls alone and
+are never relayouted; everywhere else (int8 pools, the CPU,
+``MXTPU_PALLAS`` off) by an XLA scatter, `scatter_kv_write`.
+
 ``kv_dtype="int8"`` stores the pool quantized (symmetric per-token-per-head
 int8 via `contrib/quantization.quantize_kv`) at ~4x less HBM per token;
 attention dequantizes only the gathered context.
@@ -447,6 +453,13 @@ class KVPools:
                        self.num_pages, self.page_size, self.n_kv_heads,
                        self.head_dim, self.quantized)
 
+    def pages_in_lanes(self) -> bool:
+        """Does the device keep a page's rows in lanes (a (D, page_size)
+        tile)?  Asked of the K pool's own layout; the step's kernels take
+        the pool in that orientation (`ops.pallas.paged_attention`)."""
+        from ..ops.pallas.paged_attention import pages_in_lanes
+        return pages_in_lanes(self.arrays["k"])
+
     def nbytes(self) -> int:
         return sum(int(a.size) * a.dtype.itemsize
                    for a in self.arrays.values())
@@ -454,11 +467,22 @@ class KVPools:
 
 def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
                      num_tokens, ctx_lens, page_size: int, quantized: bool,
-                     window=None):
+                     window=None, page_in_lanes: bool = False):
     """Build the `kv_fn` closure `transformer_step` calls per layer inside
-    the jitted serving step: scatter the chunk's new K/V into the paged
+    the jitted serving step: write the chunk's new K/V into the paged
     pool, then attend over each slot's pages via
     `ragged_paged_attention`.
+
+    The write takes a kernel exactly where the attention does
+    (`paged_kernel_route`: an fp pool and kernels active): the Pallas
+    call `paged_kv_write`, pools aliased in and out, so that between the
+    step's donated argument and its returned pools the K and V pools
+    pass through custom calls only and XLA never relayouts them
+    (`page_in_lanes`: what `KVPools.pages_in_lanes` found, so that the
+    calls take the pool in the layout the device keeps it in).  Every
+    other route (int8 pools with scale planes, the CPU and any non-TPU
+    backend, ``MXTPU_PALLAS`` off) keeps the XLA scatter
+    (`scatter_kv_write`) beside the reference attention.
 
     `pools` is a MUTABLE dict of the pool arrays (functional updates are
     written back per layer); after `transformer_step` returns it holds the
@@ -467,52 +491,64 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     page_tables: (B, max_pages) int32; start_pos/num_tokens/ctx_lens:
     (B,) int32.  Chunk token c of slot b sits at absolute position
     ``start_pos[b] + c`` and is real iff ``c < num_tokens[b]`` — padded
-    rows scatter to the null page.
+    rows scatter to the null page (the kernel drops them).
     """
-    from ..ops.pallas.paged_attention import ragged_paged_attention
-
-    ps = page_size
+    from ..ops.pallas.paged_attention import (
+        paged_kernel_route, paged_kv_write, ragged_paged_attention)
 
     def kv_fn(li, q, k_new, v_new):
         with jax.named_scope("mx.serve.pool_write"):
-            write(li, k_new, v_new)
+            if paged_kernel_route(quantized):
+                pools["k"], pools["v"] = paged_kv_write(
+                    pools["k"], pools["v"], k_new, v_new, li, page_tables,
+                    start_pos, num_tokens, null_page=NULL_PAGE,
+                    page_in_lanes=page_in_lanes)
+            else:
+                scatter_kv_write(pools, li, k_new, v_new, page_tables,
+                                 start_pos, num_tokens, page_size,
+                                 quantized)
         with jax.named_scope("mx.serve.paged_attn"):
             return ragged_paged_attention(
                 q, pools["k"], pools["v"], page_tables, ctx_lens,
                 start_pos, window=window, layer=li,
+                page_in_lanes=page_in_lanes,
                 k_scales=pools["k_scale"] if quantized else None,
                 v_scales=pools["v_scale"] if quantized else None)
 
-    def write(li, k_new, v_new):
-        B, Hkv, C, D = k_new.shape
-        pos = start_pos[:, None] + jnp.arange(C)[None, :]      # (B, C)
-        logical = jnp.minimum(pos // ps, page_tables.shape[1] - 1)
-        phys = jnp.take_along_axis(page_tables, logical, axis=1)
-        active = jnp.arange(C)[None, :] < num_tokens[:, None]
-        # one D-vector per (token, kv head): the scatter form that costs
-        # least next to the Pallas call (decode step 25.4 ms against
-        # 37.1 ms for a (Hkv, D)-window scatter — my chip run, PR 21).
-        # XLA:TPU still relayouts the whole pool around every layer's
-        # kernel either way; writing K/V inside the kernel is the fix
-        # (PERF.md §7)
-        head = jnp.tile(jnp.arange(Hkv), B * C)
-        page = jnp.repeat(
-            jnp.where(active, phys, NULL_PAGE).reshape(B * C), Hkv)
-        off = jnp.repeat((pos % ps).reshape(B * C), Hkv)
-
-        def scatter(name, new):
-            # (B, Hkv, C, D) -> (token, head)-major vectors, scattered
-            # straight into the stacked pool (in place under donation)
-            vecs = new.transpose(0, 2, 1, 3).reshape(B * C * Hkv, D)
-            if quantized:
-                from ..contrib.quantization import quantize_kv
-                vecs, scales = quantize_kv(vecs)
-                pools[name + "_scale"] = pools[name + "_scale"].at[
-                    li, head, page, off].set(scales)
-            pools[name] = pools[name].at[li, head, page, off].set(
-                vecs.astype(pools[name].dtype))
-
-        scatter("k", k_new)
-        scatter("v", v_new)
-
     return kv_fn
+
+
+def scatter_kv_write(pools: Dict[str, jax.Array], li, k_new, v_new,
+                     page_tables, start_pos, num_tokens, page_size: int,
+                     quantized: bool) -> None:
+    """The XLA-scatter form of the K/V write (updates `pools` in place):
+    the route of int8 pools and of every backend without the kernels, and
+    the oracle `paged_kv_write` is tested against.  Next to a Mosaic
+    custom call XLA:TPU relayouts the whole pool around a scatter of any
+    form (my chip run, PR 21), which is why the kernel route has none."""
+    ps = page_size
+    B, Hkv, C, D = k_new.shape
+    pos = start_pos[:, None] + jnp.arange(C)[None, :]      # (B, C)
+    logical = jnp.minimum(pos // ps, page_tables.shape[1] - 1)
+    phys = jnp.take_along_axis(page_tables, logical, axis=1)
+    active = jnp.arange(C)[None, :] < num_tokens[:, None]
+    # one D-vector per (token, kv head)
+    head = jnp.tile(jnp.arange(Hkv), B * C)
+    page = jnp.repeat(
+        jnp.where(active, phys, NULL_PAGE).reshape(B * C), Hkv)
+    off = jnp.repeat((pos % ps).reshape(B * C), Hkv)
+
+    def scatter(name, new):
+        # (B, Hkv, C, D) -> (token, head)-major vectors, scattered
+        # straight into the stacked pool (in place under donation)
+        vecs = new.transpose(0, 2, 1, 3).reshape(B * C * Hkv, D)
+        if quantized:
+            from ..contrib.quantization import quantize_kv
+            vecs, scales = quantize_kv(vecs)
+            pools[name + "_scale"] = pools[name + "_scale"].at[
+                li, head, page, off].set(scales)
+        pools[name] = pools[name].at[li, head, page, off].set(
+            vecs.astype(pools[name].dtype))
+
+    scatter("k", k_new)
+    scatter("v", v_new)

@@ -581,3 +581,80 @@ def test_fused_step_nan_skip_preserves_weights(monkeypatch):
                                    before[n])
                for n, v in step.pvals.items())
     assert step.trace_count == 1
+
+
+# ---------------------------------------------------------------------------
+# paged K/V write: the Pallas call against the XLA scatter it replaces
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("C", [1, 5, 16])
+def test_paged_kv_write_equals_the_scatter_bit_for_bit(C, dtype,
+                                                       page_in_lanes):
+    """`paged_kv_write` (interpret mode: the exact kernel code) leaves the
+    pool as the scatter route's `scatter_kv_write` does, bit for bit, in
+    either page orientation: a chunk that starts a page, one that
+    straddles a page boundary, ``0 < num_tokens < C``, the last logical
+    page with the padded rows past it (clamped), an idle slot, a slot
+    deep in a page; fewer kv heads than a GQA model's query heads,
+    ``layer`` != 0 of a stacked pool.  Every page no slot names is left
+    untouched, and the kernel never writes the null page (the scatter
+    parks its masked rows there)."""
+    from mxnet_tpu.ops.pallas.paged_attention import paged_kv_write
+    from mxnet_tpu.serve.kv_cache import NULL_PAGE, scatter_kv_write
+    rng = onp.random.RandomState(C)
+    L, li, Hkv, D, ps, B, maxp = 3, 1, 2, 64, 128, 6, 3
+    npages = B * maxp + 3                 # the null page + two unowned
+    pools = {n: jnp.asarray(rng.standard_normal((L, Hkv, npages, ps, D)),
+                            dtype) for n in "kv"}
+    pt = jnp.asarray(rng.permutation(onp.arange(1, B * maxp + 1))
+                     .reshape(B, maxp), jnp.int32)
+    start = jnp.asarray([0, ps - 2, ps + 3, maxp * ps - 3, 7, 2 * ps - 1],
+                        jnp.int32)
+    nt = jnp.asarray([C, C, max(C - 2, 1), min(C, 3), 0, C], jnp.int32)
+    kn = jnp.asarray(rng.standard_normal((B, Hkv, C, D)), jnp.float32)
+    vn = jnp.asarray(rng.standard_normal((B, Hkv, C, D)), jnp.float32)
+
+    want = dict(pools)
+    scatter_kv_write(want, li, kn, vn, pt, start, nt, ps, False)
+    got = dict(zip("kv", jax.jit(
+        lambda k, v: paged_kv_write(k, v, kn, vn, li, pt, start, nt,
+                                    null_page=NULL_PAGE,
+                                    page_in_lanes=page_in_lanes))(
+        pools["k"], pools["v"])))
+
+    def bits(x):
+        return onp.asarray(jax.lax.bitcast_convert_type(
+            x, jnp.uint16 if x.dtype == jnp.bfloat16 else jnp.uint32))
+
+    real = onp.arange(npages) != NULL_PAGE
+    named = onp.zeros(npages, bool)
+    named[onp.asarray(pt).ravel()] = True
+    for n in "kv":
+        g, w, o = bits(got[n]), bits(want[n]), bits(pools[n])
+        assert got[n].dtype == dtype and got[n].shape == pools[n].shape
+        onp.testing.assert_array_equal(g[:, :, real], w[:, :, real])
+        onp.testing.assert_array_equal(g[:, :, ~named], o[:, :, ~named])
+        others = onp.arange(L) != li
+        onp.testing.assert_array_equal(g[others], o[others])
+        assert (g[li] != o[li]).any()
+
+
+def test_pages_in_lanes_reads_the_arrays_own_layout():
+    """The orientation is asked of the pool's device layout: row-major on
+    the CPU (rows in sublanes); a tracer or anything without a layout is
+    row-major too."""
+    from mxnet_tpu.ops.pallas.paged_attention import pages_in_lanes
+    from mxnet_tpu.serve.kv_cache import KVPools
+    pools = KVPools.create(2, 5, 8, 2, 16, dtype="bfloat16")
+    assert pools.pages_in_lanes() is False
+    assert pages_in_lanes(object()) is False
+
+    class _Swapped:
+        class format:
+            class layout:
+                major_to_minor = (0, 1, 2, 4, 3)
+    assert pages_in_lanes(_Swapped()) is True

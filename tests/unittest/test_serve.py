@@ -247,6 +247,48 @@ def test_engine_concurrent_streaming_order_and_parity():
         assert h.state == "finished" and h.done()
 
 
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+def test_engine_kernel_route_streams_equal_reference_route(page_in_lanes,
+                                                           monkeypatch):
+    """Greedy streams on the kernel route (interpret mode: `paged_kv_write`
+    then `ragged_paged_attention`, in either page orientation) equal the
+    reference route's (XLA scatter + dense gather): prompts whose prefill
+    chunks straddle pages, decode across a page boundary, an idle slot."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    from mxnet_tpu.serve.kv_cache import KVPools
+    m = _tiny_model()
+    rng = onp.random.RandomState(5)
+    prompts = [rng.randint(0, 96, n).tolist() for n in (3, 13, 6)]
+    sc = dict(max_slots=4, page_size=8, prefill_chunk=5, max_len=32)
+
+    def streams():
+        eng = InferenceEngine(m, ServeConfig(**sc))
+        hs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+        eng.run_until_idle()
+        return [h.result(timeout=0) for h in hs]
+
+    want = streams()
+    assert want == [_ref_generate(m, p, 9) for p in prompts]
+
+    writes = []
+    kernel_write = pa.paged_kv_write
+
+    def counted(*a, **kw):
+        writes.append(kw["page_in_lanes"])
+        return kernel_write(*a, **kw)
+
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(pa, "paged_kv_write", counted)
+    monkeypatch.setattr(KVPools, "pages_in_lanes",
+                        lambda self: page_in_lanes)
+    assert streams() == want
+    # one write a layer a compiled width, all in the pool's orientation
+    assert len(writes) >= 2 and set(writes) == {page_in_lanes}
+
+
 def test_scheduler_admit_fifo_and_evict_youngest():
     """Admission is FIFO; page pressure evicts the YOUNGEST-admitted
     active (recompute preemption), which re-queues at the front and

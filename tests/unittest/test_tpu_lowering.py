@@ -258,34 +258,130 @@ def test_paged_attention_kernel_lowers_for_tpu_at_gpt2_shapes(dtype, C):
     assert txt.count("tpu_custom_call") == 1
 
 
-def test_gpt2_small_serve_step_lowers_with_paged_kernel(monkeypatch):
-    """The GPT-2-small-WIDTH serve step as a TPU would trace it (the
-    dispatch consults `jax.default_backend`): the default page size is
-    one the kernel accepts, and both compiled widths carry one
-    paged-attention custom call per layer — on the parent the step
-    lowered with ZERO custom calls (page size 16 < 128 took the dense
-    reference behind one warning)."""
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("C", [1, 16])
+def test_paged_kv_write_kernel_lowers_for_tpu_at_gpt2_shapes(dtype, C,
+                                                             page_in_lanes):
+    """`paged_kv_write` at GPT-2-small widths and the default TPU page
+    size, decode and a prefill chunk, in both page orientations: one
+    custom call, whose two results alias the pool operands."""
+    from mxnet_tpu.ops.pallas.paged_attention import LANES, paged_kv_write
+    Hkv, D = GPT2_SMALL["Hkv"], GPT2_SMALL["D"]
+    B, ps, n_layers = 8, LANES, 2
+    maxp = 1024 // ps
+    pool = jnp.ones((n_layers, Hkv, B * maxp + 1, ps, D), dtype)
+    new = jnp.ones((B, Hkv, C, D), jnp.float32)
+    pt = jnp.zeros((B, maxp), jnp.int32)
+    start = jnp.full((B,), 120, jnp.int32)
+
+    def f(kp, vp, kn, vn, pt, start, nt):
+        return paged_kv_write(kp, vp, kn, vn, 1, pt, start, nt,
+                              page_in_lanes=page_in_lanes)
+
+    txt = _lower_for_tpu(f, pool, pool, new, new, pt, start, start * 0 + C)
+    assert txt.count("tpu_custom_call") == 1
+    assert "output_operand_alias" in txt
+
+
+def _gpt2_width_engine(monkeypatch, page_in_lanes):
+    """A one-layer engine at GPT-2-small widths, traced as a TPU would
+    trace it (the dispatch consults `jax.default_backend`) with the pool
+    in the given orientation.  Depth and vocab are cut: neither shapes the
+    pool's calls."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.gpt import GPTConfig, GPTForCausalLM
-    from mxnet_tpu.ops.pallas.paged_attention import LANES
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
-
-    # GPT-2-small widths (768 hidden, 12 heads of 64); depth and vocab are
-    # cut — neither shapes the attention call this test pins
-    n_layers = 1
-    cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=n_layers,
+    from mxnet_tpu.serve.kv_cache import KVPools
+    cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=1,
                     vocab_size=1024)
     model = GPTForCausalLM(cfg)
     model.initialize()
     model(mx.np.array([[1, 2]], dtype="int32"))    # eager init on the cpu
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    eng = InferenceEngine(model, ServeConfig(max_len=1024))
+    monkeypatch.setattr(KVPools, "pages_in_lanes",
+                        lambda self: page_in_lanes)
+    return InferenceEngine(model, ServeConfig(max_len=1024))
+
+
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+def test_gpt2_small_serve_step_lowers_with_paged_kernel(monkeypatch,
+                                                        page_in_lanes):
+    """The GPT-2-small-WIDTH serve step as a TPU would trace it: the
+    default page size is one the kernels accept, and every compiled width
+    carries two custom calls a layer (the K/V write, then the paged
+    attention) and NO scatter or dynamic-update-slice over a pool-shaped
+    operand: the pools pass through custom calls alone, so XLA:TPU has no
+    layout of its own to give them (on the parent, one custom call a
+    layer beside 2 scatters, and 96% of a decode step in whole-pool
+    relayout copies)."""
+    import re
+    from mxnet_tpu.ops.pallas.paged_attention import LANES
+    eng = _gpt2_width_engine(monkeypatch, page_in_lanes)
+    n_layers = 1
     assert eng.serve_config.page_size == LANES
+    pool = eng.pools.arrays["k"]
+    # tensor<1x12x65x128x64xbf16>, in either order of the last two dims
+    dims = "x".join(map(str, pool.shape[:3])) + "x(128x64|64x128)xbf16"
+    writes_pool = re.compile(
+        r"stablehlo\.(scatter|dynamic_update_slice)[^\n]*tensor<" + dims)
     for C in eng._step_widths():
         txt = eng._step_fn(C).trace(*eng._step_avals(C)).lower(
             lowering_platforms=("tpu",)).as_text()
-        assert txt.count("tpu_custom_call") == n_layers, (C, txt.count(
+        assert txt.count("tpu_custom_call") == 2 * n_layers, (C, txt.count(
             "tpu_custom_call"))
+        assert not writes_pool.search(txt), (C, writes_pool.search(txt))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip to compile for; the test that
+    asks is skipped where no such topology can be described."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
+        monkeypatch, v5e_chip):
+    """Mosaic and XLA:TPU run at `.compile()`.  A v5e keeps a bf16
+    (..., 128, 64) pool as ``{3,4,2,1,0}`` (the page's rows in lanes; my
+    chip run, PR 28).  With the step's pools pinned to that layout at both
+    ends and the kernels in that orientation, the compiled module of every
+    width holds no `copy` of a pool-sized array (the logical transposes
+    around the calls are bitcasts) and its temporaries are a fraction of a
+    pool.  Fed the other orientation the same pools cost a whole-pool copy
+    at each end of the step, which is what `pages_in_lanes` is for."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    eng = _gpt2_width_engine(monkeypatch, page_in_lanes=True)
+    pool = eng.pools.arrays["k"]
+    pool_fmt = Format(Layout(major_to_minor=(0, 1, 2, 4, 3)), v5e_chip)
+
+    def described(x):
+        fmt = pool_fmt if x.shape == pool.shape else v5e_chip
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=fmt)
+
+    pool_copy = re.compile(
+        r"= bf16\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
+    for C in eng._step_widths():
+        fn = jax.jit(eng._step_fn(C).__wrapped__, donate_argnums=(1,),
+                     out_shardings=((pool_fmt, pool_fmt), v5e_chip))
+        avals = jax.tree_util.tree_map(described, eng._step_avals(C))
+        compiled = fn.trace(*avals).lower(
+            lowering_platforms=("tpu",)).compile()
+        txt = compiled.as_text()
+        assert txt.count("tpu_custom_call") == 2
+        assert not pool_copy.search(txt), (C, pool_copy.search(txt))
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < pool.size * pool.dtype.itemsize // 4
 
 
 def test_gspmd_mesh_step_takes_references_and_shard_map_keeps_kernels(
