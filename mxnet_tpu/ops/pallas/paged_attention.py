@@ -145,7 +145,8 @@ def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
+def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False,
+                     from_first=False):
     """Build the kernel body with static shape parameters.
 
     One (slot, kv-head, page) grid step: rows are the GQA fold — row r =
@@ -156,21 +157,28 @@ def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
     q/k/v go to the MXU as stored and accumulate in f32.  With
     `page_in_lanes` the K/V blocks are (D, page_size) tiles (see
     `pages_in_lanes`): K^T feeds the score matmul as it lies and V^T is
-    contracted over its lane dim, the form q.K^T has otherwise."""
+    contracted over its lane dim, the form q.K^T has otherwise.  With
+    `from_first` a fourth prefetched scalar a slot gives its first live
+    page and the page axis walks from there (a windowed layer's table
+    holds nothing a query can see before it)."""
     from jax.experimental import pallas as pl
 
     nt_dims = (((1,), (1,)), ((), ()))      # contract both minor dims
 
-    def kernel(pt_ref, ctx_ref, start_ref, q_ref, k_ref, v_ref,
-               o_ref, m_scr, l_scr, acc_scr):
+    def kernel(pt_ref, ctx_ref, start_ref, *refs):
+        if from_first:
+            first_ref, refs = refs[0], refs[1:]
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
         b = pl.program_id(0)
-        pi = pl.program_id(2)
+        step = pl.program_id(2)
         n_pages = pl.num_programs(2)
+        # the logical page this step reads
+        pi = step + first_ref[b] if from_first else step
 
         rows, d = q_ref.shape
         ps = k_ref.shape[1 if page_in_lanes else 0]
 
-        @pl.when(pi == 0)
+        @pl.when(step == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
             l_scr[...] = jnp.zeros_like(l_scr)
@@ -221,9 +229,14 @@ def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
 
         # skip pages entirely past the slot's context (the ragged win:
         # a decode slot with 40 tokens touches 3 pages, not max_pages)
-        pl.when(pi * ps < ctx)(_step)
+        live = pi * ps < ctx
+        if window is not None:
+            # nor pages wholly before the first query's window: masked
+            # to exact zeros anyway, so skipping them changes no bit
+            live &= (pi + 1) * ps > start - window
+        pl.when(live)(_step)
 
-        @pl.when(pi == n_pages - 1)
+        @pl.when(step == n_pages - 1)
         def _store():
             l = l_scr[...]
             l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -244,11 +257,14 @@ def _lanes(x, n):
 
 
 def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
-                window, scale, page_in_lanes=False):
+                window, scale, page_in_lanes=False, first_page=None,
+                walk_pages=None):
     """Launch the Pallas kernel over the stacked ``(n_layers, Hkv, pages,
     ps, D)`` pools (shapes pre-validated by the wrapper).  The layer is
     picked in the K/V index map, so XLA never materialises a per-layer
-    slice of the pool to feed the custom call."""
+    slice of the pool to feed the custom call.  `first_page` (B,) with
+    the static `walk_pages`: the page axis of the grid is `walk_pages`
+    long and slot b walks its table from ``first_page[b]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -267,10 +283,14 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     rows_p = rows + pad
 
-    def q_map(b, h, pi, pt, ctx, st):
+    from_first = first_page is not None
+
+    def q_map(b, h, pi, *scalars):
         return (b, h, 0, 0)
 
-    def kv_map(b, h, pi, pt, ctx, st):
+    def kv_map(b, h, pi, pt, ctx, st, *first):
+        if from_first:
+            pi = jnp.minimum(pi + first[0][b], maxp - 1)
         return (layer, h, pt[b, pi], 0, 0)
 
     kv_block = (None, None, None, ps, D)
@@ -279,8 +299,8 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
         kv_block = (None, None, None, D, ps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, maxp),
+        num_scalar_prefetch=4 if from_first else 3,
+        grid=(B, Hkv, walk_pages if from_first else maxp),
         in_specs=[
             pl.BlockSpec((None, None, rows_p, D), q_map),
             pl.BlockSpec(kv_block, kv_map),
@@ -293,16 +313,19 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
             pltpu.VMEM((rows_p, D), jnp.float32),
         ],
     )
+    scalars = (page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+               start_pos.astype(jnp.int32))
+    if from_first:
+        scalars += (first_page.astype(jnp.int32),)
     out = pl.pallas_call(
-        _make_rpa_kernel(scale, C, rep, window, page_in_lanes),
+        _make_rpa_kernel(scale, C, rep, window, page_in_lanes, from_first),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
         name="ragged_paged_attention",
-    )(page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      start_pos.astype(jnp.int32), qf, kpool, vpool)
+    )(*scalars, qf, kpool, vpool)
     return out[:, :, :rows].reshape(B, H, C, D)
 
 
@@ -512,7 +535,8 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
 def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                            start_pos, window=None, scale=None,
                            k_scales=None, v_scales=None, use_kernel=None,
-                           layer=None, page_in_lanes=False):
+                           layer=None, page_in_lanes=False,
+                           first_page=None, walk_pages=None):
     """Mixed prefill/decode attention over a paged KV pool — one launch.
 
     q: (B, H, C, D) chunk queries (C = 1 for a pure-decode step);
@@ -524,6 +548,13 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
     written to the pool); start_pos: (B,) absolute position of each
     slot's first chunk token.  Rows past a slot's real token count
     produce causally-valid garbage the caller must ignore.
+
+    `window`: how many EARLIER keys a query sees besides itself.  With
+    `first_page` (B,) and the static `walk_pages`, the kernel walks only
+    pages ``first_page[b] .. first_page[b] + walk_pages - 1`` of slot b's
+    table: the caller vouches that no query of the chunk sees a key
+    outside them (a windowed layer whose older pages went back to the
+    allocator; their table entries may name any page).
 
     fp pools run the Pallas kernel wherever the package's ``MXTPU_PALLAS``
     policy makes kernels active (a TPU backend, or ``kernel`` mode); a
@@ -556,7 +587,8 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
         return _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens,
                            start_pos, window,
                            scale if scale is not None
-                           else 1.0 / math.sqrt(D), page_in_lanes)
+                           else 1.0 / math.sqrt(D), page_in_lanes,
+                           first_page, walk_pages)
     return paged_attention_reference(
         q, kpool[layer], vpool[layer], page_tables, ctx_lens, start_pos,
         window=window, scale=scale,

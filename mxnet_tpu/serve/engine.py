@@ -46,9 +46,9 @@ from .. import telemetry as _tele
 from .. import tracing as _trace
 from .decode import (extract_decode_weights, transformer_step, lm_logits,
                      quantize_decode_weights, decode_weight_bytes,
-                     tp_qkv_row_perm)
+                     tp_qkv_row_perm, decode_spec)
 from .kv_cache import (KVPools, PageAllocator, PrefixIndex,
-                       make_paged_kv_fn)
+                       make_paged_kv_fn, window_walk_pages)
 from .scheduler import ContinuousBatchingScheduler, ServeRequest
 from .spec import Drafter, NGramDrafter
 
@@ -179,8 +179,11 @@ class InferenceEngine:
 
         cfg = self.cfg
         H = cfg.num_heads
+        #: what each layer is (norms, attention kind, FFN kind, cache
+        #: group): from the model's configuration alone
+        self.spec = decode_spec(cfg)
         self.n_kv_heads = getattr(cfg, "num_kv_heads", None) or H
-        self.head_dim = cfg.hidden_size // H
+        self.head_dim = self.spec.head_dim
         self.max_len = sc.max_len or cfg.max_position
         if self.max_len > cfg.max_position:
             raise MXNetError(
@@ -220,16 +223,26 @@ class InferenceEngine:
         num_pages = sc.num_pages or \
             sc.max_slots * self.max_pages_per_seq + 1 + bonus
         self.bonus_pages = bonus
+        sliding = self._plan_sliding_group(sc)
         self.pools = KVPools.create(
-            cfg.num_layers, num_pages, sc.page_size, self.n_kv_heads,
-            self.head_dim, dtype=kv_dtype)
+            len(self.spec.group_layers("full")), num_pages, sc.page_size,
+            self.n_kv_heads, self.head_dim, dtype=kv_dtype, sliding=sliding)
         if self.tp > 1:
             self._tp_shard_pools()
         self.allocator = PageAllocator(num_pages, sc.page_size)
+        #: the sliding group's own free list (None: the model has no
+        #: window layers, one pool holds every layer)
+        self.sliding_allocator = (
+            None if sliding is None
+            else PageAllocator(sliding[1], sc.page_size))
         #: cross-request prompt-prefix cache (MXTPU_PREFIX_CACHE):
-        #: shared read-only page runs with COW forks; None when off
+        #: shared read-only page runs with COW forks; None when off.
+        #: Off too for a model with a sliding group: a prefix may be
+        #: shared only where every group still holds it, and the sliding
+        #: group has let the prompt's pages go (docs/serving.md)
         self.prefix_index = (PrefixIndex(self.allocator, sc.page_size)
-                             if sc.prefix_cache else None)
+                             if sc.prefix_cache and sliding is None
+                             else None)
         #: speculative-decoding proposal hook (MXTPU_SPEC_TOKENS)
         self.drafter = drafter if drafter is not None else (
             NGramDrafter() if sc.spec_tokens > 0 else None)
@@ -244,11 +257,41 @@ class InferenceEngine:
         self._key = jax.random.PRNGKey(seed)
         self.compile_seconds = None
         self._steps_executed = 0
+        #: the last step's routing counts (expert layers, held experts),
+        #: None for a model without expert layers
+        self.last_moe_counts = None
         #: `perf_counter` instant the last step's executable call
         #: returned (`_execute`): the scheduler's launch/wait boundary
         self.launched_ts = 0.0
         self._note_weight_bytes()
         _health.beat("serve.step")   # announce the heartbeat name early
+
+    def _plan_sliding_group(self, sc):
+        """``(layers, pages)`` of the sliding group's pool, or None for
+        a model without window layers.  Sized so that every slot can hold
+        what a chunk of the widest step can see (`window_walk_pages`),
+        plus the null page; an explicit ``num_pages`` caps it too."""
+        layers = self.spec.group_layers("sliding")
+        self.sliding_window = self.sliding_walk = None
+        if not layers:
+            return None
+        windows = {self.spec.layers[i].window for i in layers}
+        if len(windows) != 1 or None in windows:
+            raise MXNetError(
+                f"the sliding cache group needs ONE window for all its "
+                f"layers, got {sorted(map(str, windows))}")
+        if sc.role != "both":
+            raise MXNetError(
+                "a model with a sliding cache group serves with "
+                "role='both': the prefill->decode handoff moves the full "
+                "group's pages only")
+        self.sliding_window = windows.pop()
+        self.sliding_walk = min(
+            self.max_pages_per_seq,
+            window_walk_pages(self.sliding_window, max(self._step_widths()),
+                              sc.page_size))
+        pages = sc.max_slots * self.sliding_walk + 1
+        return len(layers), min(pages, sc.num_pages or pages)
 
     # ------------------------------------------------------------------
     # weight-only quantization (docs/quantization.md)
@@ -309,9 +352,9 @@ class InferenceEngine:
                 sc = self.serve_config
                 num_pages = self.pools.num_pages + bonus
                 self.pools = KVPools.create(
-                    self.cfg.num_layers, num_pages, sc.page_size,
+                    self.pools.n_layers, num_pages, sc.page_size,
                     self.n_kv_heads, self.head_dim,
-                    dtype=self._kv_dtype)
+                    dtype=self._kv_dtype, sliding=self.pools.sliding)
                 self.allocator = PageAllocator(num_pages, sc.page_size)
                 self.bonus_pages = bonus
                 if getattr(self, "prefix_index", None) is not None:
@@ -364,7 +407,9 @@ class InferenceEngine:
         tp = fit_axes(len(jax.devices()), tp=want)["tp"]
         dims = [self.n_kv_heads, self.cfg.num_heads,
                 self.cfg.hidden_size]
-        if self.P["layers"]:
+        if any(ls.norm != "layernorm" for ls in self.spec.layers):
+            dims.append(1)        # only the GPT block has a tp scheme
+        elif self.P["layers"]:
             dims.append(self._outdim(self.P["layers"][0]["w1"]))
         if self.P.get("head") is not None:
             dims.append(self._outdim(self.P["head"]))
@@ -449,7 +494,11 @@ class InferenceEngine:
         cfg = self.cfg
         sc = self.serve_config
         ps = sc.page_size
-        window = getattr(cfg, "window", None)
+        spec = self.spec
+        layer_plan = spec.cache_plan()
+        has_sliding = self.sliding_allocator is not None
+        sliding_walk = self.sliding_walk
+        has_moe = any(ls.ffn == "moe" for ls in spec.layers)
         quantized = self.quantized
         pool_names = self.pools.names
         page_in_lanes = self.pools.pages_in_lanes()
@@ -460,23 +509,32 @@ class InferenceEngine:
         tp_axis = "tp" if tp > 1 else None
 
         def step(P, pools_t, tok, num_tokens, start_pos, page_tables,
-                 ctx_lens, temps, greedy_mask, key):
+                 ctx_lens, temps, greedy_mask, key, sliding_tables=None):
             from ..models.gpt import _filter_logits
             pools = dict(zip(pool_names, pools_t))
             kv_fn = make_paged_kv_fn(pools, page_tables, start_pos,
                                      num_tokens, ctx_lens, ps, quantized,
-                                     window=window,
-                                     page_in_lanes=page_in_lanes)
+                                     page_in_lanes=page_in_lanes,
+                                     layer_plan=layer_plan,
+                                     sliding_tables=sliding_tables,
+                                     sliding_walk=sliding_walk)
             # padded rows may run past the table; clamp for the embedding
             # gather only (writes are masked, attention rows are ignored)
             pos = jnp.minimum(start_pos[:, None] + jnp.arange(C)[None, :],
                               max_pos - 1)
-            h = transformer_step(P, cfg, tok, pos, kv_fn,
-                                 tp=tp, tp_axis=tp_axis)
+            aux = {}
+            h = transformer_step(
+                P, cfg, tok, pos, kv_fn, tp=tp, tp_axis=tp_axis,
+                row_valid=(jnp.arange(C)[None, :] < num_tokens[:, None])
+                if has_moe else None, aux=aux)
+            # routing counts leave with the step's tokens: (expert
+            # layers, held experts), no second pass over the router
+            tail = (jnp.stack(aux["moe_counts"]),) if has_moe else ()
             B = tok.shape[0]
             with jax.named_scope("mx.serve.sample"):
                 last = h[jnp.arange(B), jnp.maximum(num_tokens - 1, 0)]
-                logits = lm_logits(P, last, tp, tp_axis)      # (B, V)
+                logits = lm_logits(P, last, tp, tp_axis,
+                                   spec.cast_inputs)          # (B, V)
                 greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 filtered = _filter_logits(
                     logits.astype(jnp.float32) / temps[:, None],
@@ -506,13 +564,15 @@ class InferenceEngine:
                     [jnp.argmax(lm_logits(
                         P, h[jnp.arange(B),
                              jnp.maximum(num_tokens - T + j, 0)],
-                        tp, tp_axis),
+                        tp, tp_axis, spec.cast_inputs),
                         axis=-1)
                      for j in range(T)], axis=1).astype(jnp.int32)
-                return tuple(pools[n] for n in pool_names), nxt, all_tok
-            return tuple(pools[n] for n in pool_names), nxt
+                return (tuple(pools[n] for n in pool_names), nxt,
+                        all_tok) + tail
+            return (tuple(pools[n] for n in pool_names), nxt) + tail
 
         if tp > 1:
+            assert not has_sliding and not has_moe   # `_resolve_tp`
             # the body runs per-shard: weights/pools arrive as their
             # local OUT-dim / kv-head shards, batch inputs replicated;
             # every cross-shard combine inside is an all-gather, so the
@@ -754,7 +814,8 @@ class InferenceEngine:
             sd((B, self.max_pages_per_seq), i32), sd((B,), i32),
             sd((B,), jnp.float32), sd((B,), jnp.bool_),
             sd(self._key.shape, self._key.dtype),
-        )
+        ) + ((sd((B, self.max_pages_per_seq), i32),)
+             if self.sliding_allocator is not None else ())
 
     def _compile(self, C: int):
         ex = self._execs.get(C)
@@ -803,11 +864,15 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def _execute(self, tok, num_tokens, start_pos, tables, ctx_lens,
-                 temps, greedy_mask, C: int):
+                 temps, greedy_mask, C: int, sliding_tables=None):
         """Run one fused step (called by the scheduler); returns
         ``(next_token[B], all_tok)`` as host numpy — `all_tok` is the
         (B, C) per-position greedy argmax when speculation is enabled,
-        else None.  Leaves in ``self.launched_ts`` the `perf_counter`
+        else None.  `sliding_tables`: the sliding group's page tables,
+        for a model that has the group.  A model with expert layers also
+        leaves the step's routing counts, (expert layers, held experts),
+        in ``self.last_moe_counts``, read back with the tokens.
+        Leaves in ``self.launched_ts`` the `perf_counter`
         instant the executable's call returned: the boundary between
         the step's ``launch`` phase (key split, seven host-to-device
         transfers, dispatch) and its ``wait`` (blocking on the tokens),
@@ -824,22 +889,26 @@ class InferenceEngine:
         with _trace.annotation("serve.step.launch"):
             self._key, sub = jax.random.split(self._key)
             with self._device_lock:
+                more = () if sliding_tables is None \
+                    else (jnp.asarray(sliding_tables),)
                 out = ex(
                     self.P, self.pools.as_tuple(), jnp.asarray(tok),
                     jnp.asarray(num_tokens), jnp.asarray(start_pos),
                     jnp.asarray(tables), jnp.asarray(ctx_lens),
-                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
-                if self.serve_config.spec_tokens > 0:
-                    out_pools, nxt, all_tok = out
-                else:
-                    (out_pools, nxt), all_tok = out, None
+                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub,
+                    *more)
+                out_pools, nxt, rest = out[0], out[1], list(out[2:])
+                all_tok = rest.pop(0) \
+                    if self.serve_config.spec_tokens > 0 else None
+                counts = rest.pop(0) if rest else None
                 # rebind the donated pool buffers to the step's outputs
                 self.pools = self.pools.replace(out_pools)
             self.launched_ts = time.perf_counter()
         with _trace.annotation("serve.step.wait"):
-            return (onp.asarray(jax.device_get(nxt)),
-                    None if all_tok is None
-                    else onp.asarray(jax.device_get(all_tok)))
+            nxt, all_tok, counts = jax.device_get((nxt, all_tok, counts))
+            self.last_moe_counts = counts
+            return (onp.asarray(nxt),
+                    None if all_tok is None else onp.asarray(all_tok))
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device-copy ONE physical page (every layer, K + V + scale
@@ -856,7 +925,7 @@ class InferenceEngine:
         d = jnp.int32(dst)
         with self._device_lock:
             arrs = self.pools.arrays
-            for name in self.pools.names:
+            for name in self.pools.full_names:
                 arrs[name] = self._cow_fn(arrs[name], s, d)
 
     # ------------------------------------------------------------------
@@ -871,7 +940,7 @@ class InferenceEngine:
         with self._device_lock:
             return {name: onp.asarray(
                         jax.device_get(self.pools.arrays[name][:, :, ids]))
-                    for name in self.pools.names}
+                    for name in self.pools.full_names}
 
     def install_pages(self, page_ids, arrays: dict) -> None:
         """Scatter `export_pages`-shaped contents into this engine's
@@ -886,7 +955,7 @@ class InferenceEngine:
         ids = jnp.asarray(page_ids, jnp.int32)
         with self._device_lock:
             arrs = self.pools.arrays
-            for name in self.pools.names:
+            for name in self.pools.full_names:
                 arrs[name] = self._install_fn(
                     arrs[name], ids,
                     jnp.asarray(arrays[name], arrs[name].dtype))
@@ -963,6 +1032,10 @@ class InferenceEngine:
             "queue_depth": self.scheduler.queue_depth,
             "active_slots": self.scheduler.active_count,
             "free_pages": self.allocator.free_pages,
+            "free_pages_sliding": (
+                None if self.sliding_allocator is None
+                else self.sliding_allocator.free_pages),
+            "kv_pages_released": self.scheduler.kv_pages_released,
             "page_occupancy": round(self.allocator.occupancy(), 4),
             "pool_bytes": self.pools.nbytes(),
             "weight_bytes": self.weight_bytes(),

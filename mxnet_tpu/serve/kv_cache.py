@@ -22,6 +22,17 @@ and out, so that the donated pools pass through custom calls alone and
 are never relayouted; everywhere else (int8 pools, the CPU,
 ``MXTPU_PALLAS`` off) by an XLA scatter, `scatter_kv_write`.
 
+**Cache groups** (docs/serving.md "Layers and cache groups"): a model
+whose layers are not all of one kind has one pool a kind.  The ``full``
+group (arrays ``k`` / ``v``) keeps a sequence's whole context, as above.
+The ``sliding`` group (arrays ``k_sliding`` / ``v_sliding``, with a page
+table and an allocator of its own) holds the layers whose queries see a
+window only: a page that lies wholly before ``cursor - window`` goes back
+to its allocator (`window_first_page`), its table entry becomes the null
+page, and the attention kernel is told the slot's first live page and
+walks `window_walk_pages` pages from there, never the whole table.  A
+model of one kind of layer (GPT-2) has the ``full`` group alone.
+
 ``kv_dtype="int8"`` stores the pool quantized (symmetric per-token-per-head
 int8 via `contrib/quantization.quantize_kv`) at ~4x less HBM per token;
 attention dequantizes only the gathered context.
@@ -52,9 +63,28 @@ import jax.numpy as jnp
 from ..base import MXNetError
 
 __all__ = ["PageAllocator", "PrefixIndex", "KVPools", "make_paged_kv_fn",
-           "NULL_PAGE"]
+           "NULL_PAGE", "SLIDING", "window_first_page", "window_walk_pages"]
 
 NULL_PAGE = 0
+#: suffix of the sliding group's pool arrays (``k_sliding``/``v_sliding``)
+SLIDING = "_sliding"
+
+
+def window_first_page(cursor, window: int, page_size: int):
+    """First logical page a query at position >= `cursor` can still see
+    in a layer whose queries see `window` earlier keys: every page before
+    it lies wholly before ``cursor - window``.  Works on ints and on
+    arrays (the host's release rule and the kernel's first live page are
+    this one expression)."""
+    return (cursor - window) // page_size * (cursor > window)
+
+
+def window_walk_pages(window: int, chunk: int, page_size: int) -> int:
+    """Pages a chunk of `chunk` queries can see in such a layer: keys
+    ``start - window .. start + chunk - 1`` span at most this many pages
+    wherever `start` falls (``window / page + 2`` for a 4096-key window,
+    pages of 128 and chunks of 16)."""
+    return -(-(window + chunk) // page_size) + 1
 
 
 class PageAllocator:
@@ -409,6 +439,11 @@ class KVPools:
     - ``k_scale``/``v_scale``: (n_layers, Hkv, num_pages, page_size)
       float32 (int8 pools only; one symmetric scale per stored vector)
 
+    - ``k_sliding``/``v_sliding``: (sliding layers, Hkv, sliding pages,
+      page_size, D): the sliding group's pool, where the model has one
+      (`sliding` = (layers, pages)); ``n_layers``/``num_pages`` count the
+      full group.
+
     The arrays are exposed as a flat tuple (`as_tuple`) so the engine can
     pass them through a jitted step with ``donate_argnums`` and rebind the
     donated outputs (`replace`).
@@ -416,7 +451,7 @@ class KVPools:
 
     def __init__(self, arrays: Dict[str, jax.Array], n_layers: int,
                  num_pages: int, page_size: int, n_kv_heads: int,
-                 head_dim: int, quantized: bool):
+                 head_dim: int, quantized: bool, sliding=None):
         self.arrays = arrays
         self.n_layers = n_layers
         self.num_pages = num_pages
@@ -424,10 +459,12 @@ class KVPools:
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.quantized = quantized
+        self.sliding = sliding
 
     @classmethod
     def create(cls, n_layers: int, num_pages: int, page_size: int,
-               n_kv_heads: int, head_dim: int, dtype="float32") -> "KVPools":
+               n_kv_heads: int, head_dim: int, dtype="float32",
+               sliding=None) -> "KVPools":
         quantized = str(dtype) == "int8"
         shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         store_dt = jnp.int8 if quantized else jnp.dtype(dtype)
@@ -437,8 +474,17 @@ class KVPools:
             sshape = shape[:-1]
             arrays["k_scale"] = jnp.zeros(sshape, jnp.float32)
             arrays["v_scale"] = jnp.zeros(sshape, jnp.float32)
+        if sliding is not None:
+            if quantized:
+                raise MXNetError(
+                    "an int8 KV pool has no sliding group: serve a model "
+                    "with window layers with an fp kv_dtype")
+            sshape = (sliding[0], n_kv_heads, sliding[1], page_size,
+                      head_dim)
+            arrays["k" + SLIDING] = jnp.zeros(sshape, store_dt)
+            arrays["v" + SLIDING] = jnp.zeros(sshape, store_dt)
         return cls(arrays, n_layers, num_pages, page_size, n_kv_heads,
-                   head_dim, quantized)
+                   head_dim, quantized, sliding)
 
     @property
     def names(self):
@@ -451,7 +497,13 @@ class KVPools:
         """Rebind to the donated step outputs (same metadata)."""
         return KVPools(dict(zip(self.names, values)), self.n_layers,
                        self.num_pages, self.page_size, self.n_kv_heads,
-                       self.head_dim, self.quantized)
+                       self.head_dim, self.quantized, self.sliding)
+
+    @property
+    def full_names(self):
+        """The full group's arrays: what a page id of the full group's
+        allocator indexes (page copies, handoff export/install)."""
+        return tuple(n for n in self.names if not n.endswith(SLIDING))
 
     def pages_in_lanes(self) -> bool:
         """Does the device keep a page's rows in lanes (a (D, page_size)
@@ -467,7 +519,9 @@ class KVPools:
 
 def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
                      num_tokens, ctx_lens, page_size: int, quantized: bool,
-                     window=None, page_in_lanes: bool = False):
+                     window=None, page_in_lanes: bool = False,
+                     layer_plan=None, sliding_tables=None,
+                     sliding_walk: Optional[int] = None):
     """Build the `kv_fn` closure `transformer_step` calls per layer inside
     the jitted serving step: write the chunk's new K/V into the paged
     pool, then attend over each slot's pages via
@@ -492,35 +546,53 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     (B,) int32.  Chunk token c of slot b sits at absolute position
     ``start_pos[b] + c`` and is real iff ``c < num_tokens[b]`` — padded
     rows scatter to the null page (the kernel drops them).
+
+    `layer_plan`: one ``(group, index in the group's pool, window)`` a
+    layer, from the model's `DecodeSpec` (None: every layer in the full
+    group at its own index under the one `window`, the GPT block).  A
+    ``"sliding"`` layer writes and reads ``k_sliding``/``v_sliding``
+    through `sliding_tables` (same logical indexing; released pages are
+    the null page) and attends from each slot's first live page over
+    `sliding_walk` pages (`window_first_page`, `window_walk_pages`).
     """
     from ..ops.pallas.paged_attention import (
         paged_kernel_route, paged_kv_write, ragged_paged_attention)
 
     def kv_fn(li, q, k_new, v_new):
+        group, gi, win = ("full", li, window) if layer_plan is None \
+            else layer_plan[li]
+        sliding = group == "sliding"
+        kn, vn = ("k" + SLIDING, "v" + SLIDING) if sliding else ("k", "v")
+        tables = sliding_tables if sliding else page_tables
         with jax.named_scope("mx.serve.pool_write"):
             if paged_kernel_route(quantized):
-                pools["k"], pools["v"] = paged_kv_write(
-                    pools["k"], pools["v"], k_new, v_new, li, page_tables,
+                pools[kn], pools[vn] = paged_kv_write(
+                    pools[kn], pools[vn], k_new, v_new, gi, tables,
                     start_pos, num_tokens, null_page=NULL_PAGE,
                     page_in_lanes=page_in_lanes)
             else:
-                scatter_kv_write(pools, li, k_new, v_new, page_tables,
+                scatter_kv_write(pools, gi, k_new, v_new, tables,
                                  start_pos, num_tokens, page_size,
-                                 quantized)
+                                 quantized, names=(kn, vn))
         with jax.named_scope("mx.serve.paged_attn"):
+            walk = {}
+            if sliding:
+                walk = dict(
+                    first_page=window_first_page(start_pos, win, page_size),
+                    walk_pages=min(sliding_walk, tables.shape[1]))
             return ragged_paged_attention(
-                q, pools["k"], pools["v"], page_tables, ctx_lens,
-                start_pos, window=window, layer=li,
+                q, pools[kn], pools[vn], tables, ctx_lens,
+                start_pos, window=win, layer=gi,
                 page_in_lanes=page_in_lanes,
                 k_scales=pools["k_scale"] if quantized else None,
-                v_scales=pools["v_scale"] if quantized else None)
+                v_scales=pools["v_scale"] if quantized else None, **walk)
 
     return kv_fn
 
 
 def scatter_kv_write(pools: Dict[str, jax.Array], li, k_new, v_new,
                      page_tables, start_pos, num_tokens, page_size: int,
-                     quantized: bool) -> None:
+                     quantized: bool, names=("k", "v")) -> None:
     """The XLA-scatter form of the K/V write (updates `pools` in place):
     the route of int8 pools and of every backend without the kernels, and
     the oracle `paged_kv_write` is tested against.  Next to a Mosaic
@@ -550,5 +622,5 @@ def scatter_kv_write(pools: Dict[str, jax.Array], li, k_new, v_new,
         pools[name] = pools[name].at[li, head, page, off].set(
             vecs.astype(pools[name].dtype))
 
-    scatter("k", k_new)
-    scatter("v", v_new)
+    scatter(names[0], k_new)
+    scatter(names[1], v_new)

@@ -52,7 +52,7 @@ from .. import telemetry as _tele
 from .. import tracing as _trace
 from . import qos as _qos
 from . import traffic as _traffic
-from .kv_cache import NULL_PAGE
+from .kv_cache import NULL_PAGE, window_first_page
 
 __all__ = ["ServeRequest", "ContinuousBatchingScheduler",
            "terminate_request", "finish_request", "deliver_token"]
@@ -314,6 +314,11 @@ class _Slot:
         self.slot_idx = slot_idx
         self.pages: List[int] = []
         self.table = onp.zeros(max_pages, onp.int32)   # NULL_PAGE fill
+        # the sliding cache group (a model with window layers): logical
+        # page -> physical page of the pages still live, and the table
+        # the step reads (released and unallocated entries: NULL_PAGE)
+        self.wpages: dict = {}
+        self.wtable = onp.zeros(max_pages, onp.int32)
         self.ctx = 0          # tokens already written to the pool
         self.admit_seq = admit_seq    # admission order (eviction priority)
         # prompt blocks registered in the engine's PrefixIndex (once,
@@ -347,6 +352,11 @@ class ContinuousBatchingScheduler:
         self.max_len = engine.max_len
         self.max_pages_per_seq = engine.max_pages_per_seq
         self.allocator = engine.allocator
+        # the sliding cache group's free list and window (None: the model
+        # keeps every layer's whole context in the one pool)
+        self.sliding_allocator = engine.sliding_allocator
+        self.sliding_window = engine.sliding_window
+        self.kv_pages_released = 0   # sliding pages let go behind windows
         self._queue: deque = deque()
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
         self._lock = threading.Lock()
@@ -651,6 +661,10 @@ class ContinuousBatchingScheduler:
                 if index is not None:
                     attached, hit = index.lookup(seq[:-1])
                 need = self.allocator.pages_for(len(seq) + 1)
+                if self.sliding_allocator is not None and not \
+                        self.sliding_allocator.can_alloc(
+                            min(need, self.engine.sliding_walk)):
+                    return     # the sliding group is dry: wait for frees
                 pages = self._alloc_pages(need - len(attached))
                 if pages is None:
                     # OOM backpressure: wait for frees (the attached
@@ -692,6 +706,7 @@ class ContinuousBatchingScheduler:
         """Recycle a slot's KV pages and vacate it — the one way any
         request leaves the active set."""
         self.allocator.free(slot.pages)
+        self._drop_sliding(slot, list(slot.wpages))
         self._slots[slot.slot_idx] = None
 
     def _evict(self, slot: _Slot, reason: str) -> None:
@@ -713,24 +728,65 @@ class ContinuousBatchingScheduler:
         self._telemetry_request(req, "evicted", reason=reason,
                                 generated=len(req.tokens))
 
-    def _ensure_capacity(self, slot: _Slot, upto_tokens: int) -> bool:
-        """Grow `slot`'s page table to hold `upto_tokens`, evicting
-        younger actives when the free list runs dry.  Returns False when
-        even eviction cannot help (the slot itself must yield)."""
-        need_total = self.allocator.pages_for(upto_tokens)
-        while len(slot.pages) < need_total:
-            got = self._alloc_pages(1)
+    def _take_page(self, slot: _Slot, alloc) -> Optional[int]:
+        """One page from `alloc()` for `slot`, evicting the youngest
+        OTHER active while the free list is dry; None when nobody is
+        left to evict (the slot itself must yield)."""
+        while True:
+            got = alloc()
             if got is not None:
-                slot.table[len(slot.pages)] = got[0]
-                slot.pages.extend(got)
-                continue
+                return got[0]
             victims = [s for s in self._slots
                        if s is not None and s is not slot]
             if not victims:
-                return False
+                return None
             victims.sort(key=lambda s: s.admit_seq)
             self._evict(victims[-1], reason="page_pressure")
+
+    def _ensure_capacity(self, slot: _Slot, upto_tokens: int) -> bool:
+        """Grow `slot`'s page table to hold `upto_tokens`, evicting
+        younger actives when the free list runs dry; in the sliding
+        group, the pages from the slot's first live page on.  Returns
+        False when even eviction cannot help (the slot itself must
+        yield)."""
+        need_total = self.allocator.pages_for(upto_tokens)
+        while len(slot.pages) < need_total:
+            page = self._take_page(slot, lambda: self._alloc_pages(1))
+            if page is None:
+                return False
+            slot.table[len(slot.pages)] = page
+            slot.pages.append(page)
+        if self.sliding_allocator is not None:
+            first = window_first_page(slot.ctx, self.sliding_window,
+                                      self.page_size)
+            for pg in range(first, need_total):
+                if pg in slot.wpages:
+                    continue
+                page = self._take_page(
+                    slot, lambda: self.sliding_allocator.alloc(1))
+                if page is None:
+                    return False
+                slot.wpages[pg] = page
+                slot.wtable[pg] = page
         return True
+
+    def _release_behind_window(self, slot: _Slot) -> int:
+        """Give back the slot's sliding-group pages that lie wholly
+        before ``cursor - window``: no query from the cursor on can see
+        a key in them.  Returns how many went."""
+        first = window_first_page(slot.ctx, self.sliding_window,
+                                  self.page_size)
+        return self._drop_sliding(
+            slot, [pg for pg in slot.wpages if pg < first])
+
+    def _drop_sliding(self, slot: _Slot, logical: List[int]) -> int:
+        """Free the sliding-group pages at the logical indices `logical`
+        and null their table entries; returns how many."""
+        if logical:
+            self.sliding_allocator.free([slot.wpages.pop(pg)
+                                         for pg in logical])
+            slot.wtable[logical] = NULL_PAGE
+        return len(logical)
 
     def _cow_guard(self, slot: _Slot, first: int, last: int) -> bool:
         """Copy-on-write before the fused step scatters into token
@@ -774,6 +830,7 @@ class ContinuousBatchingScheduler:
         prefix pages always sit below the cursor), so they go straight
         back to the free list."""
         keep = max(1, self.allocator.pages_for(slot.ctx + 1))
+        self._drop_sliding(slot, [pg for pg in slot.wpages if pg >= keep])
         if len(slot.pages) <= keep:
             return
         extra = slot.pages[keep:]
@@ -866,7 +923,11 @@ class ContinuousBatchingScheduler:
             if batch is None:
                 self._update_gauges()
                 return False
-            C, plan, actives, arrays = batch
+            C, plan, actives, arrays, wtables = batch
+            more = {} if wtables is None else {"sliding_tables": wtables}
+            kv_counts = {
+                "kv_pages_full": sum(len(s.pages) for s in actives),
+                "kv_pages_sliding": sum(len(s.wpages) for s in actives)}
 
         t_plan = time.perf_counter()
         try:
@@ -875,7 +936,7 @@ class ContinuousBatchingScheduler:
             # traffic — slot.ctx has already advanced past tokens that
             # will never land, the hardest failover shape
             fault_point("replica_step")
-            next_tokens, all_tok = self.engine._execute(*arrays, C)
+            next_tokens, all_tok = self.engine._execute(*arrays, C, **more)
         except Exception as exc:
             with self._step_lock:
                 if self._abandoned:
@@ -926,18 +987,39 @@ class ContinuousBatchingScheduler:
                  "tokens_fed": sum(pl["nt"] for pl in plan.values()),
                  "emitted": emitted, "drafted": drafted,
                  "accepted": accepted, "queue_depth": queued,
-                 "h2d_bytes": sum(a.nbytes for a in arrays),
+                 "h2d_bytes": sum(a.nbytes for a in arrays)
+                 + (0 if wtables is None else wtables.nbytes),
+                 **kv_counts, **self._moe_counts(),
                  **{k: now - was for k, was, now in zip(
                      self._COUNTED, before, self._totals())}})
         return True
 
+    def _moe_counts(self) -> dict:
+        """The last step's routing, from the counts that came back with
+        its tokens (`engine.last_moe_counts`: expert layers x held
+        experts): pairs routed to the experts held here, held experts
+        (layer by layer) that got at least one, and the busiest held
+        expert's pairs over the mean.  {} for a model without expert
+        layers."""
+        counts = getattr(self.engine, "last_moe_counts", None)
+        if counts is None:
+            return {}
+        counts = onp.asarray(counts)
+        mean = counts.mean()
+        return {"moe_tokens_routed": int(counts.sum()),
+                "moe_experts_touched": int((counts > 0).sum()),
+                "moe_experts_held": int(counts.size),
+                "moe_load_max_over_mean":
+                    float(counts.max() / mean) if mean > 0 else 0.0}
+
     #: a step's counts taken as differences of running totals
     _COUNTED = ("admitted", "evicted", "expired", "finished",
-                "prefix_hit", "cow_forks")
+                "prefix_hit", "cow_forks", "kv_pages_released")
 
     def _totals(self) -> tuple:
         return (self._n_admitted, self._n_evicted, self._n_expired,
-                self._n_finished, self.prefix_hit_tokens, self.cow_forks)
+                self._n_finished, self.prefix_hit_tokens, self.cow_forks,
+                self.kv_pages_released)
 
     def _plan(self):
         """Build one step's numpy batch over the active slots: the chunk
@@ -996,6 +1078,10 @@ class ContinuousBatchingScheduler:
         # evicting younger actives) are evicted themselves this
         # round.  The COW guard then forks any still-shared page in
         # the write range before the step scatters into it.
+        sliding = self.sliding_allocator is not None
+        if sliding:
+            self.kv_pages_released += sum(
+                self._release_behind_window(s) for s in actives)
         for s in sorted(actives, key=lambda s: s.admit_seq):
             if self._slots[s.slot_idx] is not s:
                 continue      # already evicted by a victim search
@@ -1013,6 +1099,7 @@ class ContinuousBatchingScheduler:
         num_tokens = onp.zeros(B, onp.int32)
         start_pos = onp.zeros(B, onp.int32)
         tables = onp.zeros((B, self.max_pages_per_seq), onp.int32)
+        wtables = onp.zeros_like(tables) if sliding else None
         ctx_lens = onp.zeros(B, onp.int32)
         temps = onp.ones(B, onp.float32)
         greedy = onp.ones(B, bool)
@@ -1029,6 +1116,8 @@ class ContinuousBatchingScheduler:
             num_tokens[i] = nt
             start_pos[i] = s.ctx
             tables[i] = s.table
+            if sliding:
+                wtables[i] = s.wtable
             ctx_lens[i] = s.ctx + nt
             temps[i] = s.req.temperature
             greedy[i] = s.req.greedy
@@ -1038,7 +1127,7 @@ class ContinuousBatchingScheduler:
                        "consume": s.ctx + nt_seq == len(seq)}
             s.ctx += nt
         return C, plan, actives, (tok, num_tokens, start_pos, tables,
-                                  ctx_lens, temps, greedy)
+                                  ctx_lens, temps, greedy), wtables
 
     def _emit_step(self, plan, actives, next_tokens, all_tok,
                    t_plan: float, t_wait: float):
@@ -1147,7 +1236,8 @@ class ContinuousBatchingScheduler:
         rep = {} if self.name is None else {"replica": self.name}
         on_step = ("active", "tokens_fed", "emitted", "admitted",
                    "evicted", "expired", "drafted", "accepted",
-                   "prefix_hit")
+                   "prefix_hit") + tuple(
+                       k for k in counts if k.startswith(("moe_", "kv_")))
         _trace.record_phases(
             _trace.get_tracer("serve"), "serve.step", self.STEP_PHASES,
             stamps,
@@ -1158,7 +1248,9 @@ class ContinuousBatchingScheduler:
             phase_tags={
                 "admit": {k: counts[k]
                           for k in ("queue_depth", "admitted")},
-                "plan": {k: counts[k] for k in ("cow_forks", "evicted")},
+                "plan": {k: counts[k] for k in (
+                    "cow_forks", "evicted", "kv_pages_full",
+                    "kv_pages_sliding", "kv_pages_released")},
                 "launch": {"h2d_bytes": counts["h2d_bytes"]},
                 "emit": {k: counts[k] for k in ("emitted", "finished")}})
 

@@ -349,6 +349,66 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+# the published widths of the benchmark's `afmoe` configuration: 48 query /
+# 8 KV heads of 128, experts 3072 -> 2 x 3072 -> 3072, 16 held, pages of 128
+AFMOE = dict(H=48, Hkv=8, D=128, E=3072, F=3072, held=16, window=4095)
+
+
+@pytest.mark.parametrize("sliding", [False, True], ids=["full", "sliding"])
+@pytest.mark.parametrize("C", [1, 16])
+def test_paged_attention_kernel_lowers_at_afmoe_widths(C, sliding):
+    """Head dim 128, six queries a KV head, a 128-page table; the sliding
+    group's call walks 34 pages from each slot's first live page."""
+    from mxnet_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    from mxnet_tpu.serve.kv_cache import (window_first_page,
+                                          window_walk_pages)
+    H, Hkv, D = (AFMOE[k] for k in ("H", "Hkv", "D"))
+    B, ps, maxp = 32, 128, 128
+    q = jnp.ones((B, H, C, D), jnp.bfloat16)
+    pool = jnp.ones((4, Hkv, 64, ps, D), jnp.bfloat16)
+    pt = jnp.zeros((B, maxp), jnp.int32)
+    ctx = jnp.full((B,), 9000, jnp.int32)
+    walk = window_walk_pages(AFMOE["window"], 16, ps)
+    assert walk == 34
+
+    def f(q, kp, vp, pt, ctx, start):
+        more = dict(window=AFMOE["window"], walk_pages=walk,
+                    first_page=window_first_page(start, AFMOE["window"],
+                                                 ps)) if sliding else {}
+        return ragged_paged_attention(q, kp, vp, pt, ctx, start,
+                                      use_kernel=True, layer=3, **more)
+
+    txt = _lower_for_tpu(f, q, pool, pool, pt, ctx, ctx - C)
+    assert txt.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("n_out", [2 * AFMOE["F"], AFMOE["E"]],
+                         ids=["w13", "w2"])
+def test_grouped_expert_matmul_compiles_for_v5e_at_afmoe_widths(v5e_chip,
+                                                                n_out):
+    """`mx_moe_gmm` with its dynamic grid bound, compiled by Mosaic and
+    XLA:TPU for the described chip: the row buffer of a C = 16 step (512
+    rows x 4 experts a token, every expert's run padded to whole tiles)
+    against 16 stacked expert matrices, 3 MB slabs two deep in VMEM."""
+    from mxnet_tpu.ops.pallas import moe_gmm as G
+    R = G.padded_rows(512 * 4, AFMOE["held"])
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def f(xs, w, rows):
+        return G.grouped_matmul(xs, w, rows, use_kernel=True)
+
+    compiled = jax.jit(f).trace(
+        described((R, AFMOE["E"]), jnp.bfloat16),
+        described((AFMOE["held"], AFMOE["E"], n_out), jnp.bfloat16),
+        described((AFMOE["held"],), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile()
+    txt = compiled.as_text()
+    assert "mx_moe_gmm" in txt and txt.count("custom-call") >= 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
         monkeypatch, v5e_chip):
     """Mosaic and XLA:TPU run at `.compile()`.  A v5e keeps a bf16

@@ -686,6 +686,7 @@ PALLAS_NAMES = {
     "fused_optimizer.py": ["mx_fused_opt_update", "mx_fused_lamb_moments",
                            "mx_fused_lamb_apply", "mx_fused_opt_autotune"],
     "moe_dispatch.py": ["mx_moe_dispatch"],
+    "moe_gmm.py": ["mx_moe_gmm"],
     "paged_attention.py": ["ragged_paged_attention", "paged_kv_write"],
     "quantized_matmul.py": ["mx_quant_matmul"],
     "softmax_xent.py": ["mx_softmax_xent_fwd", "mx_softmax_xent_bwd"],
