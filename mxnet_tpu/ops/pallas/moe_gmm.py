@@ -22,6 +22,8 @@ interpret-mode oracle.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -83,7 +85,11 @@ def _pick_tk(K: int, N: int, itemsize: int) -> int:
     return best
 
 
-def _gmm_pallas(xs, w, group_rows, tile):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gmm_pallas(xs, w, group_rows, *, tile, interpret):
+    """The launch, under a `jax.jit` of its own: the expert layers of a
+    step make the same two calls (W1|W3, W2), so the step traces and
+    lowers two bodies a width, not two a layer (PERF.md, PR 32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -119,7 +125,7 @@ def _gmm_pallas(xs, w, group_rows, tile):
             out_specs=pl.BlockSpec((tile, N), lambda t, k, tg: (t, 0))),
         out_shape=jax.ShapeDtypeStruct((R, N), jnp.float32),
         compiler_params=tpu_compiler_params("arbitrary", "arbitrary"),
-        interpret=interpret_mode(),
+        interpret=interpret,
         name="mx_moe_gmm",
     )(tile_group, xs, w)
 
@@ -142,4 +148,5 @@ def grouped_matmul(xs, w, group_rows, tile: int = TILE_ROWS,
     if not use_kernel:
         return grouped_matmul_reference(xs, w, group_rows)
     note_fused_launch("moe_gmm")
-    return _gmm_pallas(xs.astype(w.dtype), w, group_rows, tile)
+    return _gmm_pallas(xs.astype(w.dtype), w, group_rows, tile=tile,
+                       interpret=interpret_mode())

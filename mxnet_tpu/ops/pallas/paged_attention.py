@@ -8,10 +8,26 @@ values head-major as fixed-size pages `(Hkv, num_pages, page_size, D)` in
 HBM, so one (kv head, page) is a contiguous `(page_size, D)` tile — the
 block Mosaic can DMA (a kv-head axis in the second-minor position cannot
 be squeezed out of a TPU block); each slot's logical context is the
-concatenation of the pages its page table names.  The kernel walks a
-slot's pages sequentially (online softmax, flash style), fetching the
-physical page via scalar-prefetched page-table indices — no
-(B, L_max, ...) contiguous gather is ever materialised on the TPU path.
+concatenation of the pages its page table names.  The kernel's grid is a
+**work list of the live (slot, logical page) pairs** with a traced bound
+(`serve.kv_cache.live_page_items`: for each slot the pages from its first
+query's window to its context's end, slot after slot), so a page no query
+can see costs no grid step; one step takes ALL kv heads of its page and
+walks on through the slot's pages (online softmax, flash style, the state
+of every head in VMEM scratch), fetching the physical page via
+scalar-prefetched page-table indices — no (B, L_max, ...) contiguous
+gather is ever materialised on the TPU path.  The kernel is bound by the
+count of its grid steps, not by bytes or FLOPs: the `(slots, kv heads,
+table pages)` grid this replaced paid 6,144 steps a GPT-2-small layer for
+≈ 100 live pages (PERF.md, PR 32).
+
+Both kernels read the stacked pool of a cache group and pick the layer in
+their index maps from a **prefetched scalar**, under an inner `jax.jit`
+(`_rpa_pallas`, `_kv_write_pallas`): the layers of a group make the same
+call, so a serving step traces each kernel body and lowers it to Mosaic
+once a compiled width, not once a layer.  A warm start compiles nothing,
+but it does trace and lower, and a Python constant in an index map made
+every layer's call a program of its own (PERF.md, PR 32).
 
 The chunk's new K/V rows enter the pool through a second kernel,
 `paged_kv_write`, whose pool operands are aliased to its results: on the
@@ -145,40 +161,39 @@ def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False,
-                     from_first=False):
+def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
     """Build the kernel body with static shape parameters.
 
-    One (slot, kv-head, page) grid step: rows are the GQA fold — row r =
-    (query-head-in-group r // chunk, chunk token r % chunk), so every
-    row's query position is ``start + r % chunk``.  Pages walk
-    sequentially (innermost grid dim) with flash-style online softmax in
-    VMEM scratch.  All elementwise math is f32 (v5e has no bf16 VPU):
-    q/k/v go to the MXU as stored and accumulate in f32.  With
-    `page_in_lanes` the K/V blocks are (D, page_size) tiles (see
+    One grid step is one item of the work list (`live_page_items`): a
+    live (slot, logical page) pair, for ALL kv heads.  A slot's
+    items are consecutive, pages ascending, so each head walks the
+    slot's pages in order with flash-style online softmax in VMEM
+    scratch; the scratch is reset at a slot's first item and the output
+    normalised and stored at its last (both read from the list's
+    neighbours).  Rows are the GQA fold — row r = (query-head-in-group
+    r // chunk, chunk token r % chunk), so every row's query position is
+    ``start + r % chunk``.  All elementwise math is f32 (v5e has no bf16
+    VPU): q/k/v go to the MXU as stored and accumulate in f32.  With
+    `page_in_lanes` the K/V blocks are (D, page_size) tiles a head (see
     `pages_in_lanes`): K^T feeds the score matmul as it lies and V^T is
-    contracted over its lane dim, the form q.K^T has otherwise.  With
-    `from_first` a fourth prefetched scalar a slot gives its first live
-    page and the page axis walks from there (a windowed layer's table
-    holds nothing a query can see before it)."""
+    contracted over its lane dim, the form q.K^T has otherwise."""
     from jax.experimental import pallas as pl
 
     nt_dims = (((1,), (1,)), ((), ()))      # contract both minor dims
 
-    def kernel(pt_ref, ctx_ref, start_ref, *refs):
-        if from_first:
-            first_ref, refs = refs[0], refs[1:]
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        b = pl.program_id(0)
-        step = pl.program_id(2)
-        n_pages = pl.num_programs(2)
-        # the logical page this step reads
-        pi = step + first_ref[b] if from_first else step
+    def kernel(pt_ref, ctx_ref, start_ref, slot_ref, page_ref, lay_ref,
+               q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
+        i = pl.program_id(0)
+        b = slot_ref[i]
+        pi = page_ref[i]            # the logical page this step reads
+        opens = (i == 0) | (slot_ref[jnp.maximum(i - 1, 0)] != b)
+        closes = (i == pl.num_programs(0) - 1) | \
+            (slot_ref[jnp.minimum(i + 1, slot_ref.shape[0] - 1)] != b)
 
-        rows, d = q_ref.shape
-        ps = k_ref.shape[1 if page_in_lanes else 0]
+        n_kv, rows, d = q_ref.shape
+        ps = k_ref.shape[2 if page_in_lanes else 1]
 
-        @pl.when(step == 0)
+        @pl.when(opens)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
             l_scr[...] = jnp.zeros_like(l_scr)
@@ -186,62 +201,53 @@ def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False,
 
         ctx = ctx_ref[b]
         start = start_ref[b]
+        # row r -> query position start + r % chunk; col j -> key
+        # position pi * ps + j.  (Without a GQA fold rows past the chunk
+        # are sublane padding the wrapper slices off, so the modulo is
+        # skipped.)  An idle slot's one item keeps nothing: exact zeros.
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+        qpos = start + (r if rep == 1 else jax.lax.rem(r, chunk))
+        kpos = pi * ps + c
+        keep = (kpos < ctx) & (kpos <= qpos)
+        if window is not None:
+            keep &= kpos >= qpos - window
 
-        def _step():
-            qb = q_ref[...]
-            kb = k_ref[...]
+        for h in range(n_kv):
+            qb = q_ref[h]
+            kb = k_ref[h]
             if page_in_lanes:
                 s = jax.lax.dot(qb, kb, preferred_element_type=jnp.float32)
             else:
                 s = jax.lax.dot_general(
                     qb, kb, nt_dims, preferred_element_type=jnp.float32)
-            s = s * scale
-            # row r -> query position start + r % chunk; col j -> key
-            # position pi * ps + j
-            r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            # (without a GQA fold rows past the chunk are sublane padding
-            # the wrapper slices off, so the modulo is skipped)
-            qpos = start + (r if rep == 1 else jax.lax.rem(r, chunk))
-            kpos = pi * ps + c
-            keep = (kpos < ctx) & (kpos <= qpos)
-            if window is not None:
-                keep &= kpos >= qpos - window
-            s = jnp.where(keep, s, MASK_VALUE)
-            m_prev = m_scr[...]
-            l_prev = l_scr[...]
+            s = jnp.where(keep, s * scale, MASK_VALUE)
+            m_prev = m_scr[h]
+            l_prev = l_scr[h]
             m_cur = jnp.max(s, axis=1)[:, None]
             m_next = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - _lanes(m_next, ps))
             # fully-masked rows: exp(MASK - m) must be exactly 0, not 1
             p = jnp.where(s > 0.5 * MASK_VALUE, p, 0.0)
             alpha = jnp.exp(m_prev - m_next)
-            l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-            m_scr[...] = m_next
-            vb = v_ref[...]
+            l_scr[h] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
+            m_scr[h] = m_next
+            vb = v_ref[h]
             pb = p.astype(vb.dtype)
             if page_in_lanes:
                 pv = jax.lax.dot_general(
                     pb, vb, nt_dims, preferred_element_type=jnp.float32)
             else:
                 pv = jax.lax.dot(pb, vb, preferred_element_type=jnp.float32)
-            acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + pv
+            acc_scr[h] = acc_scr[h] * _lanes(alpha, d) + pv
 
-        # skip pages entirely past the slot's context (the ragged win:
-        # a decode slot with 40 tokens touches 3 pages, not max_pages)
-        live = pi * ps < ctx
-        if window is not None:
-            # nor pages wholly before the first query's window: masked
-            # to exact zeros anyway, so skipping them changes no bit
-            live &= (pi + 1) * ps > start - window
-        pl.when(live)(_step)
-
-        @pl.when(step == n_pages - 1)
+        @pl.when(closes)
         def _store():
-            l = l_scr[...]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_scr[...] / _lanes(l_safe, d)).astype(
-                o_ref.dtype)
+            for h in range(n_kv):
+                l = l_scr[h]
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                o_ref[h] = (acc_scr[h] / _lanes(l_safe, d)).astype(
+                    o_ref.dtype)
 
     return kernel
 
@@ -256,21 +262,27 @@ def _lanes(x, n):
     return jnp.tile(x, (1, n // LANES))
 
 
-def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
-                window, scale, page_in_lanes=False, first_page=None,
-                walk_pages=None):
+@functools.partial(jax.jit, static_argnames=(
+    "window", "scale", "page_in_lanes", "interpret"))
+def _rpa_pallas(q, kpool, vpool, lay, page_tables, ctx_lens, start_pos,
+                item_slot, item_page, n_items, *, window, scale,
+                page_in_lanes, interpret):
     """Launch the Pallas kernel over the stacked ``(n_layers, Hkv, pages,
-    ps, D)`` pools (shapes pre-validated by the wrapper).  The layer is
+    ps, D)`` pools (shapes pre-validated by the wrapper).  The grid is the
+    work list, its bound the traced count of live items: a page past a
+    slot's context or before its window costs no step.  The layer is
     picked in the K/V index map, so XLA never materialises a per-layer
-    slice of the pool to feed the custom call.  `first_page` (B,) with
-    the static `walk_pages`: the page axis of the grid is `walk_pages`
-    long and slot b walks its table from ``first_page[b]``."""
+    slice of the pool to feed the custom call — and it is picked from a
+    prefetched scalar (`lay`, int32 ``(1,)``), not from a Python
+    constant, under a `jax.jit` of its own: the layers of a cache group
+    make the same call, so a step traces this body and lowers it to
+    Mosaic once a width, not once a layer (a warm set-up is Python
+    tracing and lowering: PERF.md, PR 32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, C, D = q.shape
     _, Hkv, _, ps, _ = kpool.shape
-    maxp = page_tables.shape[1]
     rep = H // Hkv
     rows = rep * C
 
@@ -283,50 +295,48 @@ def _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens, start_pos,
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     rows_p = rows + pad
 
-    from_first = first_page is not None
+    def q_map(i, pt, ctx, st, slot, page, lay):
+        return (slot[i], 0, 0, 0)
 
-    def q_map(b, h, pi, *scalars):
-        return (b, h, 0, 0)
+    def kv_map(i, pt, ctx, st, slot, page, lay):
+        return (lay[0], 0, pt[slot[i], page[i]], 0, 0)
 
-    def kv_map(b, h, pi, pt, ctx, st, *first):
-        if from_first:
-            pi = jnp.minimum(pi + first[0][b], maxp - 1)
-        return (layer, h, pt[b, pi], 0, 0)
-
-    kv_block = (None, None, None, ps, D)
+    kv_block = (None, Hkv, None, ps, D)
     if page_in_lanes:
         kpool, vpool = _lane_view(kpool), _lane_view(vpool)
-        kv_block = (None, None, None, D, ps)
+        kv_block = (None, Hkv, None, D, ps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if from_first else 3,
-        grid=(B, Hkv, walk_pages if from_first else maxp),
+        num_scalar_prefetch=6,
+        grid=(n_items,),
         in_specs=[
-            pl.BlockSpec((None, None, rows_p, D), q_map),
+            pl.BlockSpec((None, Hkv, rows_p, D), q_map),
             pl.BlockSpec(kv_block, kv_map),
             pl.BlockSpec(kv_block, kv_map),
         ],
-        out_specs=pl.BlockSpec((None, None, rows_p, D), q_map),
+        out_specs=pl.BlockSpec((None, Hkv, rows_p, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((rows_p, LANES), jnp.float32),
-            pltpu.VMEM((rows_p, LANES), jnp.float32),
-            pltpu.VMEM((rows_p, D), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, D), jnp.float32),
         ],
     )
-    scalars = (page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-               start_pos.astype(jnp.int32))
-    if from_first:
-        scalars += (first_page.astype(jnp.int32),)
     out = pl.pallas_call(
-        _make_rpa_kernel(scale, C, rep, window, page_in_lanes, from_first),
+        _make_rpa_kernel(scale, C, rep, window, page_in_lanes),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
+        compiler_params=tpu_compiler_params("arbitrary"),
+        interpret=interpret,
         name="ragged_paged_attention",
-    )(*scalars, qf, kpool, vpool)
+    )(page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      start_pos.astype(jnp.int32), item_slot, item_page, lay,
+      qf, kpool, vpool)
     return out[:, :, :rows].reshape(B, H, C, D)
+
+
+def _layer_scalar(layer):
+    """The layer index as the int32 ``(1,)`` array the kernels prefetch."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def kernel_tileable(page_size: int, head_dim: int) -> bool:
@@ -396,8 +406,8 @@ def _make_kv_write_kernel(tile, page_in_lanes):
     store: bf16 rows share a sublane) and the tile is written back."""
     from jax.experimental import pallas as pl
 
-    def kernel(pt_ref, start_ref, nt_ref, kn_ref, vn_ref, kin_ref, vin_ref,
-               ko_ref, vo_ref):
+    def kernel(pt_ref, start_ref, nt_ref, lay_ref, kn_ref, vn_ref, kin_ref,
+               vin_ref, ko_ref, vo_ref):
         b = pl.program_id(0)
         start = start_ref[b]
         nt = nt_ref[b]
@@ -466,9 +476,23 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
     min((start_pos[b] + c) // page_size, max_pages - 1)]``, cast to the
     pool's dtype; rows past `num_tokens` are dropped and an idle slot
     (``num_tokens == 0``) reads and rewrites a tile of `null_page` alone.
-    Every other byte of the pool is left as it was.  Page ids, starts and
-    counts arrive by scalar prefetch and the layer is picked in the index
-    map, as in `_rpa_pallas`; `page_in_lanes` as in `pages_in_lanes`."""
+    Every other byte of the pool is left as it was.  Page ids, starts,
+    counts and the layer arrive by scalar prefetch and the layer is
+    picked in the index map, as in `_rpa_pallas`, under the same kind of
+    inner `jax.jit`; `page_in_lanes` as in `pages_in_lanes`."""
+    return _kv_write_pallas(
+        kpool, vpool, k_new, v_new, _layer_scalar(layer), page_tables,
+        start_pos, num_tokens, null_page=int(null_page),
+        page_in_lanes=bool(page_in_lanes), interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "null_page", "page_in_lanes", "interpret"))
+def _kv_write_pallas(kpool, vpool, k_new, v_new, lay, page_tables,
+                     start_pos, num_tokens, *, null_page, page_in_lanes,
+                     interpret):
+    """`paged_kv_write`'s launch: `lay` is the layer as an int32 ``(1,)``
+    array, so that every layer of a cache group shares this trace."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -481,17 +505,17 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
     # fewer repeat its last block index and are skipped in the kernel
     n_tiles = (C + tile - 2) // tile + 1
 
-    def new_map(b, j, pt, st, nt):
+    def new_map(b, j, pt, st, nt, lay):
         return (b, 0, 0, 0)
 
-    def pool_map(b, j, pt, st, nt):
+    def pool_map(b, j, pt, st, nt, lay):
         g = jnp.minimum(st[b] // tile + j,
                         (st[b] + jnp.maximum(nt[b], 1) - 1) // tile)
         page = pt[b, jnp.minimum(g * tile // ps, maxp - 1)]
         page = jnp.where(nt[b] > 0, page, null_page)
         in_page = g % (ps // tile)
-        return ((layer, 0, page, 0, in_page) if page_in_lanes
-                else (layer, 0, page, in_page, 0))
+        return ((lay[0], 0, page, 0, in_page) if page_in_lanes
+                else (lay[0], 0, page, in_page, 0))
 
     if page_in_lanes:
         # the pool's dtype here already (the one-hot product is exact),
@@ -514,19 +538,19 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
     kpool, vpool = pl.pallas_call(
         _make_kv_write_kernel(tile, page_in_lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, n_tiles),
             in_specs=[new_spec, new_spec, pool_spec, pool_spec],
             out_specs=[pool_spec, pool_spec]),
         out_shape=[jax.ShapeDtypeStruct(kpool.shape, kpool.dtype),
                    jax.ShapeDtypeStruct(vpool.shape, vpool.dtype)],
-        # operands count the three prefetched scalars: 5, 6 are the pools
-        input_output_aliases={5: 0, 6: 1},
+        # operands count the four prefetched scalars: 6, 7 are the pools
+        input_output_aliases={6: 0, 7: 1},
         compiler_params=tpu_compiler_params("arbitrary", "arbitrary"),
-        interpret=interpret_mode(),
+        interpret=interpret,
         name="paged_kv_write",
     )(page_tables.astype(jnp.int32), start_pos.astype(jnp.int32),
-      num_tokens.astype(jnp.int32), k_new, v_new, kpool, vpool)
+      num_tokens.astype(jnp.int32), lay, k_new, v_new, kpool, vpool)
     if page_in_lanes:
         kpool, vpool = _lane_view(kpool), _lane_view(vpool)
     return kpool, vpool
@@ -535,8 +559,7 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
 def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                            start_pos, window=None, scale=None,
                            k_scales=None, v_scales=None, use_kernel=None,
-                           layer=None, page_in_lanes=False,
-                           first_page=None, walk_pages=None):
+                           layer=None, page_in_lanes=False, work_list=None):
     """Mixed prefill/decode attention over a paged KV pool — one launch.
 
     q: (B, H, C, D) chunk queries (C = 1 for a pure-decode step);
@@ -549,12 +572,14 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
     slot's first chunk token.  Rows past a slot's real token count
     produce causally-valid garbage the caller must ignore.
 
-    `window`: how many EARLIER keys a query sees besides itself.  With
-    `first_page` (B,) and the static `walk_pages`, the kernel walks only
-    pages ``first_page[b] .. first_page[b] + walk_pages - 1`` of slot b's
-    table: the caller vouches that no query of the chunk sees a key
-    outside them (a windowed layer whose older pages went back to the
-    allocator; their table entries may name any page).
+    `window`: how many EARLIER keys a query sees besides itself.  The
+    kernel visits only the pages some query of a slot's chunk can see,
+    from the first query's window to the context's end (a windowed
+    layer's older pages may have gone back to the allocator; their table
+    entries may name any page).  `work_list`: those (slot, page) pairs
+    as `serve.kv_cache.live_page_items` lists them, for a caller that
+    makes several calls over the same contexts and window (the layers of
+    one cache group); built here, the table's width a slot, if not given.
 
     fp pools run the Pallas kernel wherever the package's ``MXTPU_PALLAS``
     policy makes kernels active (a TPU backend, or ``kernel`` mode); a
@@ -584,11 +609,15 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                 f"head_dim={D}: the page size must be a multiple of 8 and "
                 f"both must be <= {LANES} or a multiple of {LANES} "
                 "(ServeConfig.page_size / MXTPU_SERVE_PAGE_SIZE)")
-        return _rpa_pallas(q, kpool, vpool, layer, page_tables, ctx_lens,
-                           start_pos, window,
-                           scale if scale is not None
-                           else 1.0 / math.sqrt(D), page_in_lanes,
-                           first_page, walk_pages)
+        if work_list is None:
+            from ...serve.kv_cache import live_page_items
+            work_list = live_page_items(ctx_lens, start_pos, window, ps,
+                                        page_tables.shape[1])
+        return _rpa_pallas(
+            q, kpool, vpool, _layer_scalar(layer), page_tables, ctx_lens,
+            start_pos, *work_list, window=window,
+            scale=float(scale) if scale is not None else 1.0 / math.sqrt(D),
+            page_in_lanes=bool(page_in_lanes), interpret=interpret_mode())
     return paged_attention_reference(
         q, kpool[layer], vpool[layer], page_tables, ctx_lens, start_pos,
         window=window, scale=scale,
@@ -598,7 +627,7 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
 
 # ---------------------------------------------------------------------------
 # autotune registration: the launch itself has no free block parameter
-# (pages walk one at a time), so the tunable knob is the POOL's page
+# (a grid step is one page, all its kv heads), so the knob is the POOL's page
 # size — `tune("paged_attention", (slots, heads, kv_heads, head_dim,
 # ctx))` times a serving-shaped decode step per candidate and
 # `serve.ServeConfig` picks the persisted winner up when
@@ -624,11 +653,12 @@ def _at_roofline(config, shapes, dtype):
     b, h, hkv, d, ctx = (list(shapes) + [8, 8, 8, 64, 512])[:5]
     ps = config.page_size
     pages = max(1, -(-ctx // ps))
-    # each slot streams ceil(ctx/ps) pages of K and V; bigger pages
-    # waste tail bandwidth but cost fewer grid steps
+    # each slot streams ceil(ctx/ps) pages of K and V, all kv heads of a
+    # page one grid step; bigger pages waste tail bandwidth but cost
+    # fewer steps
     return {"flops": 4.0 * b * h * ctx * d,
             "bytes": b * hkv * pages * ps * d * 2.0 * 4,
-            "steps": float(b * hkv * pages)}
+            "steps": float(b * pages)}
 
 
 def _at_build(config, shapes, dtype):

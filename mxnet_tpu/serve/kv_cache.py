@@ -28,10 +28,12 @@ group (arrays ``k`` / ``v``) keeps a sequence's whole context, as above.
 The ``sliding`` group (arrays ``k_sliding`` / ``v_sliding``, with a page
 table and an allocator of its own) holds the layers whose queries see a
 window only: a page that lies wholly before ``cursor - window`` goes back
-to its allocator (`window_first_page`), its table entry becomes the null
-page, and the attention kernel is told the slot's first live page and
-walks `window_walk_pages` pages from there, never the whole table.  A
-model of one kind of layer (GPT-2) has the ``full`` group alone.
+to its allocator (`window_first_page`), and its table entry becomes the
+null page.  A model of one kind of layer (GPT-2) has the ``full`` group
+alone.  In either group the attention kernel visits only the (slot, page)
+pairs some query of the step can see (`live_page_range`): one work list a
+group and step (`live_page_items`), of at most `window_walk_pages` pages a
+slot in the sliding group and the table's width in the full one.
 
 ``kv_dtype="int8"`` stores the pool quantized (symmetric per-token-per-head
 int8 via `contrib/quantization.quantize_kv`) at ~4x less HBM per token;
@@ -63,7 +65,8 @@ import jax.numpy as jnp
 from ..base import MXNetError
 
 __all__ = ["PageAllocator", "PrefixIndex", "KVPools", "make_paged_kv_fn",
-           "NULL_PAGE", "SLIDING", "window_first_page", "window_walk_pages"]
+           "NULL_PAGE", "SLIDING", "window_first_page", "window_walk_pages",
+           "live_page_range", "live_page_items"]
 
 NULL_PAGE = 0
 #: suffix of the sliding group's pool arrays (``k_sliding``/``v_sliding``)
@@ -74,8 +77,8 @@ def window_first_page(cursor, window: int, page_size: int):
     """First logical page a query at position >= `cursor` can still see
     in a layer whose queries see `window` earlier keys: every page before
     it lies wholly before ``cursor - window``.  Works on ints and on
-    arrays (the host's release rule and the kernel's first live page are
-    this one expression)."""
+    arrays (the host's release rule and a slot's first page in the
+    kernel's work list are this one expression)."""
     return (cursor - window) // page_size * (cursor > window)
 
 
@@ -85,6 +88,53 @@ def window_walk_pages(window: int, chunk: int, page_size: int) -> int:
     wherever `start` falls (``window / page + 2`` for a 4096-key window,
     pages of 128 and chunks of 16)."""
     return -(-(window + chunk) // page_size) + 1
+
+
+def live_page_range(ctx, start, window: Optional[int], page_size: int,
+                    walk: int):
+    """``(first, count)``: the closed run of logical pages some query of
+    a slot's chunk can see.  `ctx` is the slot's context length with this
+    chunk's tokens in, `start` the position of the chunk's first query,
+    `window` the layer's (None: the whole context).  The run goes from
+    `window_first_page` of the first query (0 without a window) to the
+    page that holds key ``ctx - 1``; it is never empty (an idle slot,
+    ``ctx == 0``, gets its first page, which the kernel masks whole, so
+    that every slot's output is written) and never longer than `walk`,
+    the static bound its caller sized the work list for.  Works on ints
+    and on arrays: the paged-attention kernel's work list
+    (`live_page_items`) and the scheduler's ``attn_items_*`` counters
+    are this one expression."""
+    first = 0 * start if window is None \
+        else window_first_page(start, window, page_size)
+    count = (ctx + page_size - 1) // page_size - first
+    count = count + (1 - count) * (count < 1)
+    return first, count - (count - walk) * (count > walk)
+
+
+def live_page_items(ctx_lens, start_pos, window: Optional[int],
+                    page_size: int, walk: int):
+    """The paged-attention kernel's work list for one cache group:
+    ``(item_slot, item_page, n_items)``, the live (slot, logical page)
+    pairs of `live_page_range` flattened slot by slot, pages ascending.
+    The two int32 arrays have the static length ``slots x walk``; the
+    traced `n_items` says how many are real (at least one a slot), and
+    the entries past it repeat the last real one (the kernel's grid ends
+    at `n_items`: they are never visited).  Device arrays in, device
+    arrays out: built inside the jitted step, once a group."""
+    first, count = live_page_range(ctx_lens, start_pos, window, page_size,
+                                   walk)
+    slots = ctx_lens.shape[0]
+    ends = jnp.cumsum(count)
+    n_items = ends[-1]
+    i = jnp.minimum(jnp.arange(slots * walk), n_items - 1)
+    # dense (items, slots) compares, not a search and two gathers: those
+    # cost a GPT-2 decode step 0.2 ms of its 3.6 (PR 31's chip run)
+    before = i[:, None] >= ends[None, :]      # slots that end before item i
+    slot = before.sum(1)
+    own = slot[:, None] == jnp.arange(slots)[None, :]
+    page = i - (before * count[None, :]).sum(1) + (own * first[None, :]).sum(1)
+    return (slot.astype(jnp.int32), page.astype(jnp.int32),
+            n_items.astype(jnp.int32))
 
 
 class PageAllocator:
@@ -552,11 +602,18 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     group at its own index under the one `window`, the GPT block).  A
     ``"sliding"`` layer writes and reads ``k_sliding``/``v_sliding``
     through `sliding_tables` (same logical indexing; released pages are
-    the null page) and attends from each slot's first live page over
-    `sliding_walk` pages (`window_first_page`, `window_walk_pages`).
+    the null page).  On the kernel route the attention walks a work list
+    of live (slot, page) pairs (`live_page_items`), built here once a
+    group and shared by its layers: at most `sliding_walk` pages a slot
+    in the sliding group (`window_walk_pages`), the table's width in the
+    full one.
     """
     from ..ops.pallas.paged_attention import (
         paged_kernel_route, paged_kv_write, ragged_paged_attention)
+
+    kernel = paged_kernel_route(quantized)
+    # one work list a cache group (and window): its layers share both
+    work_lists = {}
 
     def kv_fn(li, q, k_new, v_new):
         group, gi, win = ("full", li, window) if layer_plan is None \
@@ -565,7 +622,7 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
         kn, vn = ("k" + SLIDING, "v" + SLIDING) if sliding else ("k", "v")
         tables = sliding_tables if sliding else page_tables
         with jax.named_scope("mx.serve.pool_write"):
-            if paged_kernel_route(quantized):
+            if kernel:
                 pools[kn], pools[vn] = paged_kv_write(
                     pools[kn], pools[vn], k_new, v_new, gi, tables,
                     start_pos, num_tokens, null_page=NULL_PAGE,
@@ -575,17 +632,18 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
                                  start_pos, num_tokens, page_size,
                                  quantized, names=(kn, vn))
         with jax.named_scope("mx.serve.paged_attn"):
-            walk = {}
-            if sliding:
-                walk = dict(
-                    first_page=window_first_page(start_pos, win, page_size),
-                    walk_pages=min(sliding_walk, tables.shape[1]))
+            if kernel and (group, win) not in work_lists:
+                work_lists[group, win] = live_page_items(
+                    ctx_lens, start_pos, win, page_size,
+                    min(sliding_walk, tables.shape[1]) if sliding
+                    else tables.shape[1])
             return ragged_paged_attention(
                 q, pools[kn], pools[vn], tables, ctx_lens,
                 start_pos, window=win, layer=gi,
                 page_in_lanes=page_in_lanes,
                 k_scales=pools["k_scale"] if quantized else None,
-                v_scales=pools["v_scale"] if quantized else None, **walk)
+                v_scales=pools["v_scale"] if quantized else None,
+                work_list=work_lists.get((group, win)))
 
     return kv_fn
 

@@ -52,7 +52,7 @@ from .. import telemetry as _tele
 from .. import tracing as _trace
 from . import qos as _qos
 from . import traffic as _traffic
-from .kv_cache import NULL_PAGE, window_first_page
+from .kv_cache import NULL_PAGE, live_page_range, window_first_page
 
 __all__ = ["ServeRequest", "ContinuousBatchingScheduler",
            "terminate_request", "finish_request", "deliver_token"]
@@ -928,6 +928,8 @@ class ContinuousBatchingScheduler:
             kv_counts = {
                 "kv_pages_full": sum(len(s.pages) for s in actives),
                 "kv_pages_sliding": sum(len(s.wpages) for s in actives)}
+            if _trace.capturing():
+                kv_counts.update(self._attn_items(arrays[2], arrays[4]))
 
         t_plan = time.perf_counter()
         try:
@@ -993,6 +995,24 @@ class ContinuousBatchingScheduler:
                  **{k: now - was for k, was, now in zip(
                      self._COUNTED, before, self._totals())}})
         return True
+
+    def _attn_items(self, start_pos, ctx_lens) -> dict:
+        """Grid steps one layer's paged-attention call takes this step,
+        a cache group: the live (slot, page) pairs of `live_page_range`,
+        the expression the kernel's work list is built from, over the
+        plan's own starts and contexts (idle slots count their one
+        item).  ``attn_items_table``, slots x table width, is what a
+        walk of the whole table would take: the ratio is the live
+        share."""
+        items = {"attn_items_full": 0, "attn_items_sliding": 0,
+                 "attn_items_table": self.max_slots * self.max_pages_per_seq}
+        for group, window in {(g, w) for g, _, w
+                              in self.engine.spec.cache_plan()}:
+            walk = self.engine.sliding_walk if group == "sliding" \
+                else self.max_pages_per_seq
+            items["attn_items_" + group] = int(live_page_range(
+                ctx_lens, start_pos, window, self.page_size, walk)[1].sum())
+        return items
 
     def _moe_counts(self) -> dict:
         """The last step's routing, from the counts that came back with
@@ -1237,7 +1257,8 @@ class ContinuousBatchingScheduler:
         on_step = ("active", "tokens_fed", "emitted", "admitted",
                    "evicted", "expired", "drafted", "accepted",
                    "prefix_hit") + tuple(
-                       k for k in counts if k.startswith(("moe_", "kv_")))
+                       k for k in counts
+                       if k.startswith(("moe_", "kv_", "attn_")))
         _trace.record_phases(
             _trace.get_tracer("serve"), "serve.step", self.STEP_PHASES,
             stamps,

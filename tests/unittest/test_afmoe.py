@@ -344,14 +344,16 @@ def test_walk_pages_at_the_published_sizes():
                                          (23, 16), (40, 1)])
 def test_kernel_with_first_live_page_matches_dense_attention(monkeypatch,
                                                              start, chunk):
-    """`ragged_paged_attention` walking `walk_pages` pages from each slot's
-    first live page (the pages before it point at the null page) against
-    `_dense_attend(window=)` over the contiguous context: queries whose
-    window starts exactly on, just before and just after a page edge."""
+    """`ragged_paged_attention` walking a work list of `window_walk_pages`
+    pages a slot, from the slot's first live page (the pages before it
+    point at the null page), against `_dense_attend(window=)` over the
+    contiguous context: queries whose window starts exactly on, just
+    before and just after a page edge."""
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     from mxnet_tpu.ops.pallas.paged_attention import (
         _dense_attend, ragged_paged_attention)
-    from mxnet_tpu.serve.kv_cache import (window_first_page,
+    from mxnet_tpu.serve.kv_cache import (live_page_items,
+                                          window_first_page,
                                           window_walk_pages)
     ps, d, hkv, h, win = 8, 16, 2, 4, 7
     total = start + chunk
@@ -370,15 +372,41 @@ def test_kernel_with_first_live_page_matches_dense_attention(monkeypatch,
     first = int(window_first_page(start, win, ps))
     table = jnp.arange(1, maxp + 1, dtype=jnp.int32).at[:first].set(0)[None]
     starts = jnp.asarray([start], jnp.int32)
+    ctx = jnp.asarray([total], jnp.int32)
+    work_list = live_page_items(ctx, starts, win, ps,
+                                window_walk_pages(win, 16, ps))
+    assert int(work_list[1][0]) == first
     out = ragged_paged_attention(
-        q, kpool, vpool, table, jnp.asarray([total], jnp.int32), starts,
-        window=win, layer=0, use_kernel=True,
-        first_page=jnp.asarray([first], jnp.int32),
-        walk_pages=window_walk_pages(win, 16, ps))
+        q, kpool, vpool, table, ctx, starts, window=win, layer=0,
+        use_kernel=True, work_list=work_list)
     qpos = starts[:, None] + jnp.arange(chunk)[None]
-    want = _dense_attend(q, kc, vc, qpos,
-                         ctx_len=jnp.asarray([total], jnp.int32), window=win)
+    want = _dense_attend(q, kc, vc, qpos, ctx_len=ctx, window=win)
     assert onp.abs(onp.asarray(out - want)).max() < 1e-5
+
+
+def test_engine_kernel_route_streams_equal_reference_route(built,
+                                                           monkeypatch):
+    """Greedy streams with both cache groups on the kernel route
+    (interpret mode: one work list a group and step, the sliding one of
+    `sliding_walk` pages a slot over a table whose released entries are
+    the null page) equal the reference route's: prompts past the window,
+    decoded across page edges, a slot idle at the end."""
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    model, _ = built
+    rng = onp.random.default_rng(11)
+    prompts = [rng.integers(0, 96, n).tolist() for n in (27, 6)]
+
+    def engine():       # the kernels tile pages of 8, not the file's 4
+        return InferenceEngine(model, ServeConfig(
+            max_slots=3, page_size=8, prefill_chunk=5, max_len=96))
+
+    want = _drive(engine(), prompts, new=7)
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    eng = engine()
+    assert eng.sliding_walk == 3 < eng.max_pages_per_seq
+    assert _drive(eng, prompts, new=7) == want
+    assert eng.scheduler.kv_pages_released > 0
 
 
 @pytest.mark.parametrize("rows", [[5, 0, 33, 2], [0, 0, 0, 9], [64, 64, 1, 1]])
@@ -471,6 +499,46 @@ def test_step_counts_carry_routing_and_cache_group_counters(built):
     assert 0 <= slow["moe_experts_touched"] <= 16
     assert eng.last_moe_counts.shape == (4, 4)
     assert eng.stats()["kv_pages_released"] > 0
+
+
+def test_attn_items_count_each_groups_live_pages_while_capturing(built):
+    """Two cache groups, two counters, each the `n_items` of the work
+    list the kernel would walk: the full group's items grow with the
+    context, the sliding group's stay within what a window and a chunk
+    can span (`sliding_walk` a slot)."""
+    from mxnet_tpu import tracing
+    from mxnet_tpu.serve.kv_cache import live_page_items
+    model, _ = built
+    eng = _engine(model, 4, slots=2)
+    seen = []
+    plan = eng.scheduler._plan
+
+    def spy():
+        out = plan()
+        if out is not None:
+            _, _, start, _, ctx = out[3][:5]
+            # the kernel's own list: its traced bound is the grid
+            seen.append(tuple(
+                int(live_page_items(jnp.asarray(ctx), jnp.asarray(start),
+                                    w, 4, walk)[2])
+                for w, walk in ((None, eng.max_pages_per_seq),
+                                (7, eng.sliding_walk))))
+        return out
+    eng.scheduler._plan = spy
+    tracing.enable()
+    try:
+        _drive(eng, [onp.random.default_rng(3).integers(0, 96, 30).tolist()],
+               new=6)
+        steps = [s.tags for s in tracing.get_tracer("serve").spans()
+                 if s.name == "serve.step"]
+    finally:
+        tracing.disable()
+    assert [(t["attn_items_full"], t["attn_items_sliding"])
+            for t in steps] == seen
+    assert max(f for f, _ in seen) == 1 + -(-35 // 4)     # an idle slot's 1
+    assert max(w for _, w in seen) <= 1 + eng.sliding_walk
+    assert all(t["attn_items_table"] == 2 * eng.max_pages_per_seq
+               for t in steps)
 
 
 @pytest.mark.parametrize("scope", [

@@ -643,6 +643,239 @@ def test_paged_kv_write_equals_the_scatter_bit_for_bit(C, dtype,
         assert (g[li] != o[li]).any()
 
 
+# ---------------------------------------------------------------------------
+# paged attention: the work list of live (slot, page) pairs, and the kernel
+# that walks it, against the gather reference
+# ---------------------------------------------------------------------------
+
+# context lengths of a ragged batch over pages of 8 and a table of 6: idle
+# slots first, last and between live ones; a context that ends on a page
+# boundary; one page; the whole table; a chunk shorter than the step's width
+_RAGGED_CTX = [0, 21, 0, 24, 5, 48, 35, 0]
+_RAGGED_NT = [0, 16, 0, 16, 3, 16, 5, 0]
+
+
+def _ragged_batch(C):
+    ctx = onp.asarray(_RAGGED_CTX)
+    nt = onp.minimum(onp.asarray(_RAGGED_NT), C)
+    return ctx, ctx - nt, nt
+
+
+def _visible_pages(ctx, start, nt, window, ps):
+    """Brute force: the pages that hold a key some real query of the
+    chunk sees, ascending."""
+    seen = set()
+    for qpos in range(start, start + nt):
+        for kpos in range(ctx):
+            if kpos <= qpos and (window is None or kpos >= qpos - window):
+                seen.add(kpos // ps)
+    return sorted(seen)
+
+
+@pytest.mark.parametrize("window", [None, 0, 5, 11, 4095],
+                         ids=lambda w: f"window_{w}")
+@pytest.mark.parametrize("C", [1, 16])
+def test_live_page_items_list_every_visible_page_once_in_order(C, window):
+    """`live_page_items` against the enumeration: a slot's items are the
+    pages some query of its chunk sees, each once, ascending, slot after
+    slot; an idle slot has the one item that keeps its output written;
+    the tail past `n_items` repeats the last item; ints and arrays give
+    the same range."""
+    from mxnet_tpu.serve.kv_cache import (live_page_items, live_page_range,
+                                          window_first_page,
+                                          window_walk_pages)
+    ps, maxp = 8, 6
+    ctx, start, nt = _ragged_batch(C)
+    walk = maxp if window is None \
+        else min(maxp, window_walk_pages(window, C, ps))
+    slot, page, n = (onp.asarray(x) for x in live_page_items(
+        jnp.asarray(ctx), jnp.asarray(start), window, ps, walk))
+    assert slot.shape == page.shape == (len(ctx) * walk,)
+    assert slot.dtype == page.dtype == onp.int32
+    want = []
+    for b in range(len(ctx)):
+        pages = _visible_pages(ctx[b], start[b], nt[b], window, ps)
+        assert len(pages) <= walk
+        if not pages:       # idle: its first page, masked whole
+            pages = [0 if window is None
+                     else int(window_first_page(int(start[b]), window, ps))]
+        want += [(b, pg) for pg in pages]
+        first, count = live_page_range(int(ctx[b]), int(start[b]), window,
+                                       ps, walk)
+        assert (first, count) == (pages[0], len(pages))
+    assert n == len(want) >= len(ctx)
+    assert list(zip(slot[:n], page[:n])) == want
+    assert (slot[n:] == want[-1][0]).all() and (page[n:] == want[-1][1]).all()
+    first, count = live_page_range(ctx, start, window, ps, walk)
+    assert isinstance(count, onp.ndarray) and count.sum() == n
+
+
+def test_live_page_range_never_passes_the_walk_it_was_sized_for():
+    """A context the caller's static bound cannot hold is cut to the
+    bound: the work list never runs past its own length."""
+    from mxnet_tpu.serve.kv_cache import live_page_items, live_page_range
+    assert live_page_range(100, 99, None, 8, 4) == (0, 4)
+    slot, page, n = live_page_items(jnp.asarray([100, 3]),
+                                    jnp.asarray([99, 0]), None, 8, 4)
+    assert int(n) == 5 and slot.tolist() == [0, 0, 0, 0, 1, 1, 1, 1]
+
+
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+@pytest.mark.parametrize("window", [None, 11], ids=["full", "sliding"])
+@pytest.mark.parametrize("rep", [1, 6], ids=["mha", "gqa6"])
+@pytest.mark.parametrize("C", [1, 16])
+def test_paged_attention_kernel_walks_live_pages_like_the_reference(
+        C, rep, window, page_in_lanes):
+    """`ragged_paged_attention` (interpret mode: the exact kernel code)
+    over the ragged batch, against the gather reference: C = 1 and C = 16
+    with ``num_tokens < C``, six query heads a kv head, both page
+    orientations, ``layer`` != 0 of a stacked pool.  The sliding case
+    gets the table as the scheduler hands it over (every page before a
+    slot's first live one is the null page) and a work list of
+    `window_walk_pages` pages a slot; the full case builds its own over
+    the table's width.  Idle slots come out as exact zeros."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    from mxnet_tpu.serve.kv_cache import (NULL_PAGE, live_page_items,
+                                          window_first_page,
+                                          window_walk_pages)
+    rng = onp.random.RandomState(7 * C + rep)
+    ps, maxp, Hkv, D, L, li = 8, 6, 2, 16, 2, 1
+    ctx, start, nt = _ragged_batch(C)
+    B, H = len(ctx), Hkv * rep
+    npages = B * maxp + 1
+    q = jnp.asarray(rng.standard_normal((B, H, C, D)), jnp.float32)
+    kp, vp = (jnp.asarray(rng.standard_normal((L, Hkv, npages, ps, D)),
+                          jnp.float32) for _ in "kv")
+    table = onp.asarray(1 + rng.permutation(B * maxp).reshape(B, maxp),
+                        onp.int32)
+    ctx_d, start_d = jnp.asarray(ctx, jnp.int32), jnp.asarray(start,
+                                                              jnp.int32)
+    want = pa.paged_attention_reference(
+        q, kp[li], vp[li], jnp.asarray(table), ctx_d, start_d, window=window)
+    work_list = None
+    if window is not None:
+        for b in range(B):
+            first = int(window_first_page(int(start[b]), window, ps))
+            table[b, :first] = NULL_PAGE
+        assert (table[5, :2] == NULL_PAGE).all()    # first live page > 0
+        work_list = live_page_items(ctx_d, start_d, window, ps,
+                                    window_walk_pages(window, 16, ps))
+    got = jax.jit(lambda q, k, v: pa.ragged_paged_attention(
+        q, k, v, jnp.asarray(table), ctx_d, start_d, window=window,
+        use_kernel=True, layer=li, page_in_lanes=page_in_lanes,
+        work_list=work_list))(q, kp, vp)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    for b in range(B):
+        if nt[b] == 0:
+            assert not onp.asarray(got[b]).any()
+        onp.testing.assert_allclose(got[b, :, :nt[b]], want[b, :, :nt[b]],
+                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 3, 40, 4095],
+                         ids=lambda w: f"window_{w}")
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_live_page_items_match_an_enumeration_of_random_batches(seed, window):
+    """Random contexts, starts and chunk lengths over a table of 9 pages
+    of 8, with idle slots and one slot at the table's full width: the
+    list is the NumPy enumeration of every slot's visible pages,
+    ``n_items`` the sum of the counts, the tail a repeat of the last
+    item, and no slot passes the walk the list was sized for."""
+    from mxnet_tpu.serve.kv_cache import (live_page_items, live_page_range,
+                                          window_first_page,
+                                          window_walk_pages)
+    rng = onp.random.default_rng([seed, 0 if window is None else window + 1])
+    ps, maxp, B, C = 8, 9, 12, 16
+    ctx = rng.integers(1, maxp * ps + 1, B)
+    nt = onp.minimum(rng.integers(1, C + 1, B), ctx)
+    idle = rng.random(B) < 0.25
+    ctx[idle], nt[idle] = 0, 0
+    ctx[B - 1], nt[B - 1] = maxp * ps, C          # the table's full width
+    start = ctx - nt
+    walk = maxp if window is None \
+        else min(maxp, window_walk_pages(window, C, ps))
+    want = []
+    for b in range(B):
+        pages = _visible_pages(ctx[b], start[b], nt[b], window, ps) or [
+            0 if window is None
+            else int(window_first_page(int(start[b]), window, ps))]
+        assert len(pages) <= walk
+        assert pages == list(range(pages[0], pages[0] + len(pages)))
+        want += [(b, pg) for pg in pages]
+    slot, page, n = (onp.asarray(x) for x in jax.jit(
+        lambda c, s: live_page_items(c, s, window, ps, walk))(
+            jnp.asarray(ctx, jnp.int32), jnp.asarray(start, jnp.int32)))
+    assert slot.shape == page.shape == (B * walk,)
+    first, count = live_page_range(ctx, start, window, ps, walk)
+    assert n == count.sum() == len(want)
+    assert list(zip(slot[:n], page[:n])) == want
+    assert (slot[n:] == want[-1][0]).all() and (page[n:] == want[-1][1]).all()
+    if window is None:
+        assert count[B - 1] == walk == maxp
+
+
+@pytest.mark.parametrize("C", [1, 16])
+@pytest.mark.parametrize("widths,page_in_lanes", [
+    ("gpt2", False), ("gpt2", True), ("afmoe_full", False),
+    ("afmoe_sliding", False)], ids=[
+        "gpt2-rows_in_sublanes", "gpt2-rows_in_lanes", "afmoe_full",
+        "afmoe_sliding"])
+def test_paged_attention_kernel_matches_the_reference_at_published_widths(
+        widths, page_in_lanes, C):
+    """The exact kernel code (interpret mode) at the widths the benchmark
+    serves, pages of 128: GPT-2-small's 12 heads of 64 over a table of 3,
+    in both page orientations (a v5e keeps that pool's rows in lanes);
+    `afmoe`'s 48 query / 8 kv heads of 128 over a table of 36, the full
+    layer, and the sliding one under its window of 4095 with every
+    released page of the table the null page and a work list of
+    `window_walk_pages` a slot.  An idle slot, which comes out as exact
+    zeros, a slot past a page edge, a slot at the table's end and one
+    whose chunk is shorter than the step's width."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    from mxnet_tpu.serve.kv_cache import (NULL_PAGE, live_page_items,
+                                          window_first_page,
+                                          window_walk_pages)
+    ps, window = 128, None
+    if widths == "gpt2":
+        H, Hkv, D, maxp = 12, 12, 64, 3
+        ctx = onp.asarray([0, 131, 384, 77])
+    else:
+        H, Hkv, D, maxp = 48, 8, 128, 36
+        ctx = onp.asarray([0, 130, maxp * ps, 300])
+        window = 4095 if widths == "afmoe_sliding" else None
+    B = len(ctx)
+    nt = onp.minimum([0, C, C, max(C // 2, 1)], ctx)
+    start = ctx - nt
+    rng = onp.random.RandomState(3)
+    npages = B * maxp + 1
+    q = jnp.asarray(rng.standard_normal((B, H, C, D)), jnp.float32)
+    kp, vp = (jnp.asarray(rng.standard_normal((2, Hkv, npages, ps, D)),
+                          jnp.float32) for _ in "kv")
+    table = onp.asarray(1 + rng.permutation(B * maxp).reshape(B, maxp),
+                        onp.int32)
+    ctx_d, start_d = (jnp.asarray(x, jnp.int32) for x in (ctx, start))
+    want = pa.paged_attention_reference(
+        q, kp[1], vp[1], jnp.asarray(table), ctx_d, start_d, window=window)
+    work_list = None
+    if window is not None:
+        for b in range(B):
+            table[b, :int(window_first_page(int(start[b]), window, ps))] \
+                = NULL_PAGE
+        assert (table[2, :2] == NULL_PAGE).all()       # pages were released
+        walk = window_walk_pages(window, 16, ps)
+        assert walk == 34 < maxp
+        work_list = live_page_items(ctx_d, start_d, window, ps, walk)
+    got = pa.ragged_paged_attention(
+        q, kp, vp, jnp.asarray(table), ctx_d, start_d, window=window,
+        use_kernel=True, layer=1, page_in_lanes=page_in_lanes,
+        work_list=work_list)
+    assert not onp.asarray(got[0]).any()
+    for b in range(1, B):
+        onp.testing.assert_allclose(got[b, :, :nt[b]], want[b, :, :nt[b]],
+                                    rtol=2e-5, atol=2e-5)
+
+
 def test_pages_in_lanes_reads_the_arrays_own_layout():
     """The orientation is asked of the pool's device layout: row-major on
     the CPU (rows in sublanes); a tracer or anything without a layout is

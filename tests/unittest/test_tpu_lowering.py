@@ -285,16 +285,16 @@ def test_paged_kv_write_kernel_lowers_for_tpu_at_gpt2_shapes(dtype, C,
     assert "output_operand_alias" in txt
 
 
-def _gpt2_width_engine(monkeypatch, page_in_lanes):
-    """A one-layer engine at GPT-2-small widths, traced as a TPU would
-    trace it (the dispatch consults `jax.default_backend`) with the pool
-    in the given orientation.  Depth and vocab are cut: neither shapes the
-    pool's calls."""
+def _gpt2_width_engine(monkeypatch, page_in_lanes, num_layers=1):
+    """An engine at GPT-2-small widths (one layer unless told), traced as
+    a TPU would trace it (the dispatch consults `jax.default_backend`)
+    with the pool in the given orientation.  Depth and vocab are cut:
+    neither shapes the pool's calls."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.gpt import GPTConfig, GPTForCausalLM
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
     from mxnet_tpu.serve.kv_cache import KVPools
-    cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=1,
+    cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=num_layers,
                     vocab_size=1024)
     model = GPTForCausalLM(cfg)
     model.initialize()
@@ -335,6 +335,88 @@ def test_gpt2_small_serve_step_lowers_with_paged_kernel(monkeypatch,
         assert not writes_pool.search(txt), (C, writes_pool.search(txt))
 
 
+def _count_kernel_bodies(monkeypatch):
+    """Count the traces of the two paged kernels' bodies: every kernel
+    `_make_rpa_kernel` / `_make_kv_write_kernel` builds from here on bumps
+    its counter when Pallas traces it.  The inner jits' caches are
+    cleared so that an earlier test's trace is not reused uncounted."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    traced = {"ragged_paged_attention": 0, "paged_kv_write": 0}
+
+    def counting(make, name):
+        def made(*a, **kw):
+            body = make(*a, **kw)
+
+            def counted(*refs):
+                traced[name] += 1
+                return body(*refs)
+            return counted
+        return made
+    monkeypatch.setattr(pa, "_make_rpa_kernel", counting(
+        pa._make_rpa_kernel, "ragged_paged_attention"))
+    monkeypatch.setattr(pa, "_make_kv_write_kernel", counting(
+        pa._make_kv_write_kernel, "paged_kv_write"))
+    pa._rpa_pallas.clear_cache()
+    pa._kv_write_pallas.clear_cache()
+    return traced
+
+
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+def test_serve_step_lowers_each_paged_kernel_once_a_width_not_once_a_layer(
+        monkeypatch, page_in_lanes):
+    """The guard on set-up.  A warm set-up compiles nothing, but it still
+    traces the step in Python and lowers every Pallas body to Mosaic
+    before the cache can be asked (PR 31 was refused for 3 s of exactly
+    that).  A three-layer GPT-2-width engine lowered for the TPU holds ONE
+    Mosaic body of `ragged_paged_attention` and one of `paged_kv_write` a
+    width, each called once a layer, and Pallas traces each kernel body
+    once a width: the layer index is a prefetched scalar, not a Python
+    constant in an index map, so the layers share one inner jit's
+    trace."""
+    import re
+    n_layers = 3
+    eng = _gpt2_width_engine(monkeypatch, page_in_lanes, n_layers)
+    traced = _count_kernel_bodies(monkeypatch)
+    widths = eng._step_widths()
+    for C in widths:
+        txt = eng._step_fn(C).trace(*eng._step_avals(C)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert txt.count("tpu_custom_call") == 2, (C, txt.count(
+            "tpu_custom_call"))
+        for fn in ("_rpa_pallas", "_kv_write_pallas"):
+            assert len(re.findall(r"call @%s\b" % fn, txt)) == n_layers
+    assert traced == {"ragged_paged_attention": len(widths),
+                      "paged_kv_write": len(widths)}
+
+
+def test_two_cache_groups_trace_the_attention_body_once_each(monkeypatch):
+    """An `afmoe` engine (four sliding layers, one full) traces the
+    attention kernel's body once a (width, cache group): the group's
+    layers differ in the layer scalar alone."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    m = AfmoeForCausalLM(AfmoeConfig(
+        vocab_size=512, hidden_size=64, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=16, intermediate_size=128,
+        moe_intermediate_size=32, num_dense_layers=1, num_experts=8,
+        num_experts_per_tok=2, layer_types=["sliding_attention"] * 4
+        + ["full_attention"], sliding_window=8, max_position=256,
+        experts_held=(2, 4), vocab_rows=(128, 96)))
+    m.initialize()
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
+    eng = InferenceEngine(m, ServeConfig(max_slots=2, page_size=8,
+                                         prefill_chunk=16, max_len=64))
+    traced = _count_kernel_bodies(monkeypatch)
+    for C in eng._step_widths():
+        txt = eng._step_fn(C).trace(*eng._step_avals(C)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "_rpa_pallas" in txt
+    assert traced["ragged_paged_attention"] == 2 * len(eng._step_widths())
+    assert traced["paged_kv_write"] == 2 * len(eng._step_widths())
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """One described (not attached) v5e chip to compile for; the test that
@@ -358,10 +440,9 @@ AFMOE = dict(H=48, Hkv=8, D=128, E=3072, F=3072, held=16, window=4095)
 @pytest.mark.parametrize("C", [1, 16])
 def test_paged_attention_kernel_lowers_at_afmoe_widths(C, sliding):
     """Head dim 128, six queries a KV head, a 128-page table; the sliding
-    group's call walks 34 pages from each slot's first live page."""
+    group's call walks a work list of 34 pages a slot."""
     from mxnet_tpu.ops.pallas.paged_attention import ragged_paged_attention
-    from mxnet_tpu.serve.kv_cache import (window_first_page,
-                                          window_walk_pages)
+    from mxnet_tpu.serve.kv_cache import live_page_items, window_walk_pages
     H, Hkv, D = (AFMOE[k] for k in ("H", "Hkv", "D"))
     B, ps, maxp = 32, 128, 128
     q = jnp.ones((B, H, C, D), jnp.bfloat16)
@@ -372,14 +453,59 @@ def test_paged_attention_kernel_lowers_at_afmoe_widths(C, sliding):
     assert walk == 34
 
     def f(q, kp, vp, pt, ctx, start):
-        more = dict(window=AFMOE["window"], walk_pages=walk,
-                    first_page=window_first_page(start, AFMOE["window"],
-                                                 ps)) if sliding else {}
+        more = dict(window=AFMOE["window"], work_list=live_page_items(
+            ctx, start, AFMOE["window"], ps, walk)) if sliding else {}
         return ragged_paged_attention(q, kp, vp, pt, ctx, start,
                                       use_kernel=True, layer=3, **more)
 
     txt = _lower_for_tpu(f, q, pool, pool, pt, ctx, ctx - C)
     assert txt.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("page_in_lanes", [False, True],
+                         ids=["rows_in_sublanes", "rows_in_lanes"])
+@pytest.mark.parametrize("C", [1, 16])
+@pytest.mark.parametrize("widths", ["gpt2", "afmoe_full", "afmoe_sliding"])
+def test_paged_attention_kernel_compiles_for_v5e_with_its_dynamic_grid(
+        v5e_chip, widths, C, page_in_lanes):
+    """`ragged_paged_attention` with its grid bound a traced count of live
+    (slot, page) pairs and all kv heads of a page one block, compiled by
+    Mosaic and XLA:TPU for the described chip at both configurations'
+    widths (GPT-2-small: 64 slots, 12 heads of 64, f32 queries over a
+    bf16 pool, a table of 8; afmoe: 32 slots, 48 query / 8 kv heads of
+    128, a table of 128 and the sliding group's 34) in both page
+    orientations: one custom call named for the metric that reads it,
+    the online-softmax state of every head in scoped VMEM."""
+    from mxnet_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    from mxnet_tpu.serve.kv_cache import live_page_items, window_walk_pages
+    if widths == "gpt2":
+        B, maxp, qdt, window = 64, 8, jnp.float32, None
+        H, Hkv, D = (GPT2_SMALL[k] for k in ("H", "Hkv", "D"))
+    else:
+        B, maxp, qdt = 32, 128, jnp.bfloat16
+        H, Hkv, D = (AFMOE[k] for k in ("H", "Hkv", "D"))
+        window = AFMOE["window"] if widths == "afmoe_sliding" else None
+    ps = 128
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def f(q, kp, vp, pt, ctx, start):
+        work_list = None if window is None else live_page_items(
+            ctx, start, window, ps, window_walk_pages(window, 16, ps))
+        return ragged_paged_attention(
+            q, kp, vp, pt, ctx, start, window=window, use_kernel=True,
+            layer=1, page_in_lanes=page_in_lanes, work_list=work_list)
+
+    pool = described((2, Hkv, 65, ps, D), jnp.bfloat16)
+    compiled = jax.jit(f).trace(
+        described((B, H, C, D), qdt), pool, pool,
+        described((B, maxp), jnp.int32), described((B,), jnp.int32),
+        described((B,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile()
+    txt = compiled.as_text()
+    assert txt.count("tpu_custom_call") == 1
+    assert "ragged_paged_attention" in txt
 
 
 @pytest.mark.parametrize("n_out", [2 * AFMOE["F"], AFMOE["E"]],
@@ -409,6 +535,37 @@ def test_grouped_expert_matmul_compiles_for_v5e_at_afmoe_widths(v5e_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def test_expert_layers_share_one_lowered_grouped_matmul_a_shape(v5e_chip):
+    """Four expert layers make the same two `mx_moe_gmm` calls (W1|W3,
+    W2): the lowered module holds two Mosaic bodies, not eight, and the
+    compiled one still names every call for the metrics that find them
+    by name."""
+    import re
+    from mxnet_tpu.ops.pallas import moe_gmm as G
+    E, F, held, layers = 256, 128, 4, 4
+    R = G.padded_rows(64, held)
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def f(xs, w13, w2, rows):
+        for li in range(layers):
+            h = G.grouped_matmul(xs, w13[li], rows, use_kernel=True)
+            xs = G.grouped_matmul(h[:, :F].astype(xs.dtype), w2[li], rows,
+                                  use_kernel=True).astype(xs.dtype)
+        return xs
+
+    lowered = jax.jit(f).trace(
+        described((R, E), jnp.bfloat16),
+        described((layers, held, E, 2 * F), jnp.bfloat16),
+        described((layers, held, F, E), jnp.bfloat16),
+        described((held,), jnp.int32)).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    names = set(re.findall(r"%(mx_moe_gmm(?:\.\d+)?) = ",
+                           lowered.compile().as_text()))
+    assert len(names) == 2 * layers
+
+
 def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
         monkeypatch, v5e_chip):
     """Mosaic and XLA:TPU run at `.compile()`.  A v5e keeps a bf16
@@ -421,7 +578,8 @@ def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
     at each end of the step, which is what `pages_in_lanes` is for."""
     import re
     from jax.experimental.layout import Format, Layout
-    eng = _gpt2_width_engine(monkeypatch, page_in_lanes=True)
+    n_layers = 3
+    eng = _gpt2_width_engine(monkeypatch, True, n_layers)
     pool = eng.pools.arrays["k"]
     pool_fmt = Format(Layout(major_to_minor=(0, 1, 2, 4, 3)), v5e_chip)
 
@@ -438,7 +596,12 @@ def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
         compiled = fn.trace(*avals).lower(
             lowering_platforms=("tpu",)).compile()
         txt = compiled.as_text()
-        assert txt.count("tpu_custom_call") == 2
+        assert txt.count("tpu_custom_call") == 2 * n_layers
+        # the layers share one lowered function a kernel, and the compiled
+        # step still names each call for the metric that finds it by name
+        for kernel in ("ragged_paged_attention", "paged_kv_write"):
+            assert len(set(re.findall(
+                r"%%(%s\.\d+|%s) = " % (kernel, kernel), txt))) == n_layers
         assert not pool_copy.search(txt), (C, pool_copy.search(txt))
         assert compiled.memory_analysis().temp_size_in_bytes \
             < pool.size * pool.dtype.itemsize // 4

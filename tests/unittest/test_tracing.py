@@ -589,6 +589,51 @@ def test_step_spans_tile_the_step_while_capturing(gate, tmp_path):
     assert len(tracing.get_tracer("serve").spans()) == n
 
 
+def _live_pages(ctx, start, nt, window, ps):
+    """Pages holding a key some query of the chunk sees (brute force);
+    an idle slot counts the one page that keeps its output written."""
+    seen = {k // ps for qp in range(start, start + nt) for k in range(ctx)
+            if k <= qp and (window is None or k >= qp - window)}
+    return max(len(seen), 1)
+
+
+def test_attn_items_counters_equal_the_enumeration_while_capturing(
+        monkeypatch):
+    """`attn_items_full` / `attn_items_sliding` / `attn_items_table` ride
+    on every `serve.step` while capturing: the live (slot, page) pairs of
+    the plan's own starts and contexts, one for an idle slot, against the
+    table a walk of every page would take.  Not capturing, the counters
+    are not computed at all."""
+    eng = _phase_engine()
+    sched = eng.scheduler
+    plans = []
+    plan = sched._plan
+
+    def spy():
+        out = plan()
+        if out is not None:
+            _, nt, start, _, ctx = out[3][:5]
+            plans.append(sum(_live_pages(int(c), int(s), int(n), None, 4)
+                             for c, s, n in zip(ctx, start, nt)))
+        return out
+    monkeypatch.setattr(sched, "_plan", spy)
+    tracing.enable()
+    _drive(eng, prompts=((1, 2, 3, 4, 5, 6, 7, 8, 9), (7, 8, 9)), max_new=4)
+    steps = [s for s in tracing.get_tracer("serve").spans()
+             if s.name == "serve.step"]
+    assert [s.tags["attn_items_full"] for s in steps] == plans
+    assert max(plans) > 2 == min(plans)        # past one page; both idle-or-one
+    assert {s.tags["attn_items_sliding"] for s in steps} == {0}
+    assert {s.tags["attn_items_table"] for s in steps} == {2 * 6}
+    tracing.disable()
+    monkeypatch.setattr(
+        sched, "_attn_items",
+        lambda *a: pytest.fail("counted while not capturing"))
+    _drive(eng, prompts=((1, 2),), max_new=2)
+    assert not any(k.startswith("attn_items")
+                   for k in sched._phase_log[-1][3])
+
+
 def test_record_phases_tiles_by_construction():
     tr = tracing.get_tracer("t")
     parent = tracing.record_phases(
