@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from ..ops.pallas.quantized_matmul import (QuantizedTensor,  # noqa: F401
                                            gather_rows, matmul_nt,
                                            quantize_weight)
+from .kv_cache import FULL
 
 __all__ = ["extract_decode_weights", "transformer_step", "lm_logits",
            "layer_norm", "rms_norm", "quantize_decode_weights",
@@ -62,9 +63,10 @@ class LayerSpec:
     ``out_gate`` multiplies the attention output by ``sigmoid`` of a
     fourth projection before the out-projection.  ``ffn``: ``"gelu"``
     (biased), ``"swiglu"`` or ``"moe"`` (`moe_ffn`).  ``cache_group``
-    names the KV pool the layer's keys live in: ``"full"`` keeps the
-    whole context, ``"sliding"`` only what a window can still see
-    (`serve/kv_cache.py`)."""
+    labels the KV pool the layer's keys live in: `kv_cache.FULL` keeps
+    the whole context, any other label (``"sliding"``) is a group that
+    keeps only what the layers' one window can still see
+    (`serve/kv_cache.py::plan_cache_groups`)."""
     norm: str = "layernorm"
     rope: bool = False
     rope_theta: float = 10000.0
@@ -72,7 +74,7 @@ class LayerSpec:
     qk_norm: bool = False
     out_gate: bool = False
     ffn: str = "gelu"
-    cache_group: str = "full"
+    cache_group: str = FULL
 
 
 @dataclass(frozen=True)
@@ -98,9 +100,10 @@ class DecodeSpec:
     route_scale: float = 1.0
 
     def cache_groups(self) -> Tuple[str, ...]:
-        """The KV pools this model needs, "full" first."""
-        names = {ls.cache_group for ls in self.layers}
-        return tuple(n for n in ("full", "sliding") if n in names)
+        """The cache groups the layers' labels name, in the layers'
+        order but for the whole-context one, which comes first."""
+        names = dict.fromkeys(ls.cache_group for ls in self.layers)
+        return tuple(sorted(names, key=lambda n: n != FULL))
 
     def group_layers(self, group: str) -> Tuple[int, ...]:
         return tuple(i for i, ls in enumerate(self.layers)

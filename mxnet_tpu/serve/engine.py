@@ -12,6 +12,17 @@ pure-decode step at C=1; with the persistent compile cache on
 TVM-flavored "serving path as a compiled, cached artifact" — the
 AOT-export layer loads these same programs from disk).
 
+The KV pool is one pool a **cache group** (`serve/kv_cache.py`): the
+engine holds the model's groups as ONE list, ``self.groups``, of
+`CacheGroup` records built by `plan_cache_groups` (name, layers, window,
+pool size, walk, allocator; the whole-context group first), and the
+pools, the step's page tables (a tuple, one a group), `_step_avals` and
+`stats()` are loops over that list: a model of one kind of layer has a
+list of one.  ``self.allocator`` is the whole-context group's, because
+sharing (prefix cache, copy-on-write fork, handoff) is defined only
+where a slot keeps every page of its context; the prefix cache and the
+disaggregated roles are on only when every group does.
+
 Instrumented from day one: compile/journal events, per-step histograms,
 page-occupancy gauges (via the scheduler), and a ``serve.step`` heartbeat
 the hang watchdog monitors like any training loop.
@@ -47,8 +58,8 @@ from .. import tracing as _trace
 from .decode import (extract_decode_weights, transformer_step, lm_logits,
                      quantize_decode_weights, decode_weight_bytes,
                      tp_qkv_row_perm, decode_spec)
-from .kv_cache import (KVPools, PageAllocator, PrefixIndex,
-                       make_paged_kv_fn, window_walk_pages)
+from .kv_cache import (KVPools, PrefixIndex, make_paged_kv_fn,
+                       plan_cache_groups)
 from .scheduler import ContinuousBatchingScheduler, ServeRequest
 from .spec import Drafter, NGramDrafter
 
@@ -211,38 +222,17 @@ class InferenceEngine:
                                   thresholds=act_thresholds)
         if self.tp > 1:
             self._tp_shard_weights()
-        # auto pool size: every slot can hold a full-length sequence,
-        # plus the reserved null page — PLUS the pages the quantized
-        # weights just paid for: the capacity freed by smaller weights
-        # lands in the free-page gauges, not in unaccounted HBM slack
-        # (ROADMAP item 2's whole premise).  An explicit num_pages wins.
-        bonus = 0
-        if sc.num_pages == 0 and self.quant_info is not None:
-            bonus = self.quant_info["saved_bytes"] // max(
-                1, self._page_nbytes(kv_dtype))
-        num_pages = sc.num_pages or \
-            sc.max_slots * self.max_pages_per_seq + 1 + bonus
-        self.bonus_pages = bonus
-        sliding = self._plan_sliding_group(sc)
-        self.pools = KVPools.create(
-            len(self.spec.group_layers("full")), num_pages, sc.page_size,
-            self.n_kv_heads, self.head_dim, dtype=kv_dtype, sliding=sliding)
-        if self.tp > 1:
-            self._tp_shard_pools()
-        self.allocator = PageAllocator(num_pages, sc.page_size)
-        #: the sliding group's own free list (None: the model has no
-        #: window layers, one pool holds every layer)
-        self.sliding_allocator = (
-            None if sliding is None
-            else PageAllocator(sliding[1], sc.page_size))
+        self._build_pools()
         #: cross-request prompt-prefix cache (MXTPU_PREFIX_CACHE):
         #: shared read-only page runs with COW forks; None when off.
-        #: Off too for a model with a sliding group: a prefix may be
-        #: shared only where every group still holds it, and the sliding
-        #: group has let the prompt's pages go (docs/serving.md)
-        self.prefix_index = (PrefixIndex(self.allocator, sc.page_size)
-                             if sc.prefix_cache and sliding is None
-                             else None)
+        #: Off too unless every cache group keeps the whole context: a
+        #: prefix may be shared only where every group still holds it,
+        #: and a windowed group has let the prompt's pages go
+        #: (docs/serving.md)
+        self.prefix_index = (
+            PrefixIndex(self.allocator, sc.page_size)
+            if sc.prefix_cache
+            and all(g.window is None for g in self.groups) else None)
         #: speculative-decoding proposal hook (MXTPU_SPEC_TOKENS)
         self.drafter = drafter if drafter is not None else (
             NGramDrafter() if sc.spec_tokens > 0 else None)
@@ -266,32 +256,34 @@ class InferenceEngine:
         self._note_weight_bytes()
         _health.beat("serve.step")   # announce the heartbeat name early
 
-    def _plan_sliding_group(self, sc):
-        """``(layers, pages)`` of the sliding group's pool, or None for
-        a model without window layers.  Sized so that every slot can hold
-        what a chunk of the widest step can see (`window_walk_pages`),
-        plus the null page; an explicit ``num_pages`` caps it too."""
-        layers = self.spec.group_layers("sliding")
-        self.sliding_window = self.sliding_walk = None
-        if not layers:
-            return None
-        windows = {self.spec.layers[i].window for i in layers}
-        if len(windows) != 1 or None in windows:
-            raise MXNetError(
-                f"the sliding cache group needs ONE window for all its "
-                f"layers, got {sorted(map(str, windows))}")
-        if sc.role != "both":
-            raise MXNetError(
-                "a model with a sliding cache group serves with "
-                "role='both': the prefill->decode handoff moves the full "
-                "group's pages only")
-        self.sliding_window = windows.pop()
-        self.sliding_walk = min(
-            self.max_pages_per_seq,
-            window_walk_pages(self.sliding_window, max(self._step_widths()),
-                              sc.page_size))
-        pages = sc.max_slots * self.sliding_walk + 1
-        return len(layers), min(pages, sc.num_pages or pages)
+    def _build_pools(self) -> None:
+        """The model's cache groups (`kv_cache.plan_cache_groups`: one
+        record a group with its allocator, the whole-context group
+        first) and the device pools over them.  An auto-sized
+        whole-context pool also gets the pages the quantized weights
+        just paid for: the capacity freed by smaller weights lands in
+        the free-page gauges, not in unaccounted HBM slack (ROADMAP item
+        2's whole premise).  An explicit num_pages wins."""
+        sc = self.serve_config
+        bonus = 0
+        if sc.num_pages == 0 and self.quant_info is not None:
+            bonus = self.quant_info["saved_bytes"] // max(
+                1, self._page_nbytes(self._kv_dtype))
+        self.bonus_pages = bonus
+        #: the cache groups, whole-context first: the scheduler, the
+        #: step and `stats()` loop over this one list
+        self.groups = plan_cache_groups(
+            self.spec, sc, self.max_pages_per_seq,
+            max(self._step_widths()), bonus, self.quantized)
+        self.pools = KVPools.create(
+            self.groups, sc.page_size, self.n_kv_heads, self.head_dim,
+            dtype=self._kv_dtype)
+        if self.tp > 1:
+            self._tp_shard_pools()
+        #: the whole-context group's free list: the one whose pages a
+        #: prefix, a copy-on-write fork or a handoff may share (the
+        #: fleet, worker and router planes read it)
+        self.allocator = self.groups[0].allocator
 
     # ------------------------------------------------------------------
     # weight-only quantization (docs/quantization.md)
@@ -344,31 +336,20 @@ class InferenceEngine:
         # the pages the freed weight bytes pay for — the SAME formula
         # construction uses, so an artifact captured here installs into
         # a ``quant_bits``-constructed engine with identical pool avals
-        if getattr(self, "pools", None) is not None and \
-                self.serve_config.num_pages == 0:
-            bonus = info["saved_bytes"] // max(
-                1, self._page_nbytes(self._kv_dtype))
-            if bonus > 0:
-                sc = self.serve_config
-                num_pages = self.pools.num_pages + bonus
-                self.pools = KVPools.create(
-                    self.pools.n_layers, num_pages, sc.page_size,
-                    self.n_kv_heads, self.head_dim,
-                    dtype=self._kv_dtype, sliding=self.pools.sliding)
-                self.allocator = PageAllocator(num_pages, sc.page_size)
-                self.bonus_pages = bonus
-                if getattr(self, "prefix_index", None) is not None:
-                    # the old index references the replaced allocator
-                    # and pool; start empty over the new ones (idle
-                    # engine — nothing was attached)
-                    self.prefix_index = PrefixIndex(self.allocator,
-                                                    sc.page_size)
-                if sched is not None:
-                    sched.allocator = self.allocator
         if self.tp > 1:
             self._tp_shard_weights()
-            if getattr(self, "pools", None) is not None:
-                self._tp_shard_pools()
+        if getattr(self, "pools", None) is not None and \
+                self.serve_config.num_pages == 0 and \
+                info["saved_bytes"] >= self._page_nbytes(self._kv_dtype):
+            self._build_pools()
+            if getattr(self, "prefix_index", None) is not None:
+                # the old index references the replaced allocator
+                # and pool; start empty over the new ones (idle
+                # engine — nothing was attached)
+                self.prefix_index = PrefixIndex(
+                    self.allocator, self.serve_config.page_size)
+            if sched is not None:
+                sched._bind_groups()
         self._note_weight_bytes()
         return info
 
@@ -496,8 +477,8 @@ class InferenceEngine:
         ps = sc.page_size
         spec = self.spec
         layer_plan = spec.cache_plan()
-        has_sliding = self.sliding_allocator is not None
-        sliding_walk = self.sliding_walk
+        group_names = tuple(g.name for g in self.groups)
+        walks = {g.name: g.walk for g in self.groups}
         has_moe = any(ls.ffn == "moe" for ls in spec.layers)
         quantized = self.quantized
         pool_names = self.pools.names
@@ -508,16 +489,14 @@ class InferenceEngine:
         tp = self.tp
         tp_axis = "tp" if tp > 1 else None
 
-        def step(P, pools_t, tok, num_tokens, start_pos, page_tables,
-                 ctx_lens, temps, greedy_mask, key, sliding_tables=None):
+        def step(P, pools_t, tok, num_tokens, start_pos, tables,
+                 ctx_lens, temps, greedy_mask, key):
             from ..models.gpt import _filter_logits
             pools = dict(zip(pool_names, pools_t))
-            kv_fn = make_paged_kv_fn(pools, page_tables, start_pos,
-                                     num_tokens, ctx_lens, ps, quantized,
-                                     page_in_lanes=page_in_lanes,
-                                     layer_plan=layer_plan,
-                                     sliding_tables=sliding_tables,
-                                     sliding_walk=sliding_walk)
+            kv_fn = make_paged_kv_fn(pools, dict(zip(group_names, tables)),
+                                     start_pos, num_tokens, ctx_lens, ps,
+                                     quantized, page_in_lanes=page_in_lanes,
+                                     layer_plan=layer_plan, walks=walks)
             # padded rows may run past the table; clamp for the embedding
             # gather only (writes are masked, attention rows are ignored)
             pos = jnp.minimum(start_pos[:, None] + jnp.arange(C)[None, :],
@@ -572,7 +551,7 @@ class InferenceEngine:
             return (tuple(pools[n] for n in pool_names), nxt) + tail
 
         if tp > 1:
-            assert not has_sliding and not has_moe   # `_resolve_tp`
+            assert len(self.groups) == 1 and not has_moe  # `_resolve_tp`
             # the body runs per-shard: weights/pools arrive as their
             # local OUT-dim / kv-head shards, batch inputs replicated;
             # every cross-shard combine inside is an all-gather, so the
@@ -584,7 +563,7 @@ class InferenceEngine:
             rep = PS()
             pool_specs = self._pool_specs()
             in_specs = (self._tp_weight_specs(), pool_specs,
-                        rep, rep, rep, rep, rep, rep, rep, rep)
+                        rep, rep, rep, (rep,), rep, rep, rep, rep)
             out_specs = ((pool_specs, rep, rep) if spec_k > 0
                          else (pool_specs, rep))
             step = shard_map_nocheck(step, self._mesh, in_specs,
@@ -811,11 +790,11 @@ class InferenceEngine:
             tuple(sd(a.shape, a.dtype)
                   for a in self.pools.as_tuple()),
             sd((B, C), i32), sd((B,), i32), sd((B,), i32),
-            sd((B, self.max_pages_per_seq), i32), sd((B,), i32),
-            sd((B,), jnp.float32), sd((B,), jnp.bool_),
+            tuple(sd((B, self.max_pages_per_seq), i32)
+                  for _ in self.groups),
+            sd((B,), i32), sd((B,), jnp.float32), sd((B,), jnp.bool_),
             sd(self._key.shape, self._key.dtype),
-        ) + ((sd((B, self.max_pages_per_seq), i32),)
-             if self.sliding_allocator is not None else ())
+        )
 
     def _compile(self, C: int):
         ex = self._execs.get(C)
@@ -864,18 +843,18 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def _execute(self, tok, num_tokens, start_pos, tables, ctx_lens,
-                 temps, greedy_mask, C: int, sliding_tables=None):
+                 temps, greedy_mask, C: int):
         """Run one fused step (called by the scheduler); returns
         ``(next_token[B], all_tok)`` as host numpy — `all_tok` is the
         (B, C) per-position greedy argmax when speculation is enabled,
-        else None.  `sliding_tables`: the sliding group's page tables,
-        for a model that has the group.  A model with expert layers also
+        else None.  `tables`: one (slots, table width) page table a
+        cache group, in `self.groups`' order.  A model with expert layers also
         leaves the step's routing counts, (expert layers, held experts),
         in ``self.last_moe_counts``, read back with the tokens.
         Leaves in ``self.launched_ts`` the `perf_counter`
         instant the executable's call returned: the boundary between
         the step's ``launch`` phase (key split, seven host-to-device
-        transfers, dispatch) and its ``wait`` (blocking on the tokens),
+        transfers (one more a further cache group), dispatch) and its ``wait`` (blocking on the tokens),
         which the scheduler reads after the call."""
         ex = self._execs.get(C)
         if ex is None:
@@ -889,14 +868,11 @@ class InferenceEngine:
         with _trace.annotation("serve.step.launch"):
             self._key, sub = jax.random.split(self._key)
             with self._device_lock:
-                more = () if sliding_tables is None \
-                    else (jnp.asarray(sliding_tables),)
                 out = ex(
                     self.P, self.pools.as_tuple(), jnp.asarray(tok),
                     jnp.asarray(num_tokens), jnp.asarray(start_pos),
-                    jnp.asarray(tables), jnp.asarray(ctx_lens),
-                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub,
-                    *more)
+                    tuple(map(jnp.asarray, tables)), jnp.asarray(ctx_lens),
+                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
                 out_pools, nxt, rest = out[0], out[1], list(out[2:])
                 all_tok = rest.pop(0) \
                     if self.serve_config.spec_tokens > 0 else None
@@ -1032,9 +1008,8 @@ class InferenceEngine:
             "queue_depth": self.scheduler.queue_depth,
             "active_slots": self.scheduler.active_count,
             "free_pages": self.allocator.free_pages,
-            "free_pages_sliding": (
-                None if self.sliding_allocator is None
-                else self.sliding_allocator.free_pages),
+            **{"free_pages_" + g.name: g.allocator.free_pages
+               for g in self.groups[1:]},
             "kv_pages_released": self.scheduler.kv_pages_released,
             "page_occupancy": round(self.allocator.occupancy(), 4),
             "pool_bytes": self.pools.nbytes(),

@@ -23,17 +23,28 @@ are never relayouted; everywhere else (int8 pools, the CPU,
 ``MXTPU_PALLAS`` off) by an XLA scatter, `scatter_kv_write`.
 
 **Cache groups** (docs/serving.md "Layers and cache groups"): a model
-whose layers are not all of one kind has one pool a kind.  The ``full``
-group (arrays ``k`` / ``v``) keeps a sequence's whole context, as above.
-The ``sliding`` group (arrays ``k_sliding`` / ``v_sliding``, with a page
-table and an allocator of its own) holds the layers whose queries see a
-window only: a page that lies wholly before ``cursor - window`` goes back
-to its allocator (`window_first_page`), and its table entry becomes the
-null page.  A model of one kind of layer (GPT-2) has the ``full`` group
-alone.  In either group the attention kernel visits only the (slot, page)
-pairs some query of the step can see (`live_page_range`): one work list a
-group and step (`live_page_items`), of at most `window_walk_pages` pages a
-slot in the sliding group and the table's width in the full one.
+whose layers are not all of one kind has one pool a kind.  What a group
+is lives here and nowhere else: one `CacheGroup` record a group (its
+name, layers, window, pool size, walk and `PageAllocator`), all of them
+built by `plan_cache_groups` from the model's `DecodeSpec` and the
+`ServeConfig`; the engine and the scheduler loop over that list.  The
+whole-context group (label `FULL`, arrays ``k`` / ``v``) comes first and
+keeps a sequence's whole context, as above.  Every other group (arrays
+``k_<name>`` / ``v_<name>``, a page table and an allocator of its own)
+holds layers whose queries see a window only: a page that lies wholly
+before ``cursor - window`` goes back to its allocator
+(`window_first_page`), and its table entry becomes the null page.  A
+model of one kind of layer (GPT-2) has a list of one.  What a slot holds
+in a group is a `PageRun`: a contiguous run of logical pages that grows
+at the back, is trimmed at the back, and in a windowed group is let go at
+the front.  In any group the attention kernel visits only the (slot,
+page) pairs some query of the step can see (`live_page_range`): one work
+list a group and step (`live_page_items`), of at most the group's `walk`
+pages a slot (`window_walk_pages` in a windowed group, the table's width
+in the whole-context one).  Sharing (the prefix cache, copy-on-write
+forks, the prefill-to-decode handoff) addresses the whole-context group
+only: a page may be shared only while every owner still holds it, and a
+windowed group has let the prompt's pages go.
 
 ``kv_dtype="int8"`` stores the pool quantized (symmetric per-token-per-head
 int8 via `contrib/quantization.quantize_kv`) at ~4x less HBM per token;
@@ -57,20 +68,24 @@ from __future__ import annotations
 import itertools
 import math
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from dataclasses import dataclass, field
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from ..base import MXNetError
 
 __all__ = ["PageAllocator", "PrefixIndex", "KVPools", "make_paged_kv_fn",
-           "NULL_PAGE", "SLIDING", "window_first_page", "window_walk_pages",
-           "live_page_range", "live_page_items"]
+           "NULL_PAGE", "FULL", "CacheGroup", "PageRun", "plan_cache_groups",
+           "window_first_page", "window_walk_pages", "live_page_range",
+           "live_page_items"]
 
 NULL_PAGE = 0
-#: suffix of the sliding group's pool arrays (``k_sliding``/``v_sliding``)
-SLIDING = "_sliding"
+#: the `LayerSpec.cache_group` label of the group that keeps a sequence's
+#: whole context; a layer under any other label lives in a windowed group
+FULL = "full"
 
 
 def window_first_page(cursor, window: int, page_size: int):
@@ -257,6 +272,176 @@ class PageAllocator:
             self._ref[new] = 1
             self._ref[page] = ref - 1
         return new, True
+
+
+def _pool_names(group: str) -> Tuple[str, str]:
+    """The K and V arrays of the cache group labelled `group`."""
+    return ("k", "v") if group == FULL else ("k_" + group, "v_" + group)
+
+
+@dataclass(frozen=True)
+class CacheGroup:
+    """One cache group of a model: the layers whose K/V share a pool, and
+    what a slot keeps of them.  The one record the pools, the step, the
+    scheduler and the stats are spelled from (`plan_cache_groups` builds
+    the list; the whole-context group is its first entry).
+
+    ``name`` is the layers' `LayerSpec.cache_group` label: it names the
+    pool arrays (`pool_names`), the step's tags ``kv_pages_<name>`` /
+    ``attn_items_<name>`` and the stats.  ``layers``: the model's layer
+    indices in the group, in pool order.  ``window``: None where a slot
+    keeps its whole context, else the pages wholly before ``cursor -
+    window`` go back (`PageRun.release_before`).  ``num_pages``: the
+    pool's physical pages, the null page among them.  ``walk``: the
+    static bound on the live pages a slot's chunk can see, what the
+    attention kernel's work list is sized for: the table's width for the
+    whole-context group, `window_walk_pages` for a windowed one."""
+    name: str
+    layers: Tuple[int, ...]
+    window: Optional[int]
+    num_pages: int
+    walk: int
+    allocator: PageAllocator = field(compare=False, repr=False)
+
+    @property
+    def pool_names(self) -> Tuple[str, str]:
+        """The group's K and V arrays in `KVPools.arrays`."""
+        return _pool_names(self.name)
+
+
+def plan_cache_groups(spec, config, max_pages: int, chunk: int,
+                      bonus_pages: int = 0,
+                      quantized: bool = False) -> Tuple[CacheGroup, ...]:
+    """The cache groups of a model under a serving configuration, the
+    whole-context group first: the ONE place that decides which groups
+    there are and, for each, its layers, window, pool size, walk and
+    allocator.
+
+    `spec` is the model's `DecodeSpec` (the groups are what its layers'
+    ``cache_group`` labels say), `config` the `ServeConfig`, `max_pages`
+    the width of a slot's page table (``ceil(max_len / page_size)``),
+    `chunk` the widest step the engine compiles, `bonus_pages` what
+    quantized weights paid for (they go to the whole-context group).
+
+    The whole-context pool is sized so that every slot can hold a
+    full-length sequence, plus the null page (an explicit ``num_pages``
+    wins).  A windowed pool is sized so that every slot can hold what a
+    chunk of the widest step can see (`window_walk_pages`), plus the null
+    page; an explicit ``num_pages`` caps it too.  A windowed group needs
+    one window for all its layers, an fp pool, and ``role='both'``: the
+    prefill-to-decode handoff moves the whole-context group's pages
+    only."""
+    names = spec.cache_groups()
+    if FULL not in names:
+        # admission and the request caps count whole-context pages: a
+        # model of window layers alone still has the (empty) group
+        names = (FULL,) + names
+    groups = []
+    for name in names:
+        layers = spec.group_layers(name)
+        window, walk, bonus = None, max_pages, bonus_pages
+        if name != FULL:
+            windows = {spec.layers[i].window for i in layers}
+            if len(windows) != 1 or None in windows:
+                raise MXNetError(
+                    f"the {name} cache group needs ONE window for all its "
+                    f"layers, got {sorted(map(str, windows))}")
+            if config.role != "both":
+                raise MXNetError(
+                    f"a model with a windowed cache group ({name}) serves "
+                    f"with role='both': the prefill->decode handoff moves "
+                    f"the whole-context group's pages only")
+            if quantized:
+                raise MXNetError(
+                    f"an int8 KV pool has no windowed group ({name}): "
+                    f"serve a model with window layers with an fp kv_dtype")
+            window, bonus = windows.pop(), 0
+            walk = min(max_pages,
+                       window_walk_pages(window, chunk, config.page_size))
+        pages = config.max_slots * walk + 1 + bonus
+        num_pages = config.num_pages or pages
+        if window is not None:
+            num_pages = min(num_pages, pages)
+        groups.append(CacheGroup(
+            name, layers, window, num_pages, walk,
+            PageAllocator(num_pages, config.page_size)))
+    return tuple(groups)
+
+
+class PageRun:
+    """What ONE slot holds in ONE cache group: a contiguous run of
+    logical pages, ``first .. first + len(pages) - 1``, and the table row
+    the step reads (the null page wherever no page is held).  Logical
+    page ``p`` holds tokens ``p * page_size ..``, in every group alike.
+
+    The run grows at the back (`grow`), is cut at the back when rejected
+    drafts roll the cursor back (`trim`), loses pages at the front where
+    the group has a window (`release_before`), and goes back whole when
+    the slot leaves (`free`).  `pages` and `table` are changed in place
+    and never rebound: the scheduler's sharing code holds the
+    whole-context group's two by reference."""
+
+    __slots__ = ("group", "first", "pages", "table")
+
+    def __init__(self, group: CacheGroup, max_pages: int):
+        self.group = group
+        self.first = 0
+        self.pages: List[int] = []
+        self.table = onp.zeros(max_pages, onp.int32)    # NULL_PAGE fill
+
+    def grow(self, cursor: int, need: int, take: Callable, who) -> bool:
+        """Hold every logical page from the first a query at `cursor`
+        can see up to page ``need - 1``.  ``take(who, group)`` hands
+        over one physical page of the group's allocator for the slot
+        `who`, or None when none can be had: then the run keeps what it
+        got and this returns False."""
+        pages = self.pages
+        if not pages and self.group.window is not None:
+            self.first = int(window_first_page(
+                cursor, self.group.window, self.group.allocator.page_size))
+        for at in range(self.first + len(pages), need):
+            page = take(who, self.group)
+            if page is None:
+                return False
+            self.table[at] = page
+            pages.append(page)
+        return True
+
+    def release_before(self, cursor: int) -> int:
+        """Give back the pages that lie wholly before ``cursor -
+        window``: no query from `cursor` on can see a key in them.
+        Returns how many went (0 in a group that keeps the whole
+        context)."""
+        window = self.group.window
+        if window is None:
+            return 0
+        alloc = self.group.allocator
+        n = min(len(self.pages), int(window_first_page(
+            cursor, window, alloc.page_size)) - self.first)
+        if n <= 0:
+            return 0
+        alloc.free(self.pages[:n])
+        del self.pages[:n]
+        self.table[self.first:self.first + n] = NULL_PAGE
+        self.first += n
+        return n
+
+    def trim(self, keep: int) -> None:
+        """Give back the pages at logical index `keep` and beyond: the
+        cursor rolled back past rejected drafts (those pages are freshly
+        allocated by construction, so they go straight to the free
+        list)."""
+        cut = max(0, keep - self.first)
+        extra = self.pages[cut:]
+        if extra:
+            del self.pages[cut:]
+            self.table[self.first + cut:self.first + cut + len(extra)] = \
+                NULL_PAGE
+            self.group.allocator.free(extra)
+
+    def free(self) -> None:
+        """Give every page back: the slot leaves."""
+        self.trim(0)
 
 
 class _PrefixEntry:
@@ -481,60 +666,54 @@ class PrefixIndex:
 
 
 class KVPools:
-    """Device-side paged K/V storage for every layer.
+    """Device-side paged K/V storage for every layer, one K and one V
+    array a cache group (`CacheGroup.pool_names`), plus scale planes when
+    quantized:
 
-    Arrays (one K + one V, plus scale planes when quantized):
-
-    - ``k``/``v``: (n_layers, Hkv, num_pages, page_size, D) `dtype`
-    - ``k_scale``/``v_scale``: (n_layers, Hkv, num_pages, page_size)
-      float32 (int8 pools only; one symmetric scale per stored vector)
-
-    - ``k_sliding``/``v_sliding``: (sliding layers, Hkv, sliding pages,
-      page_size, D): the sliding group's pool, where the model has one
-      (`sliding` = (layers, pages)); ``n_layers``/``num_pages`` count the
-      full group.
+    - ``k``/``v``: (the whole-context group's layers, Hkv, its pages,
+      page_size, D) `dtype`
+    - ``k_scale``/``v_scale``: the same without D, float32 (int8 pools
+      only; one symmetric scale per stored vector)
+    - ``k_<name>``/``v_<name>``: (the group's layers, Hkv, the group's
+      pages, page_size, D), one pair a windowed group
 
     The arrays are exposed as a flat tuple (`as_tuple`) so the engine can
     pass them through a jitted step with ``donate_argnums`` and rebind the
     donated outputs (`replace`).
     """
 
-    def __init__(self, arrays: Dict[str, jax.Array], n_layers: int,
-                 num_pages: int, page_size: int, n_kv_heads: int,
-                 head_dim: int, quantized: bool, sliding=None):
+    def __init__(self, arrays: Dict[str, jax.Array],
+                 groups: Tuple[CacheGroup, ...], page_size: int,
+                 n_kv_heads: int, head_dim: int, quantized: bool):
         self.arrays = arrays
-        self.n_layers = n_layers
-        self.num_pages = num_pages
+        self.groups = groups
         self.page_size = page_size
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.quantized = quantized
-        self.sliding = sliding
 
     @classmethod
-    def create(cls, n_layers: int, num_pages: int, page_size: int,
-               n_kv_heads: int, head_dim: int, dtype="float32",
-               sliding=None) -> "KVPools":
+    def create(cls, groups: Sequence[CacheGroup], page_size: int,
+               n_kv_heads: int, head_dim: int,
+               dtype="float32") -> "KVPools":
         quantized = str(dtype) == "int8"
-        shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         store_dt = jnp.int8 if quantized else jnp.dtype(dtype)
-        arrays = {"k": jnp.zeros(shape, store_dt),
-                  "v": jnp.zeros(shape, store_dt)}
-        if quantized:
-            sshape = shape[:-1]
-            arrays["k_scale"] = jnp.zeros(sshape, jnp.float32)
-            arrays["v_scale"] = jnp.zeros(sshape, jnp.float32)
-        if sliding is not None:
-            if quantized:
-                raise MXNetError(
-                    "an int8 KV pool has no sliding group: serve a model "
-                    "with window layers with an fp kv_dtype")
-            sshape = (sliding[0], n_kv_heads, sliding[1], page_size,
-                      head_dim)
-            arrays["k" + SLIDING] = jnp.zeros(sshape, store_dt)
-            arrays["v" + SLIDING] = jnp.zeros(sshape, store_dt)
-        return cls(arrays, n_layers, num_pages, page_size, n_kv_heads,
-                   head_dim, quantized, sliding)
+        arrays = {}
+        for g in groups:
+            shape = (len(g.layers), n_kv_heads, g.num_pages, page_size,
+                     head_dim)
+            for name in g.pool_names:
+                arrays[name] = jnp.zeros(shape, store_dt)
+                if quantized:
+                    arrays[name + "_scale"] = jnp.zeros(shape[:-1],
+                                                        jnp.float32)
+        return cls(arrays, tuple(groups), page_size, n_kv_heads, head_dim,
+                   quantized)
+
+    @property
+    def num_pages(self) -> int:
+        """Physical pages of the whole-context group's pool."""
+        return self.groups[0].num_pages
 
     @property
     def names(self):
@@ -545,15 +724,16 @@ class KVPools:
 
     def replace(self, values) -> "KVPools":
         """Rebind to the donated step outputs (same metadata)."""
-        return KVPools(dict(zip(self.names, values)), self.n_layers,
-                       self.num_pages, self.page_size, self.n_kv_heads,
-                       self.head_dim, self.quantized, self.sliding)
+        return KVPools(dict(zip(self.names, values)), self.groups,
+                       self.page_size, self.n_kv_heads, self.head_dim,
+                       self.quantized)
 
     @property
     def full_names(self):
-        """The full group's arrays: what a page id of the full group's
+        """The whole-context group's arrays: what a page id of its
         allocator indexes (page copies, handoff export/install)."""
-        return tuple(n for n in self.names if not n.endswith(SLIDING))
+        rest = {n for g in self.groups[1:] for n in g.pool_names}
+        return tuple(n for n in self.names if n not in rest)
 
     def pages_in_lanes(self) -> bool:
         """Does the device keep a page's rows in lanes (a (D, page_size)
@@ -567,11 +747,9 @@ class KVPools:
                    for a in self.arrays.values())
 
 
-def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
+def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
                      num_tokens, ctx_lens, page_size: int, quantized: bool,
-                     window=None, page_in_lanes: bool = False,
-                     layer_plan=None, sliding_tables=None,
-                     sliding_walk: Optional[int] = None):
+                     page_in_lanes: bool = False, *, layer_plan, walks):
     """Build the `kv_fn` closure `transformer_step` calls per layer inside
     the jitted serving step: write the chunk's new K/V into the paged
     pool, then attend over each slot's pages via
@@ -592,21 +770,21 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     written back per layer); after `transformer_step` returns it holds the
     step's updated pools — the engine returns them as donated outputs.
 
-    page_tables: (B, max_pages) int32; start_pos/num_tokens/ctx_lens:
-    (B,) int32.  Chunk token c of slot b sits at absolute position
-    ``start_pos[b] + c`` and is real iff ``c < num_tokens[b]`` — padded
-    rows scatter to the null page (the kernel drops them).
+    `tables`: ``{group name: (B, max_pages) int32}``, one page table a
+    cache group, all under the same logical indexing (released pages are
+    the null page); start_pos/num_tokens/ctx_lens: (B,) int32.  Chunk
+    token c of slot b sits at absolute position ``start_pos[b] + c`` and
+    is real iff ``c < num_tokens[b]`` — padded rows scatter to the null
+    page (the kernel drops them).
 
-    `layer_plan`: one ``(group, index in the group's pool, window)`` a
-    layer, from the model's `DecodeSpec` (None: every layer in the full
-    group at its own index under the one `window`, the GPT block).  A
-    ``"sliding"`` layer writes and reads ``k_sliding``/``v_sliding``
-    through `sliding_tables` (same logical indexing; released pages are
-    the null page).  On the kernel route the attention walks a work list
-    of live (slot, page) pairs (`live_page_items`), built here once a
-    group and shared by its layers: at most `sliding_walk` pages a slot
-    in the sliding group (`window_walk_pages`), the table's width in the
-    full one.
+    `layer_plan`: one ``(group name, index in the group's pool, window)``
+    a layer, from the model's `DecodeSpec.cache_plan`: the layer writes
+    and reads its group's arrays (`CacheGroup.pool_names`) through its
+    group's table, and its mask reads ITS window.  On the kernel route
+    the attention walks a work list of live (slot, page) pairs
+    (`live_page_items`), built here once a (group, window) and shared by
+    its layers: at most ``walks[group name]`` pages a slot
+    (`CacheGroup.walk`).
     """
     from ..ops.pallas.paged_attention import (
         paged_kernel_route, paged_kv_write, ragged_paged_attention)
@@ -616,29 +794,25 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], page_tables, start_pos,
     work_lists = {}
 
     def kv_fn(li, q, k_new, v_new):
-        group, gi, win = ("full", li, window) if layer_plan is None \
-            else layer_plan[li]
-        sliding = group == "sliding"
-        kn, vn = ("k" + SLIDING, "v" + SLIDING) if sliding else ("k", "v")
-        tables = sliding_tables if sliding else page_tables
+        group, gi, win = layer_plan[li]
+        kn, vn = _pool_names(group)
+        table = tables[group]
         with jax.named_scope("mx.serve.pool_write"):
             if kernel:
                 pools[kn], pools[vn] = paged_kv_write(
-                    pools[kn], pools[vn], k_new, v_new, gi, tables,
+                    pools[kn], pools[vn], k_new, v_new, gi, table,
                     start_pos, num_tokens, null_page=NULL_PAGE,
                     page_in_lanes=page_in_lanes)
             else:
-                scatter_kv_write(pools, gi, k_new, v_new, tables,
+                scatter_kv_write(pools, gi, k_new, v_new, table,
                                  start_pos, num_tokens, page_size,
                                  quantized, names=(kn, vn))
         with jax.named_scope("mx.serve.paged_attn"):
             if kernel and (group, win) not in work_lists:
                 work_lists[group, win] = live_page_items(
-                    ctx_lens, start_pos, win, page_size,
-                    min(sliding_walk, tables.shape[1]) if sliding
-                    else tables.shape[1])
+                    ctx_lens, start_pos, win, page_size, walks[group])
             return ragged_paged_attention(
-                q, pools[kn], pools[vn], tables, ctx_lens,
+                q, pools[kn], pools[vn], table, ctx_lens,
                 start_pos, window=win, layer=gi,
                 page_in_lanes=page_in_lanes,
                 k_scales=pools["k_scale"] if quantized else None,

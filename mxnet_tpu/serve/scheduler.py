@@ -18,6 +18,18 @@ memory) and continues decoding; already-streamed tokens are never
 re-emitted.  Greedy decoding makes the continuation deterministic, so an
 evicted request's final output is identical to an uninterrupted run.
 
+A slot holds, a **cache group** of the model (`engine.groups`, a list of
+`kv_cache.CacheGroup`), one `kv_cache.PageRun`: the contiguous run of
+logical pages it has there and the table row the step reads.  Growing to
+hold a chunk, letting go what lies behind a group's window, trimming past
+a rolled-back cursor and freeing are written once over a run; this module
+calls them in a loop over the groups and names no group.  What SHARES
+pages (the prefix attach at admission, the copy-on-write guard, the
+handoff's detach and adopt, the prefix eviction under pressure) addresses
+the whole-context group alone, as ``slot.pages`` / ``slot.table`` /
+``self.allocator``: a page can be shared only while every owner keeps it,
+and a windowed group gives the prompt's pages back.
+
 Everything host-side here is plain Python bookkeeping (lists, a free-list
 allocator); the device work happens in the engine's compiled step.
 Telemetry (`serve_*` metrics + `request` journal events) is emitted at
@@ -52,7 +64,7 @@ from .. import telemetry as _tele
 from .. import tracing as _trace
 from . import qos as _qos
 from . import traffic as _traffic
-from .kv_cache import NULL_PAGE, live_page_range, window_first_page
+from .kv_cache import PageRun, live_page_range
 
 __all__ = ["ServeRequest", "ContinuousBatchingScheduler",
            "terminate_request", "finish_request", "deliver_token"]
@@ -306,19 +318,21 @@ def finish_request(req: ServeRequest,
 
 
 class _Slot:
-    """One occupied batch slot: the request plus its KV page table."""
+    """One occupied batch slot: the request plus, a cache group, the run
+    of KV pages it holds there and its page table (`kv_cache.PageRun`)."""
 
-    def __init__(self, req: ServeRequest, slot_idx: int, max_pages: int,
-                 admit_seq: int):
+    def __init__(self, req: ServeRequest, slot_idx: int, groups,
+                 max_pages: int, admit_seq: int):
         self.req = req
         self.slot_idx = slot_idx
-        self.pages: List[int] = []
-        self.table = onp.zeros(max_pages, onp.int32)   # NULL_PAGE fill
-        # the sliding cache group (a model with window layers): logical
-        # page -> physical page of the pages still live, and the table
-        # the step reads (released and unallocated entries: NULL_PAGE)
-        self.wpages: dict = {}
-        self.wtable = onp.zeros(max_pages, onp.int32)
+        #: one `PageRun` a cache group, in the engine's order
+        self.runs = tuple(PageRun(g, max_pages) for g in groups)
+        # the whole-context group's pages and table row, by the names
+        # the sharing code reads (prefix attach, copy-on-write, handoff:
+        # defined there only).  The same objects as ``runs[0]``'s, which
+        # changes them in place
+        self.pages: List[int] = self.runs[0].pages
+        self.table = self.runs[0].table
         self.ctx = 0          # tokens already written to the pool
         self.admit_seq = admit_seq    # admission order (eviction priority)
         # prompt blocks registered in the engine's PrefixIndex (once,
@@ -351,12 +365,8 @@ class ContinuousBatchingScheduler:
         self.deadline_ms = float(getattr(cfg, "deadline_ms", 0) or 0)
         self.max_len = engine.max_len
         self.max_pages_per_seq = engine.max_pages_per_seq
-        self.allocator = engine.allocator
-        # the sliding cache group's free list and window (None: the model
-        # keeps every layer's whole context in the one pool)
-        self.sliding_allocator = engine.sliding_allocator
-        self.sliding_window = engine.sliding_window
-        self.kv_pages_released = 0   # sliding pages let go behind windows
+        self._bind_groups()
+        self.kv_pages_released = 0   # pages let go behind a group's window
         self._queue: deque = deque()
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
         self._lock = threading.Lock()
@@ -412,6 +422,26 @@ class ContinuousBatchingScheduler:
         # device call, so salvaging a replica stuck in `_execute` never
         # blocks on the stuck step
         self._step_lock = threading.Lock()
+
+    def _bind_groups(self) -> None:
+        """Take the engine's cache groups (`kv_cache.CacheGroup`, the
+        whole-context one first) and spell from them, once, what every
+        step reads: the step's tag names and which groups let pages go.
+        ``allocator`` is the whole-context group's: admission, the
+        request caps and everything that SHARES pages (prefix cache,
+        copy-on-write, handoff) count and address that group only,
+        because a page may be shared only while every owner still holds
+        it.  Again after the engine rebuilt its pools."""
+        self.groups = self.engine.groups
+        self.allocator = self.engine.allocator
+        self._kv_tags = tuple("kv_pages_" + g.name for g in self.groups)
+        self._windowed = tuple(i for i, g in enumerate(self.groups)
+                               if g.window is not None)
+        # one attention work list a (group, window of its layers)
+        layers = self.engine.spec.layers
+        self._work_lists = tuple(
+            (g.name, window, g.walk) for g in self.groups
+            for window in dict.fromkeys(layers[i].window for i in g.layers))
 
     # ------------------------------------------------------------------
     def validate_request(self, prompt, max_new_tokens: int) -> List[int]:
@@ -628,9 +658,10 @@ class ContinuousBatchingScheduler:
                     if idx is None:
                         return
                     req, pages, ctx_len = self._adopt_q.popleft()
-                    slot = _Slot(req, idx, self.max_pages_per_seq,
+                    slot = _Slot(req, idx, self.groups,
+                                 self.max_pages_per_seq,
                                  next(self._admit_seq))
-                    slot.pages = list(pages)
+                    slot.pages.extend(pages)
                     slot.table[:len(slot.pages)] = slot.pages
                     slot.ctx = int(ctx_len)
                     # the handed-off pages carry the prompt KV; this
@@ -661,10 +692,9 @@ class ContinuousBatchingScheduler:
                 if index is not None:
                     attached, hit = index.lookup(seq[:-1])
                 need = self.allocator.pages_for(len(seq) + 1)
-                if self.sliding_allocator is not None and not \
-                        self.sliding_allocator.can_alloc(
-                            min(need, self.engine.sliding_walk)):
-                    return     # the sliding group is dry: wait for frees
+                if not all(g.allocator.can_alloc(min(need, g.walk))
+                           for g in self.groups[1:]):
+                    return     # a windowed group is dry: wait for frees
                 pages = self._alloc_pages(need - len(attached))
                 if pages is None:
                     # OOM backpressure: wait for frees (the attached
@@ -674,9 +704,9 @@ class ContinuousBatchingScheduler:
                         self.allocator.free(attached)
                     return
                 del self._queue[pick]
-                slot = _Slot(req, idx, self.max_pages_per_seq,
+                slot = _Slot(req, idx, self.groups, self.max_pages_per_seq,
                              next(self._admit_seq))
-                slot.pages = attached + pages
+                slot.pages.extend(attached + pages)
                 slot.table[:len(slot.pages)] = slot.pages
                 slot.ctx = hit
                 slot.qos_pages = self._projected_pages(req)
@@ -705,8 +735,8 @@ class ContinuousBatchingScheduler:
     def _release_slot(self, slot: _Slot) -> None:
         """Recycle a slot's KV pages and vacate it — the one way any
         request leaves the active set."""
-        self.allocator.free(slot.pages)
-        self._drop_sliding(slot, list(slot.wpages))
+        for run in slot.runs:
+            run.free()
         self._slots[slot.slot_idx] = None
 
     def _evict(self, slot: _Slot, reason: str) -> None:
@@ -728,12 +758,16 @@ class ContinuousBatchingScheduler:
         self._telemetry_request(req, "evicted", reason=reason,
                                 generated=len(req.tokens))
 
-    def _take_page(self, slot: _Slot, alloc) -> Optional[int]:
-        """One page from `alloc()` for `slot`, evicting the youngest
+    def _take_page(self, slot: _Slot, group) -> Optional[int]:
+        """One page of `group`'s pool for `slot`, evicting the youngest
         OTHER active while the free list is dry; None when nobody is
-        left to evict (the slot itself must yield)."""
+        left to evict (the slot itself must yield).  The whole-context
+        group's free list is also fed by dropping cached prefixes
+        (`_alloc_pages`)."""
+        alloc = self._alloc_pages if group is self.groups[0] \
+            else group.allocator.alloc
         while True:
-            got = alloc()
+            got = alloc(1)
             if got is not None:
                 return got[0]
             victims = [s for s in self._slots
@@ -744,49 +778,18 @@ class ContinuousBatchingScheduler:
             self._evict(victims[-1], reason="page_pressure")
 
     def _ensure_capacity(self, slot: _Slot, upto_tokens: int) -> bool:
-        """Grow `slot`'s page table to hold `upto_tokens`, evicting
-        younger actives when the free list runs dry; in the sliding
-        group, the pages from the slot's first live page on.  Returns
-        False when even eviction cannot help (the slot itself must
-        yield)."""
-        need_total = self.allocator.pages_for(upto_tokens)
-        while len(slot.pages) < need_total:
-            page = self._take_page(slot, lambda: self._alloc_pages(1))
-            if page is None:
+        """Grow `slot`'s run in every cache group to hold `upto_tokens`
+        (in a windowed group, the pages from the slot's first live page
+        on), evicting younger actives when a free list runs dry.
+        Returns False when even eviction cannot help (the slot itself
+        must yield)."""
+        need = self.allocator.pages_for(upto_tokens)
+        for run in slot.runs:
+            # most steps no run is short: asked here, a call is saved
+            if run.first + len(run.pages) < need and \
+                    not run.grow(slot.ctx, need, self._take_page, slot):
                 return False
-            slot.table[len(slot.pages)] = page
-            slot.pages.append(page)
-        if self.sliding_allocator is not None:
-            first = window_first_page(slot.ctx, self.sliding_window,
-                                      self.page_size)
-            for pg in range(first, need_total):
-                if pg in slot.wpages:
-                    continue
-                page = self._take_page(
-                    slot, lambda: self.sliding_allocator.alloc(1))
-                if page is None:
-                    return False
-                slot.wpages[pg] = page
-                slot.wtable[pg] = page
         return True
-
-    def _release_behind_window(self, slot: _Slot) -> int:
-        """Give back the slot's sliding-group pages that lie wholly
-        before ``cursor - window``: no query from the cursor on can see
-        a key in them.  Returns how many went."""
-        first = window_first_page(slot.ctx, self.sliding_window,
-                                  self.page_size)
-        return self._drop_sliding(
-            slot, [pg for pg in slot.wpages if pg < first])
-
-    def _drop_sliding(self, slot: _Slot, logical: List[int]) -> int:
-        """Free the sliding-group pages at the logical indices `logical`
-        and null their table entries; returns how many."""
-        if logical:
-            self.sliding_allocator.free([slot.wpages.pop(pg)
-                                         for pg in logical])
-            slot.wtable[logical] = NULL_PAGE
-        return len(logical)
 
     def _cow_guard(self, slot: _Slot, first: int, last: int) -> bool:
         """Copy-on-write before the fused step scatters into token
@@ -829,14 +832,9 @@ class ContinuousBatchingScheduler:
         token lands in.  Freshly-allocated by construction (attached
         prefix pages always sit below the cursor), so they go straight
         back to the free list."""
-        keep = max(1, self.allocator.pages_for(slot.ctx + 1))
-        self._drop_sliding(slot, [pg for pg in slot.wpages if pg >= keep])
-        if len(slot.pages) <= keep:
-            return
-        extra = slot.pages[keep:]
-        del slot.pages[keep:]
-        slot.table[keep:keep + len(extra)] = NULL_PAGE
-        self.allocator.free(extra)
+        keep = self.allocator.pages_for(slot.ctx + 1)
+        for run in slot.runs:
+            run.trim(keep)
 
     # ------------------------------------------------------------------
     def _expire_deadlines(self) -> None:
@@ -923,11 +921,10 @@ class ContinuousBatchingScheduler:
             if batch is None:
                 self._update_gauges()
                 return False
-            C, plan, actives, arrays, wtables = batch
-            more = {} if wtables is None else {"sliding_tables": wtables}
+            C, plan, actives, arrays = batch
             kv_counts = {
-                "kv_pages_full": sum(len(s.pages) for s in actives),
-                "kv_pages_sliding": sum(len(s.wpages) for s in actives)}
+                tag: sum(len(s.runs[gi].pages) for s in actives)
+                for gi, tag in enumerate(self._kv_tags)}
             if _trace.capturing():
                 kv_counts.update(self._attn_items(arrays[2], arrays[4]))
 
@@ -938,7 +935,7 @@ class ContinuousBatchingScheduler:
             # traffic — slot.ctx has already advanced past tokens that
             # will never land, the hardest failover shape
             fault_point("replica_step")
-            next_tokens, all_tok = self.engine._execute(*arrays, C, **more)
+            next_tokens, all_tok = self.engine._execute(*arrays, C)
         except Exception as exc:
             with self._step_lock:
                 if self._abandoned:
@@ -989,8 +986,8 @@ class ContinuousBatchingScheduler:
                  "tokens_fed": sum(pl["nt"] for pl in plan.values()),
                  "emitted": emitted, "drafted": drafted,
                  "accepted": accepted, "queue_depth": queued,
-                 "h2d_bytes": sum(a.nbytes for a in arrays)
-                 + (0 if wtables is None else wtables.nbytes),
+                 "h2d_bytes": sum(a.nbytes for a in
+                                  arrays[:3] + arrays[3] + arrays[4:]),
                  **kv_counts, **self._moe_counts(),
                  **{k: now - was for k, was, now in zip(
                      self._COUNTED, before, self._totals())}})
@@ -1004,12 +1001,8 @@ class ContinuousBatchingScheduler:
         item).  ``attn_items_table``, slots x table width, is what a
         walk of the whole table would take: the ratio is the live
         share."""
-        items = {"attn_items_full": 0, "attn_items_sliding": 0,
-                 "attn_items_table": self.max_slots * self.max_pages_per_seq}
-        for group, window in {(g, w) for g, _, w
-                              in self.engine.spec.cache_plan()}:
-            walk = self.engine.sliding_walk if group == "sliding" \
-                else self.max_pages_per_seq
+        items = {"attn_items_table": self.max_slots * self.max_pages_per_seq}
+        for group, window, walk in self._work_lists:
             items["attn_items_" + group] = int(live_page_range(
                 ctx_lens, start_pos, window, self.page_size, walk)[1].sum())
         return items
@@ -1046,8 +1039,9 @@ class ContinuousBatchingScheduler:
         width, speculative drafts, page capacity (evicting where the
         pool is dry), the copy-on-write guard, and the per-slot feeds
         and page tables.  Returns ``(C, plan, actives, arrays)`` —
-        `arrays` in `engine._execute`'s order — or None when no slot is
-        left to run.  Holding ``_step_lock``."""
+        `arrays` in `engine._execute`'s order, its fourth a tuple of one
+        (slots, table width) page table a cache group — or None when no
+        slot is left to run.  Holding ``_step_lock``."""
         actives = [s for s in self._slots if s is not None]
         if not actives:
             return None
@@ -1098,10 +1092,9 @@ class ContinuousBatchingScheduler:
         # evicting younger actives) are evicted themselves this
         # round.  The COW guard then forks any still-shared page in
         # the write range before the step scatters into it.
-        sliding = self.sliding_allocator is not None
-        if sliding:
+        for gi in self._windowed:
             self.kv_pages_released += sum(
-                self._release_behind_window(s) for s in actives)
+                s.runs[gi].release_before(s.ctx) for s in actives)
         for s in sorted(actives, key=lambda s: s.admit_seq):
             if self._slots[s.slot_idx] is not s:
                 continue      # already evicted by a victim search
@@ -1118,8 +1111,8 @@ class ContinuousBatchingScheduler:
         tok = onp.zeros((B, C), onp.int32)
         num_tokens = onp.zeros(B, onp.int32)
         start_pos = onp.zeros(B, onp.int32)
-        tables = onp.zeros((B, self.max_pages_per_seq), onp.int32)
-        wtables = onp.zeros_like(tables) if sliding else None
+        tables = tuple(onp.zeros((B, self.max_pages_per_seq), onp.int32)
+                       for _ in self.groups)
         ctx_lens = onp.zeros(B, onp.int32)
         temps = onp.ones(B, onp.float32)
         greedy = onp.ones(B, bool)
@@ -1135,9 +1128,6 @@ class ContinuousBatchingScheduler:
             tok[i, :nt] = feed
             num_tokens[i] = nt
             start_pos[i] = s.ctx
-            tables[i] = s.table
-            if sliding:
-                wtables[i] = s.wtable
             ctx_lens[i] = s.ctx + nt
             temps[i] = s.req.temperature
             greedy[i] = s.req.greedy
@@ -1146,8 +1136,11 @@ class ContinuousBatchingScheduler:
                        "draft": len(draft), "emitted": 0,
                        "consume": s.ctx + nt_seq == len(seq)}
             s.ctx += nt
+        for gi, table in enumerate(tables):
+            for s in actives:
+                table[s.slot_idx] = s.runs[gi].table
         return C, plan, actives, (tok, num_tokens, start_pos, tables,
-                                  ctx_lens, temps, greedy), wtables
+                                  ctx_lens, temps, greedy)
 
     def _emit_step(self, plan, actives, next_tokens, all_tok,
                    t_plan: float, t_wait: float):
@@ -1270,8 +1263,8 @@ class ContinuousBatchingScheduler:
                 "admit": {k: counts[k]
                           for k in ("queue_depth", "admitted")},
                 "plan": {k: counts[k] for k in (
-                    "cow_forks", "evicted", "kv_pages_full",
-                    "kv_pages_sliding", "kv_pages_released")},
+                    "cow_forks", "evicted", *self._kv_tags,
+                    "kv_pages_released")},
                 "launch": {"h2d_bytes": counts["h2d_bytes"]},
                 "emit": {k: counts[k] for k in ("emitted", "finished")}})
 

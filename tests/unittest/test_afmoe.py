@@ -87,7 +87,9 @@ def test_step_logits_match_the_reference_past_window_and_page(built, chunk):
     window of 8 and past pages of 4, against the full forward."""
     from mxnet_tpu.serve.decode import (extract_decode_weights, lm_logits,
                                         transformer_step)
+    from mxnet_tpu.serve import ServeConfig
     from mxnet_tpu.serve.kv_cache import (KVPools, make_paged_kv_fn,
+                                          plan_cache_groups,
                                           window_first_page,
                                           window_walk_pages)
     model, p = built
@@ -97,8 +99,21 @@ def test_step_logits_match_the_reference_past_window_and_page(built, chunk):
     seq = onp.random.default_rng(1).integers(0, 96, 41).tolist()
     want = _ref_logits(p, CFG, seq)
     ps, maxp = 4, 12
-    walk = window_walk_pages(7, 16, ps)
-    pools = KVPools.create(1, maxp + 1, ps, 2, 16, sliding=(4, maxp + 1))
+    groups = plan_cache_groups(
+        spec, ServeConfig(max_slots=1, page_size=ps, num_pages=maxp + 1),
+        maxp, 16)
+    assert [(g.name, g.layers, g.window, g.num_pages, g.walk)
+            for g in groups] == [
+        ("full", (4,), None, maxp + 1, maxp),
+        ("sliding", (0, 1, 2, 3), 7, window_walk_pages(7, 16, ps) + 1,
+         window_walk_pages(7, 16, ps))]
+    # every page of the one table is live here, so the windowed pool is
+    # as large as the whole-context one
+    groups = (groups[0], dataclasses.replace(groups[1],
+                                             num_pages=maxp + 1))
+    walks = {g.name: g.walk for g in groups}
+    pools = KVPools.create(groups, ps, 2, 16)
+    assert pools.full_names == ("k", "v")
     arrays = dict(pools.arrays)
     table = jnp.arange(1, maxp + 1, dtype=jnp.int32)[None]
     plan = spec.cache_plan()
@@ -114,9 +129,9 @@ def test_step_logits_match_the_reference_past_window_and_page(built, chunk):
         first = int(window_first_page(at, 7, ps))
         stable = table.at[0, :first].set(0)
         kv_fn = make_paged_kv_fn(
-            arrays, table, start, jnp.asarray([c], jnp.int32),
-            jnp.asarray([at + c], jnp.int32), ps, False,
-            layer_plan=plan, sliding_tables=stable, sliding_walk=walk)
+            arrays, {"full": table, "sliding": stable}, start,
+            jnp.asarray([c], jnp.int32), jnp.asarray([at + c], jnp.int32),
+            ps, False, layer_plan=plan, walks=walks)
         pos = start[:, None] + jnp.arange(c)[None]
         h = transformer_step(P, mcfg, tok, pos, kv_fn)
         got.append(onp.asarray(lm_logits(P, h, cast_inputs=True))[0])
@@ -241,36 +256,44 @@ def test_sliding_group_holds_a_window_and_reuses_released_pages(built, chunk):
     are handed to another slot; and the streams equal those of an engine
     that releases nothing."""
     from mxnet_tpu.serve import kv_cache
-    from mxnet_tpu.serve.scheduler import ContinuousBatchingScheduler
     model, _ = built
     rng = onp.random.default_rng(7)
     prompts = [rng.integers(0, 96, n).tolist() for n in (41, 9, 33, 26)]
     eng = _engine(model, chunk, slots=2)
-    assert eng.sliding_walk <= 8 // 4 + 2
-    assert eng.pools.arrays["k_sliding"].shape[2] == 2 * eng.sliding_walk + 1
+    full, sliding = eng.groups
+    assert (full.name, sliding.name) == ("full", "sliding")
+    assert sliding.walk <= 8 // 4 + 2
+    assert eng.pools.arrays[sliding.pool_names[0]].shape[2] \
+        == sliding.num_pages == 2 * sliding.walk + 1
     owners, most = {}, [0]
 
     def watch(e):
         for s in e.scheduler._slots:
             if s is None:
                 continue
-            most[0] = max(most[0], len(s.wpages))
-            for page in s.wpages.values():
+            run = s.runs[1]
+            assert run.group is sliding and s.runs[0].pages is s.pages
+            most[0] = max(most[0], len(run.pages))
+            for page in run.pages:
                 owners.setdefault(page, set()).add(s.req.id)
+            # the table row: the run's pages where it holds them, the
+            # null page everywhere else
+            row = onp.zeros_like(run.table)
+            row[run.first:run.first + len(run.pages)] = run.pages
+            assert (run.table == row).all()
     streams = _drive(eng, prompts, watch=watch)
     assert most[0] <= 8 // 4 + 2
     assert eng.scheduler.kv_pages_released > 0
     assert any(len(reqs) > 1 for reqs in owners.values())
-    assert eng.sliding_allocator.free_pages == \
-        eng.sliding_allocator.total_pages          # all came back
+    for g in eng.groups:                           # all came back
+        assert g.allocator.free_pages == g.allocator.total_pages
 
     with pytest.MonkeyPatch.context() as mp:
-        import mxnet_tpu.serve.engine as E
-        mp.setattr(E, "window_walk_pages", lambda *a: 10 ** 6)
-        mp.setattr(ContinuousBatchingScheduler, "_release_behind_window",
-                   lambda self, slot: 0)
+        mp.setattr(kv_cache, "window_walk_pages", lambda *a: 10 ** 6)
+        mp.setattr(kv_cache.PageRun, "release_before",
+                   lambda self, cursor: 0)
         keep = _engine(model, chunk, slots=2)
-        assert keep.sliding_walk == keep.max_pages_per_seq
+        assert keep.groups[1].walk == keep.max_pages_per_seq
         assert _drive(keep, prompts) == streams
         assert keep.scheduler.kv_pages_released == 0
 
@@ -281,11 +304,11 @@ def test_wide_chunks_hold_what_a_chunk_can_see(built):
     from mxnet_tpu.serve.kv_cache import window_walk_pages
     model, _ = built
     eng = _engine(model, 16, slots=2)
-    assert eng.sliding_walk == window_walk_pages(7, 16, 4) == 7
+    assert eng.groups[1].walk == window_walk_pages(7, 16, 4) == 7
     most = [0]
 
     def watch(e):
-        most[0] = max([most[0]] + [len(s.wpages)
+        most[0] = max([most[0]] + [len(s.runs[1].pages)
                                    for s in e.scheduler._slots if s])
     prompts = [onp.random.default_rng(2).integers(0, 96, 50).tolist()]
     _drive(eng, prompts, watch=watch)
@@ -300,7 +323,8 @@ def test_sliding_group_under_page_pressure_preempts_and_recovers(built):
     prompts = [rng.integers(0, 96, n).tolist() for n in (30, 28)]
     roomy = _drive(_engine(model, 3, slots=2), prompts)
     tight = _engine(model, 3, slots=2, num_pages=26)
-    tight.sliding_allocator._free = tight.sliding_allocator._free[:5]
+    dry = tight.groups[1].allocator
+    dry._free = dry._free[:5]
     assert _drive(tight, prompts) == roomy
     assert tight.scheduler._n_evicted > 0
 
@@ -388,7 +412,7 @@ def test_engine_kernel_route_streams_equal_reference_route(built,
                                                            monkeypatch):
     """Greedy streams with both cache groups on the kernel route
     (interpret mode: one work list a group and step, the sliding one of
-    `sliding_walk` pages a slot over a table whose released entries are
+    the group's `walk` pages a slot over a table whose released entries are
     the null page) equal the reference route's: prompts past the window,
     decoded across page edges, a slot idle at the end."""
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
@@ -404,7 +428,7 @@ def test_engine_kernel_route_streams_equal_reference_route(built,
     monkeypatch.setenv("MXTPU_PALLAS", "kernel")
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
     eng = engine()
-    assert eng.sliding_walk == 3 < eng.max_pages_per_seq
+    assert eng.groups[1].walk == 3 < eng.max_pages_per_seq
     assert _drive(eng, prompts, new=7) == want
     assert eng.scheduler.kv_pages_released > 0
 
@@ -461,7 +485,8 @@ def test_gpt_through_the_per_layer_step_streams_what_generate_does(kw, tp):
                                   max_new_tokens=12).asnumpy())[0].tolist()
     eng = InferenceEngine(m, ServeConfig(max_slots=2, page_size=4,
                                          prefill_chunk=4, max_len=32, tp=tp))
-    assert eng.tp == tp and eng.sliding_allocator is None
+    assert eng.tp == tp and [g.name for g in eng.groups] == ["full"]
+    assert "free_pages_sliding" not in eng.stats()
     assert eng.pools.names == ("k", "v")
     assert eng.generate(prompt, max_new_tokens=12) == want
 
@@ -479,6 +504,16 @@ def test_gpt_spec_is_one_block_for_every_layer():
     assert [ls.ffn for ls in a.layers] == ["swiglu"] * 2 + ["moe"] * 6
     assert a.layers[0].window == 4095 and a.layers[3].window is None
     assert a.layers[0].rope and not a.layers[3].rope
+    # window layers alone: the whole-context group is still there, empty
+    # (admission and the request caps count its pages)
+    from mxnet_tpu.serve import ServeConfig
+    from mxnet_tpu.serve.kv_cache import plan_cache_groups
+    w = AfmoeConfig(num_layers=2, num_dense_layers=1,
+                    layer_types=["sliding_attention"] * 2).decode_spec()
+    assert w.cache_groups() == ("sliding",)
+    assert [(g.name, g.layers) for g in plan_cache_groups(
+        w, ServeConfig(max_slots=2, page_size=128), 8, 16)] == \
+        [("full", ()), ("sliding", (0, 1))]
 
 
 # ---------------------------------------------------------------------------
@@ -505,7 +540,7 @@ def test_attn_items_count_each_groups_live_pages_while_capturing(built):
     """Two cache groups, two counters, each the `n_items` of the work
     list the kernel would walk: the full group's items grow with the
     context, the sliding group's stay within what a window and a chunk
-    can span (`sliding_walk` a slot)."""
+    can span (the group's `walk` a slot)."""
     from mxnet_tpu import tracing
     from mxnet_tpu.serve.kv_cache import live_page_items
     model, _ = built
@@ -521,8 +556,7 @@ def test_attn_items_count_each_groups_live_pages_while_capturing(built):
             seen.append(tuple(
                 int(live_page_items(jnp.asarray(ctx), jnp.asarray(start),
                                     w, 4, walk)[2])
-                for w, walk in ((None, eng.max_pages_per_seq),
-                                (7, eng.sliding_walk))))
+                for w, walk in ((g.window, g.walk) for g in eng.groups)))
         return out
     eng.scheduler._plan = spy
     tracing.enable()
@@ -536,7 +570,7 @@ def test_attn_items_count_each_groups_live_pages_while_capturing(built):
     assert [(t["attn_items_full"], t["attn_items_sliding"])
             for t in steps] == seen
     assert max(f for f, _ in seen) == 1 + -(-35 // 4)     # an idle slot's 1
-    assert max(w for _, w in seen) <= 1 + eng.sliding_walk
+    assert max(w for _, w in seen) <= 1 + eng.groups[1].walk
     assert all(t["attn_items_table"] == 2 * eng.max_pages_per_seq
                for t in steps)
 

@@ -881,8 +881,10 @@ def test_pages_in_lanes_reads_the_arrays_own_layout():
     the CPU (rows in sublanes); a tracer or anything without a layout is
     row-major too."""
     from mxnet_tpu.ops.pallas.paged_attention import pages_in_lanes
-    from mxnet_tpu.serve.kv_cache import KVPools
-    pools = KVPools.create(2, 5, 8, 2, 16, dtype="bfloat16")
+    from mxnet_tpu.serve.kv_cache import CacheGroup, KVPools, PageAllocator
+    pools = KVPools.create(
+        [CacheGroup("full", (0, 1), None, 5, 4, PageAllocator(5, 8))],
+        8, 2, 16, dtype="bfloat16")
     assert pools.pages_in_lanes() is False
     assert pages_in_lanes(object()) is False
 

@@ -69,6 +69,73 @@ def test_page_allocator_guards():
         and a.pages_for(3) == 2
 
 
+@pytest.mark.parametrize("window", [None, 7])
+def test_page_run_grows_releases_trims_and_frees_over_a_group(window):
+    """What a slot holds in one cache group (`kv_cache.PageRun`), the one
+    set of page mechanics both kinds of group run through: the run grows
+    to hold a chunk, lets go what lies before the group's first live page
+    (nothing, where the group keeps the whole context), is trimmed past a
+    rolled-back cursor and is freed whole; the table row is the null page
+    wherever no page is held, and every page ends in the allocator."""
+    from mxnet_tpu.serve import PageAllocator
+    from mxnet_tpu.serve.kv_cache import (CacheGroup, PageRun,
+                                          window_first_page)
+    ps, maxp = 4, 16
+    alloc = PageAllocator(num_pages=maxp + 1, page_size=ps)
+    group = CacheGroup("full" if window is None else "w", (0,), window,
+                       maxp + 1, maxp, alloc)
+    assert group.pool_names == (("k", "v") if window is None
+                                else ("k_w", "v_w"))
+    run = PageRun(group, maxp)
+
+    def take(who, of):
+        assert who is run and of is group
+        got = alloc.alloc(1)
+        return None if got is None else got[0]
+
+    def check():
+        row = onp.zeros(maxp, onp.int32)
+        row[run.first:run.first + len(run.pages)] = run.pages
+        assert (run.table == row).all() and 0 not in run.pages
+        assert alloc.free_pages == alloc.total_pages - len(run.pages)
+
+    cursor = 0
+    for chunk in (5, 6, 1, 1, 16, 3, 1):        # prefill, decode, prefill
+        released = run.release_before(cursor)
+        first = 0 if window is None \
+            else int(window_first_page(cursor, window, ps))
+        assert run.first == first or not run.pages
+        assert released == 0 or window is not None
+        assert run.grow(cursor, alloc.pages_for(cursor + chunk), take, run)
+        cursor += chunk
+        # from the first page a query at the chunk's start could see to
+        # the page of its last token, and no other
+        assert (run.first, run.first + len(run.pages)) == \
+            (first, alloc.pages_for(cursor))
+        check()
+    assert cursor == 33 and (run.first > 0) == (window is not None)
+
+    # drafts rejected: the cursor rolls back, the pages past the one the
+    # next token lands in go back
+    held = list(run.pages)
+    assert run.grow(cursor, alloc.pages_for(cursor + 9), take, run)
+    assert len(run.pages) > len(held)
+    run.trim(alloc.pages_for(cursor + 1))
+    assert run.pages == held
+    check()
+
+    # a dry free list: the run keeps what it got and says so
+    hoard = alloc.alloc(alloc.free_pages - 1)
+    assert not run.grow(cursor, alloc.pages_for(cursor + 3 * ps), take, run)
+    assert len(run.pages) == len(held) + 1
+    alloc.free(hoard)
+    check()
+
+    run.free()
+    assert run.pages == [] and not run.table.any()
+    assert alloc.free_pages == alloc.total_pages
+
+
 # ---------------------------------------------------------------------------
 # ragged paged attention: paged-vs-dense numerical parity
 # ---------------------------------------------------------------------------

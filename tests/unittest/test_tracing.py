@@ -599,11 +599,11 @@ def _live_pages(ctx, start, nt, window, ps):
 
 def test_attn_items_counters_equal_the_enumeration_while_capturing(
         monkeypatch):
-    """`attn_items_full` / `attn_items_sliding` / `attn_items_table` ride
-    on every `serve.step` while capturing: the live (slot, page) pairs of
-    the plan's own starts and contexts, one for an idle slot, against the
-    table a walk of every page would take.  Not capturing, the counters
-    are not computed at all."""
+    """`attn_items_<group>` (here `attn_items_full` alone) and
+    `attn_items_table` ride on every `serve.step` while capturing: the
+    live (slot, page) pairs of the plan's own starts and contexts, one for
+    an idle slot, against the table a walk of every page would take.  Not
+    capturing, the counters are not computed at all."""
     eng = _phase_engine()
     sched = eng.scheduler
     plans = []
@@ -623,7 +623,8 @@ def test_attn_items_counters_equal_the_enumeration_while_capturing(
              if s.name == "serve.step"]
     assert [s.tags["attn_items_full"] for s in steps] == plans
     assert max(plans) > 2 == min(plans)        # past one page; both idle-or-one
-    assert {s.tags["attn_items_sliding"] for s in steps} == {0}
+    # one cache group: no tag of a group the model does not have
+    assert not any(k.endswith("_sliding") for s in steps for k in s.tags)
     assert {s.tags["attn_items_table"] for s in steps} == {2 * 6}
     tracing.disable()
     monkeypatch.setattr(
