@@ -87,6 +87,53 @@ def _default_page_size() -> int:
         LANES if jax.default_backend() == "tpu" else 16)
 
 
+class StepLayout:
+    """Where a step's host arrays lie in the ONE int32 array a launch
+    hands the device: ``tok``, ``num_tokens``, ``start_pos``, one page
+    table a cache group, ``ctx_lens``, ``greedy_mask`` (as int32) and
+    ``temps`` (its float32 bits), end to end.  The offsets follow from
+    the shapes alone — (slots, chunk width, cache groups, table width)
+    — so the host's `pack` and the jitted step's `unpack` cannot
+    disagree, and a further cache group is one more entry."""
+
+    def __init__(self, slots: int, chunk: int, n_groups: int,
+                 table_width: int):
+        B = slots
+        self.shapes = ((B, chunk), (B,), (B,),
+                       *[(B, table_width)] * n_groups, (B,), (B,), (B,))
+        #: elements of the packed array
+        self.size = sum(math.prod(s) for s in self.shapes)
+
+    def pack(self, tok, num_tokens, start_pos, tables, ctx_lens, temps,
+             greedy_mask) -> onp.ndarray:
+        """`_execute`'s arrays as one fresh int32 array (never a reused
+        buffer: the CPU backend may alias host memory)."""
+        parts = (tok, num_tokens, start_pos, *tables, ctx_lens,
+                 onp.asarray(greedy_mask, onp.int32),
+                 onp.asarray(temps, onp.float32).view(onp.int32))
+        if tuple(onp.shape(p) for p in parts) != self.shapes:
+            raise MXNetError(
+                f"step inputs of shapes "
+                f"{[onp.shape(p) for p in parts]} do not fit the step's "
+                f"layout {list(self.shapes)}")
+        return onp.concatenate(
+            [onp.asarray(p, onp.int32).ravel() for p in parts])
+
+    def unpack(self, packed):
+        """The packed array back as `pack`'s arguments, in their order:
+        static slices, reshapes, a bitcast and a compare — nothing that
+        depends on a value."""
+        parts, at = [], 0
+        for shape in self.shapes:
+            n = math.prod(shape)
+            parts.append(packed[at:at + n].reshape(shape))
+            at += n
+        tok, num_tokens, start_pos, *tables, ctx_lens, greedy, temps = parts
+        return (tok, num_tokens, start_pos, tuple(tables), ctx_lens,
+                jax.lax.bitcast_convert_type(temps, jnp.float32),
+                greedy != 0)
+
+
 @dataclass
 class ServeConfig:
     """Serving knobs; every field defaults from its ``MXTPU_SERVE_*``
@@ -253,6 +300,8 @@ class InferenceEngine:
         #: `perf_counter` instant the last step's executable call
         #: returned (`_execute`): the scheduler's launch/wait boundary
         self.launched_ts = 0.0
+        #: what that call was handed from the host: (bytes, arrays)
+        self.launched_h2d = (0, 0)
         self._note_weight_bytes()
         _health.beat("serve.step")   # announce the heartbeat name early
 
@@ -468,10 +517,17 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # compiled step
     # ------------------------------------------------------------------
-    def _step_fn(self, C: int):
-        fn = self._step_fns.get(C)
-        if fn is not None:
-            return fn
+    def _layout(self, C: int) -> StepLayout:
+        """The packed launch array's layout at chunk width C."""
+        return StepLayout(self.serve_config.max_slots, C,
+                          len(self.groups), self.max_pages_per_seq)
+
+    def _step_body(self, C: int):
+        """One fused step over its inputs one by one and the step's own
+        key, not jitted: ``body(P, pools, tok, num_tokens, start_pos,
+        tables, ctx_lens, temps, greedy_mask, key)`` returns ``(pools,
+        next_token[, all_tok][, moe_counts])``.  `_step_fn` wraps it in
+        the launch's two arguments; the tests run it as the reference."""
         cfg = self.cfg
         sc = self.serve_config
         ps = sc.page_size
@@ -489,7 +545,7 @@ class InferenceEngine:
         tp = self.tp
         tp_axis = "tp" if tp > 1 else None
 
-        def step(P, pools_t, tok, num_tokens, start_pos, tables,
+        def body(P, pools_t, tok, num_tokens, start_pos, tables,
                  ctx_lens, temps, greedy_mask, key):
             from ..models.gpt import _filter_logits
             pools = dict(zip(pool_names, pools_t))
@@ -550,22 +606,44 @@ class InferenceEngine:
                         all_tok) + tail
             return (tuple(pools[n] for n in pool_names), nxt) + tail
 
-        if tp > 1:
-            assert len(self.groups) == 1 and not has_moe  # `_resolve_tp`
+        return body
+
+    def _step_fn(self, C: int):
+        """The jitted step at chunk width C: ``step(P, pools, packed,
+        key)`` — the step's host arrays as ONE int32 array (`StepLayout`)
+        and the sampling key, which the program splits itself and hands
+        back as its last output, so that it never leaves the device.
+        Returns ``(pools, next_token[, all_tok][, moe_counts], key)``."""
+        fn = self._step_fns.get(C)
+        if fn is not None:
+            return fn
+        body = self._step_body(C)
+        layout = self._layout(C)
+
+        def step(P, pools_t, packed, key):
+            # split here, so the key never leaves the device: the chain
+            # a host-side `jax.random.split` would walk, value for value
+            key, sub = jax.random.split(key)
+            return body(P, pools_t, *layout.unpack(packed), sub) + (key,)
+
+        if self.tp > 1:
+            # `_resolve_tp`: only the one-group GPT block has a tp scheme
+            assert len(self.groups) == 1 and \
+                not any(ls.ffn == "moe" for ls in self.spec.layers)
             # the body runs per-shard: weights/pools arrive as their
-            # local OUT-dim / kv-head shards, batch inputs replicated;
-            # every cross-shard combine inside is an all-gather, so the
-            # sampled/greedy outputs are computed identically on every
-            # shard (replicated out_specs, checker off — the numeric
-            # pin is the tp bit-identity test)
+            # local OUT-dim / kv-head shards, the packed inputs and the
+            # key replicated; every cross-shard combine inside is an
+            # all-gather, so the sampled/greedy outputs (and the next
+            # key) are computed identically on every shard (replicated
+            # out_specs, checker off — the numeric pin is the tp
+            # bit-identity test)
             from jax.sharding import PartitionSpec as PS
             from ..parallel.mesh import shard_map_nocheck
             rep = PS()
             pool_specs = self._pool_specs()
-            in_specs = (self._tp_weight_specs(), pool_specs,
-                        rep, rep, rep, (rep,), rep, rep, rep, rep)
-            out_specs = ((pool_specs, rep, rep) if spec_k > 0
-                         else (pool_specs, rep))
+            in_specs = (self._tp_weight_specs(), pool_specs, rep, rep)
+            out_specs = (pool_specs, rep, rep) + (
+                (rep,) if self.serve_config.spec_tokens > 0 else ())
             step = shard_map_nocheck(step, self._mesh, in_specs,
                                      out_specs)
         fn = jax.jit(step, donate_argnums=(1,))
@@ -780,19 +858,15 @@ class InferenceEngine:
 
     def _step_avals(self, C: int):
         """The aval tuple one fused step takes at chunk width C (shared
-        by AOT compile and export capture)."""
-        B = self.serve_config.max_slots
+        by AOT compile and export capture): weights, pools, the packed
+        int32 array and the key."""
         sd = jax.ShapeDtypeStruct
-        i32 = jnp.int32
         return (
             jax.tree_util.tree_map(
                 lambda x: sd(x.shape, x.dtype), self.P),
             tuple(sd(a.shape, a.dtype)
                   for a in self.pools.as_tuple()),
-            sd((B, C), i32), sd((B,), i32), sd((B,), i32),
-            tuple(sd((B, self.max_pages_per_seq), i32)
-                  for _ in self.groups),
-            sd((B,), i32), sd((B,), jnp.float32), sd((B,), jnp.bool_),
+            sd((self._layout(C).size,), jnp.int32),
             sd(self._key.shape, self._key.dtype),
         )
 
@@ -853,9 +927,13 @@ class InferenceEngine:
         in ``self.last_moe_counts``, read back with the tokens.
         Leaves in ``self.launched_ts`` the `perf_counter`
         instant the executable's call returned: the boundary between
-        the step's ``launch`` phase (key split, seven host-to-device
-        transfers (one more a further cache group), dispatch) and its ``wait`` (blocking on the tokens),
-        which the scheduler reads after the call."""
+        the step's ``launch`` phase (the arrays packed into one int32
+        array, `StepLayout`, and the call, which carries that one
+        host-to-device transfer; the key is the previous step's output
+        and is split inside the program) and its ``wait`` (blocking on
+        the tokens), which the scheduler reads after the call; beside
+        it ``self.launched_h2d``, the bytes and the host arrays that
+        call was handed."""
         ex = self._execs.get(C)
         if ex is None:
             ex = self._compile(C)
@@ -866,19 +944,18 @@ class InferenceEngine:
             fault_point("tp_collective")
         self._steps_executed += 1
         with _trace.annotation("serve.step.launch"):
-            self._key, sub = jax.random.split(self._key)
+            packed = self._layout(C).pack(tok, num_tokens, start_pos,
+                                          tables, ctx_lens, temps,
+                                          greedy_mask)
             with self._device_lock:
-                out = ex(
-                    self.P, self.pools.as_tuple(), jnp.asarray(tok),
-                    jnp.asarray(num_tokens), jnp.asarray(start_pos),
-                    tuple(map(jnp.asarray, tables)), jnp.asarray(ctx_lens),
-                    jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
-                out_pools, nxt, rest = out[0], out[1], list(out[2:])
+                out_pools, nxt, *rest, self._key = ex(
+                    self.P, self.pools.as_tuple(), packed, self._key)
                 all_tok = rest.pop(0) \
                     if self.serve_config.spec_tokens > 0 else None
                 counts = rest.pop(0) if rest else None
                 # rebind the donated pool buffers to the step's outputs
                 self.pools = self.pools.replace(out_pools)
+            self.launched_h2d = (packed.nbytes, 1)
             self.launched_ts = time.perf_counter()
         with _trace.annotation("serve.step.wait"):
             nxt, all_tok, counts = jax.device_get((nxt, all_tok, counts))
@@ -978,7 +1055,8 @@ class InferenceEngine:
         """Install another engine's compiled step executables instead of
         lowering our own — replica N>1 of a fleet warms from replica 0's
         AOT compile (the executables are pure programs over (weights,
-        pools, batch); each engine still passes its OWN pool buffers).
+        pools, packed batch, key); each engine still passes its OWN
+        pool buffers and carries its OWN key).
         Requires an identical serving configuration."""
         if other._export_config() != self._export_config():
             raise MXNetError(

@@ -979,6 +979,7 @@ class ContinuousBatchingScheduler:
                 drafted, accepted, emitted = self._emit_step(
                     plan, actives, next_tokens, all_tok, t_plan, t_wait)
                 self._update_gauges()
+            h2d_bytes, h2d_arrays = self.engine.launched_h2d
             self._note_step(
                 C, (t_in, t_admit, t_plan, t_launch, t_wait,
                     time.perf_counter()),
@@ -986,8 +987,7 @@ class ContinuousBatchingScheduler:
                  "tokens_fed": sum(pl["nt"] for pl in plan.values()),
                  "emitted": emitted, "drafted": drafted,
                  "accepted": accepted, "queue_depth": queued,
-                 "h2d_bytes": sum(a.nbytes for a in
-                                  arrays[:3] + arrays[3] + arrays[4:]),
+                 "h2d_bytes": h2d_bytes, "h2d_arrays": h2d_arrays,
                  **kv_counts, **self._moe_counts(),
                  **{k: now - was for k, was, now in zip(
                      self._COUNTED, before, self._totals())}})
@@ -1265,7 +1265,8 @@ class ContinuousBatchingScheduler:
                 "plan": {k: counts[k] for k in (
                     "cow_forks", "evicted", *self._kv_tags,
                     "kv_pages_released")},
-                "launch": {"h2d_bytes": counts["h2d_bytes"]},
+                "launch": {k: counts[k]
+                           for k in ("h2d_bytes", "h2d_arrays")},
                 "emit": {k: counts[k] for k in ("emitted", "finished")}})
 
     def phase_stats(self, slowest: int = 5) -> dict:

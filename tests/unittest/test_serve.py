@@ -735,3 +735,181 @@ def test_deadline_env_knob_and_telemetry(monkeypatch, tmp_path):
         assert expired[0]["where"] == "queued"
     finally:
         tele.disable()
+
+
+# ---------------------------------------------------------------------------
+# the packed launch: one host-to-device array and a device-carried key a step
+# ---------------------------------------------------------------------------
+
+def _tiny_afmoe():
+    """Two cache groups (two window layers, one full) and an expert
+    layer, small."""
+    from mxnet_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
+    m = AfmoeForCausalLM(AfmoeConfig(
+        vocab_size=96, hidden_size=32, num_layers=3, num_heads=4,
+        num_kv_heads=2, head_dim=8, intermediate_size=64,
+        moe_intermediate_size=16, num_dense_layers=1, num_experts=4,
+        num_experts_per_tok=2,
+        layer_types=["sliding_attention"] * 2 + ["full_attention"],
+        sliding_window=8, max_position=96))
+    m.initialize()
+    return m
+
+
+def _launch_engines(case, n=1, seed=11):
+    """`n` engines over ONE model, of one seed; the weights from a fixed
+    seed too, so that the widths a run takes do not hang on the test's
+    random one."""
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    mx.random.seed(7)
+    m = _tiny_afmoe() if case == "afmoe_two_groups" else _tiny_model(
+        max_position=96)
+    return [InferenceEngine(m, ServeConfig(
+        max_slots=2, page_size=4, prefill_chunk=4, max_len=96,
+        spec_tokens=2 if case == "gpt2_spec" else 0), seed=seed)
+        for _ in range(n)]
+
+
+def _parents_launch(eng, widths):
+    """`_execute` as it was before the launch was packed, over the same
+    step body: the key split on the host, one `jnp.asarray` an array
+    (seven; one more a further cache group), the sub-key handed in."""
+    import jax
+    import jax.numpy as jnp
+    fns = {}
+
+    def execute(tok, num_tokens, start_pos, tables, ctx_lens, temps,
+                greedy_mask, C):
+        widths.append(C)
+        if C not in fns:
+            fns[C] = jax.jit(eng._step_body(C), donate_argnums=(1,))
+        eng._key, sub = jax.random.split(eng._key)
+        out_pools, nxt, *rest = fns[C](
+            eng.P, eng.pools.as_tuple(), jnp.asarray(tok),
+            jnp.asarray(num_tokens), jnp.asarray(start_pos),
+            tuple(map(jnp.asarray, tables)), jnp.asarray(ctx_lens),
+            jnp.asarray(temps), jnp.asarray(greedy_mask), sub)
+        eng.pools = eng.pools.replace(out_pools)
+        all_tok = onp.asarray(rest.pop(0)) \
+            if eng.serve_config.spec_tokens > 0 else None
+        eng.last_moe_counts = onp.asarray(rest.pop(0)) if rest else None
+        return onp.asarray(nxt), all_tok
+    return execute
+
+
+@pytest.mark.parametrize("case", ["gpt2", "afmoe_two_groups", "gpt2_spec"])
+def test_packed_launch_streams_equal_the_parents_launch_bit_for_bit(case):
+    """Greedy and sampled requests mixed, a fixed seed: the engine (one
+    packed array, the key split inside the program and carried on the
+    device) emits the streams of a reference that runs the same step body
+    the way the parent launched it (host-side split chain, an array an
+    input), over at least 20 steps of the chunk width and 20 of C = 1."""
+    eng, ref = _launch_engines(case, 2)
+    ref_widths, widths = [], []
+    ref._execute = _parents_launch(ref, ref_widths)
+    real = eng._execute
+    eng._execute = lambda *a: (widths.append(a[-1]), real(*a))[1]
+    rng = onp.random.RandomState(5)
+    # the periodic prompt gives the n-gram drafter something to propose
+    reqs = [(rng.randint(0, 96, 33).tolist(), 6, False, 0.8),
+            ([7, 8, 9] * 7, 44, True, 1.0),
+            (rng.randint(0, 96, 37).tolist(), 30, False, 1.3),
+            (rng.randint(0, 96, 9).tolist(), 36, True, 1.0),
+            (rng.randint(0, 96, 26).tolist(), 30, False, 0.6)]
+    out = []
+    for e in (eng, ref):
+        hs = [e.submit(p, max_new_tokens=n, greedy=g, temperature=t)
+              for p, n, g, t in reqs]
+        e.run_until_idle()
+        out.append([h.result(timeout=0) for h in hs])
+    assert out[0] == out[1]
+    assert widths == ref_widths
+    assert widths.count(4) >= 20 and widths.count(1) >= 20, widths
+    if case == "gpt2_spec":
+        assert widths.count(3) >= 1, widths
+    # the key the device carried is the host chain's, split by split
+    assert onp.array_equal(onp.asarray(eng._key), onp.asarray(ref._key))
+    # sampled streams did sample: they differ from their greedy runs
+    assert out[0][0] != eng.generate(reqs[0][0], reqs[0][1])
+
+
+@pytest.mark.parametrize("case", ["gpt2", "afmoe_two_groups", "gpt2_spec"])
+def test_step_takes_weights_pools_one_packed_array_and_the_key(case):
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import tracing
+    eng, = _launch_engines(case)
+    n_groups = 2 if case == "afmoe_two_groups" else 1
+    assert len(eng.groups) == n_groups
+    B, W = 2, eng.max_pages_per_seq
+    rng = onp.random.RandomState(0)
+    for C in eng._step_widths():
+        P, pools, *rest = eng._step_avals(C)
+        layout = eng._layout(C)
+        packed, key = jax.tree_util.tree_leaves(rest)
+        assert (packed.shape, packed.dtype) == ((layout.size,), jnp.int32)
+        assert layout.size == B * (C + 5 + n_groups * W)
+        assert (key.shape, key.dtype) == (eng._key.shape, eng._key.dtype)
+        # the layout round-trips, `temps` by its bits and the bool mask
+        x = (rng.randint(0, 96, (B, C)).astype(onp.int32),
+             rng.randint(0, C + 1, B).astype(onp.int32),
+             rng.randint(0, 90, B).astype(onp.int32),
+             tuple(rng.randint(0, 50, (B, W)).astype(onp.int32)
+                   for _ in range(n_groups)),
+             rng.randint(0, 96, B).astype(onp.int32),
+             onp.array([0.7, onp.float32(1) / 3], onp.float32),
+             onp.array([True, False]))
+        host = layout.pack(*x)
+        assert (host.dtype, host.shape) == (onp.int32, (layout.size,))
+        back = jax.jit(layout.unpack)(host)
+        for a, b in zip(jax.tree_util.tree_leaves(x),
+                        jax.tree_util.tree_leaves(back)):
+            assert a.dtype == b.dtype and onp.array_equal(a, onp.asarray(b))
+        with pytest.raises(MXNetError, match="layout"):
+            layout.pack(x[0][:, :-1], *x[1:])
+    # the launch span's counters: what the call was really handed
+    tracing.disable()
+    tracing.reset()
+    tracing.enable()
+    try:
+        eng.generate(list(range(1, 8)), max_new_tokens=3)
+        launches = [s for s in tracing.get_tracer("serve").spans()
+                    if s.name == "serve.step.launch"]
+    finally:
+        tracing.disable()
+        tracing.reset()
+    assert {s.tags["h2d_arrays"] for s in launches} == {1}
+    assert {s.tags["h2d_bytes"] for s in launches} <= {
+        4 * eng._layout(C).size for C in eng._step_widths()}
+    # (7 prompt tokens at chunk 4: two steps at the least; accepted drafts
+    # can spare the rest)
+    assert len(launches) == eng.stats()["steps_executed"] >= 2
+    assert eng.launched_h2d == (launches[-1].tags["h2d_bytes"], 1)
+
+
+def test_artifact_captured_before_the_packed_launch_is_refused(tmp_path):
+    """A serve artifact whose step took its inputs one by one (as every
+    capture before the packed launch did) no longer matches the step's
+    avals: an explicit load raises, naming the leaf count."""
+    import json
+    eng, = _launch_engines("gpt2")
+    path = eng.export(str(tmp_path / "art"))
+    fresh, = _launch_engines("gpt2")
+    fresh.load_export(path)                    # as captured: accepted
+    mpath = os.path.join(path, "manifest.json")
+    manifest = json.load(open(mpath))
+    B, W = 2, eng.max_pages_per_seq
+    n_old = 0
+    for rec in manifest["modules"].values():
+        C = rec["meta"]["chunk"]
+        weights_and_pools = rec["in_avals"][:-2]
+        rec["in_avals"] = weights_and_pools + [
+            [[B, C], "int32"], [[B], "int32"], [[B], "int32"],
+            [[B, W], "int32"], [[B], "int32"], [[B], "float32"],
+            [[B], "bool"], [[2], "uint32"]]
+        n_old = len(rec["in_avals"])
+    json.dump(manifest, open(mpath, "w"))
+    fresh, = _launch_engines("gpt2")
+    with pytest.raises(MXNetError, match=f"captured with {n_old}"):
+        fresh.warmup(artifact=path)
+    assert not fresh._execs

@@ -591,7 +591,8 @@ def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
         r"= bf16\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
     for C in eng._step_widths():
         fn = jax.jit(eng._step_fn(C).__wrapped__, donate_argnums=(1,),
-                     out_shardings=((pool_fmt, pool_fmt), v5e_chip))
+                     out_shardings=((pool_fmt, pool_fmt), v5e_chip,
+                                    v5e_chip))
         avals = jax.tree_util.tree_map(described, eng._step_avals(C))
         compiled = fn.trace(*avals).lower(
             lowering_platforms=("tpu",)).compile()
