@@ -8,7 +8,14 @@ values head-major as fixed-size pages `(Hkv, num_pages, page_size, D)` in
 HBM, so one (kv head, page) is a contiguous `(page_size, D)` tile — the
 block Mosaic can DMA (a kv-head axis in the second-minor position cannot
 be squeezed out of a TPU block); each slot's logical context is the
-concatenation of the pages its page table names.  The kernel's grid is a
+concatenation of the pages its page table names.  Where the head dim is
+under the 128 lanes and divides them, ``g = 128 / D`` kv heads share a
+page row instead (**folded**, `fold_heads`): the pool is
+``(Hkv / g, num_pages, page_size, g * D)``, kv head ``j * g + t`` in lanes
+``t * D ..`` of row group j, the same bytes with no padding.  A minor dim
+of 128 keeps the page's rows in sublanes in XLA:TPU's own layout, so the
+write moves one 16-row tile a row group where rows in lanes made it move
+the whole page (PERF.md section 6).  The kernel's grid is a
 **work list of the live (slot, logical page) pairs** with a traced bound
 (`serve.kv_cache.live_page_items`: for each slot the pages from its first
 query's window to its context's end, slot after slot), so a page no query
@@ -35,12 +42,16 @@ kernel route the pools are operands and results of custom calls only, so
 XLA:TPU has no layout of its own to give them and nothing to copy.  Both
 kernels take the pool in either page orientation (`pages_in_lanes`): as
 ``(page_size, D)`` tiles, or — where the device keeps a page's rows in
-lanes, as a v5e does for D < 128 — as the ``(D, page_size)`` tiles of the
-transposed view, which is a bitcast there.
+lanes, as a v5e does for an unfolded D < 128 — as the ``(D, page_size)``
+tiles of the transposed view, which is a bitcast there.
 
 Grouped-query attention uses the same folding trick as
 `flash_attention.py`: the `rep = H // Hkv` query heads sharing a kv head
-stack along the row axis, so K/V pages stream once per kv head.
+stack along the row axis, so K/V pages stream once per kv head.  A folded
+pool stacks the query rows of a row group's g kv heads the same way, each
+row in its own head's lanes and zero in the others': its scores against
+the 128-lane keys are its head's alone (every added product is x·0), and
+of the 128 lanes P·V gives it, it keeps its head's.
 
 The **reference path** (`paged_attention_reference`) gathers the page table
 into a contiguous `(B, L, Hkv, D)` context and runs masked dense attention
@@ -70,8 +81,8 @@ MASK_VALUE = -1e30
 LANES = 128
 
 __all__ = ["ragged_paged_attention", "paged_attention_reference",
-           "paged_kv_write", "paged_kernel_route", "pages_in_lanes",
-           "gather_pages",
+           "paged_kv_write", "paged_kv_write_bytes", "paged_kernel_route",
+           "pages_in_lanes", "gather_pages", "fold_heads", "unfold_heads",
            "kernel_tileable", "MASK_VALUE", "LANES"]
 
 
@@ -121,22 +132,51 @@ def _dense_attend(q, kc, vc, q_pos, ctx_len=None, window=None, scale=None):
 
 
 # ---------------------------------------------------------------------------
+# folded pools: g kv heads side by side in the lanes of one page row
+# ---------------------------------------------------------------------------
+
+def fold_heads(x, g: int, axis: int = 1):
+    """Put kv heads `g` at a time side by side in the minor dim: an array
+    with kv heads on `axis` and D minor becomes one with ``Hkv / g`` row
+    groups on `axis` and ``g * D`` minor, kv head ``j * g + t`` in lanes
+    ``t * D .. (t + 1) * D`` of group j.  Pure data movement (exact);
+    ``g == 1`` returns `x` as it is."""
+    if g == 1:
+        return x
+    x = jnp.moveaxis(x, axis, -2)
+    *lead, h, d = x.shape
+    return jnp.moveaxis(x.reshape(*lead, h // g, g * d), -2, axis)
+
+
+def unfold_heads(x, g: int, axis: int = 1):
+    """The inverse of `fold_heads`: one kv head a row again."""
+    if g == 1:
+        return x
+    x = jnp.moveaxis(x, axis, -2)
+    *lead, n, gd = x.shape
+    return jnp.moveaxis(x.reshape(*lead, n * g, gd // g), -2, axis)
+
+
+# ---------------------------------------------------------------------------
 # page gathering (reference path + int8 dequant epilogue)
 # ---------------------------------------------------------------------------
 
-def gather_pages(pool, page_tables, scales=None):
+def gather_pages(pool, page_tables, scales=None, heads_per_row: int = 1):
     """Materialise each slot's logical context from the paged pool.
 
-    pool: (Hkv, num_pages, page_size, D); page_tables: (B, max_pages)
-    int32 (unallocated entries may point anywhere — callers mask by
-    ctx_len).  Returns (B, Hkv, max_pages * page_size, D).
+    pool: (Hkv, num_pages, page_size, D), or a folded pool (Hkv / g,
+    num_pages, page_size, g * D) with ``heads_per_row = g``; page_tables:
+    (B, max_pages) int32 (unallocated entries may point anywhere —
+    callers mask by ctx_len).  Returns (B, Hkv, max_pages * page_size,
+    D) either way.
 
     `scales` (Hkv, num_pages, page_size) dequantizes an int8 pool on the
     fly — only the gathered context is dequantized, never the whole pool.
     """
     g = pool[:, page_tables]                    # (Hkv, B, maxp, ps, D)
     Hkv, B, maxp, ps, D = g.shape
-    g = g.reshape(Hkv, B, maxp * ps, D).transpose(1, 0, 2, 3)
+    g = unfold_heads(g.reshape(Hkv, B, maxp * ps, D).transpose(1, 0, 2, 3),
+                     heads_per_row)
     if scales is not None:
         sc = scales[:, page_tables].reshape(Hkv, B, maxp * ps, 1)
         g = g.astype(jnp.float32) * sc.transpose(1, 0, 2, 3)
@@ -145,14 +185,15 @@ def gather_pages(pool, page_tables, scales=None):
 
 def paged_attention_reference(q, kpool, vpool, page_tables, ctx_lens,
                               start_pos, window=None, scale=None,
-                              k_scales=None, v_scales=None, out_dtype=None):
+                              k_scales=None, v_scales=None, out_dtype=None,
+                              heads_per_row: int = 1):
     """Dense reference: gather the page table to a contiguous context and
     run `_dense_attend`.  CPU tier-1 path and the kernel's test oracle."""
     B, H, C, D = q.shape
     q_pos = start_pos[:, None] + jnp.arange(C)[None, :]
     dt = out_dtype or q.dtype
-    kc = gather_pages(kpool, page_tables, k_scales).astype(dt)
-    vc = gather_pages(vpool, page_tables, v_scales).astype(dt)
+    kc = gather_pages(kpool, page_tables, k_scales, heads_per_row).astype(dt)
+    vc = gather_pages(vpool, page_tables, v_scales, heads_per_row).astype(dt)
     return _dense_attend(q.astype(dt), kc, vc, q_pos, ctx_len=ctx_lens,
                          window=window, scale=scale)
 
@@ -172,8 +213,10 @@ def _make_rpa_kernel(scale, chunk, rep, window, page_in_lanes=False):
     normalised and stored at its last (both read from the list's
     neighbours).  Rows are the GQA fold — row r = (query-head-in-group
     r // chunk, chunk token r % chunk), so every row's query position is
-    ``start + r % chunk``.  All elementwise math is f32 (v5e has no bf16
-    VPU): q/k/v go to the MXU as stored and accumulate in f32.  With
+    ``start + r % chunk`` (`rep` counts the query heads a block of K/V
+    serves: a folded pool's whole row group).  All elementwise math is
+    f32 (v5e has no bf16 VPU): q/k/v go to the MXU as stored and
+    accumulate in f32.  With
     `page_in_lanes` the K/V blocks are (D, page_size) tiles a head (see
     `pages_in_lanes`): K^T feeds the score matmul as it lies and V^T is
     contracted over its lane dim, the form q.K^T has otherwise."""
@@ -262,13 +305,34 @@ def _lanes(x, n):
     return jnp.tile(x, (1, n // LANES))
 
 
+def _stack_queries(q, n_kv, g):
+    """(B, H, C, D) -> (B, n_kv, g * rep * C, g * D) for a pool folded
+    `g` kv heads a row: the query rows of a row group's g kv heads stacked
+    (head t's rows at ``t * rep * C ..``), each in its head's lanes and
+    zero in the other heads'."""
+    B, H, C, D = q.shape
+    qg = q.reshape(B, n_kv, g, H // (n_kv * g) * C, 1, D)
+    own = jnp.eye(g, dtype=bool)[:, None, :, None]
+    return jnp.where(own, qg, 0).reshape(B, n_kv, -1, g * D)
+
+
+def _unstack_outputs(o, g, shape):
+    """The inverse of `_stack_queries` on the kernel's output rows: each
+    row keeps its own head's lanes."""
+    B, H, C, D = shape
+    n_kv = o.shape[1]
+    o = jnp.diagonal(o.reshape(B, n_kv, g, -1, g, D), axis1=2, axis2=4)
+    return jnp.moveaxis(o, -1, 2).reshape(shape)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "window", "scale", "page_in_lanes", "interpret"))
+    "window", "scale", "page_in_lanes", "heads_per_row", "interpret"))
 def _rpa_pallas(q, kpool, vpool, lay, page_tables, ctx_lens, start_pos,
                 item_slot, item_page, n_items, *, window, scale,
-                page_in_lanes, interpret):
-    """Launch the Pallas kernel over the stacked ``(n_layers, Hkv, pages,
-    ps, D)`` pools (shapes pre-validated by the wrapper).  The grid is the
+                page_in_lanes, heads_per_row, interpret):
+    """Launch the Pallas kernel over the stacked ``(n_layers, Hkv / g,
+    pages, ps, g * D)`` pools (``g = heads_per_row``; shapes pre-validated
+    by the wrapper).  The grid is the
     work list, its bound the traced count of live items: a page past a
     slot's context or before its window costs no step.  The layer is
     picked in the K/V index map, so XLA never materialises a per-layer
@@ -282,13 +346,19 @@ def _rpa_pallas(q, kpool, vpool, lay, page_tables, ctx_lens, start_pos,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, C, D = q.shape
-    _, Hkv, _, ps, _ = kpool.shape
-    rep = H // Hkv
+    _, Hkv, _, ps, _ = kpool.shape      # Hkv: rows of g kv heads
+    g = heads_per_row
+    rep = H // Hkv              # query heads a row of K/V serves
     rows = rep * C
 
-    # fold query heads onto rows: (B, H, C, D) -> (B, Hkv, rep*C, D),
-    # padded to the dtype's sublane tile so tiny decode batches still tile
-    qf = q.reshape(B, Hkv, rows, D)
+    # fold query heads onto rows: (B, H, C, D) -> (B, Hkv, rep*C, D) (a
+    # folded pool: `_stack_queries`, D becomes g * D), padded to the
+    # dtype's sublane tile so tiny decode batches still tile
+    if g > 1:
+        qf = _stack_queries(q, Hkv, g)
+        D = g * D
+    else:
+        qf = q.reshape(B, Hkv, rows, D)
     min_rows = 8 * max(1, 4 // q.dtype.itemsize)
     pad = (-rows) % min_rows
     if pad:
@@ -331,6 +401,8 @@ def _rpa_pallas(q, kpool, vpool, lay, page_tables, ctx_lens, start_pos,
     )(page_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       start_pos.astype(jnp.int32), item_slot, item_page, lay,
       qf, kpool, vpool)
+    if g > 1:
+        return _unstack_outputs(out[:, :, :rows], g, q.shape)
     return out[:, :, :rows].reshape(B, H, C, D)
 
 
@@ -374,7 +446,11 @@ def pages_in_lanes(pool) -> bool:
     the pool as it is shaped it costs a whole-pool relayout at each end of
     the step; fed the transposed VIEW (a bitcast of that layout) it costs
     none.  The kernels take either orientation (`page_in_lanes=`); the
-    engine asks here, once, which one its pool has."""
+    engine asks here, once, which one its pool has.  A folded pool
+    (`fold_heads`) has a minor dim of 128 and keeps its rows in
+    sublanes; this orientation is left to a D < 128 pool the engine
+    cannot fold (an odd count of kv heads a shard, a D that does not
+    divide 128)."""
     try:
         order = tuple(pool.format.layout.major_to_minor)
     except AttributeError:      # nothing with a layout to ask (a tracer)
@@ -465,11 +541,14 @@ def _make_kv_write_kernel(tile, page_in_lanes):
 
 def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
                    start_pos, num_tokens, null_page: int = 0,
-                   page_in_lanes: bool = False):
+                   page_in_lanes: bool = False, heads_per_row: int = 1):
     """Write a chunk's new K/V rows into layer `layer` of the stacked
     pools, in place: returns the (k, v) pools, aliased to the operands.
 
-    kpool/vpool: (n_layers, Hkv, num_pages, page_size, D); k_new/v_new:
+    kpool/vpool: (n_layers, Hkv, num_pages, page_size, D), or folded
+    (n_layers, Hkv / g, num_pages, page_size, g * D) with ``heads_per_row
+    = g`` (`fold_heads`: the new rows are folded the same way before the
+    call, and a grid step moves a row tile of every row group); k_new/v_new:
     (B, Hkv, C, D); page_tables: (B, max_pages); start_pos/num_tokens:
     (B,).  Row ``c < num_tokens[b]`` of slot b lands at offset
     ``(start_pos[b] + c) % page_size`` of page ``page_tables[b,
@@ -480,10 +559,40 @@ def paged_kv_write(kpool, vpool, k_new, v_new, layer, page_tables,
     counts and the layer arrive by scalar prefetch and the layer is
     picked in the index map, as in `_rpa_pallas`, under the same kind of
     inner `jax.jit`; `page_in_lanes` as in `pages_in_lanes`."""
+    g = int(heads_per_row)
+    if kpool.shape[-1] != g * k_new.shape[-1]:
+        raise ValueError(f"a pool of {g} kv head(s) a row holds rows of "
+                         f"{g} x {k_new.shape[-1]} lanes, got "
+                         f"{kpool.shape[-1]}")
     return _kv_write_pallas(
-        kpool, vpool, k_new, v_new, _layer_scalar(layer), page_tables,
-        start_pos, num_tokens, null_page=int(null_page),
-        page_in_lanes=bool(page_in_lanes), interpret=interpret_mode())
+        kpool, vpool, fold_heads(k_new, g), fold_heads(v_new, g),
+        _layer_scalar(layer), page_tables, start_pos, num_tokens,
+        null_page=int(null_page), page_in_lanes=bool(page_in_lanes),
+        interpret=interpret_mode())
+
+
+def paged_kv_write_bytes(pool_shape, dtype, slots: int, chunk: int,
+                         page_in_lanes: bool = False) -> int:
+    """HBM bytes one `paged_kv_write` call moves, reckoned from its block
+    shapes and grid: each of its ``slots x tiles`` grid steps reads and
+    writes one pool block of K and one of V (a tile of page rows, every
+    row group), and each slot reads its new K and V rows once.  A step of
+    a chunk that straddles fewer tiles than the grid allows repeats its
+    block and moves nothing: this is the grid's bound, what the chunk's
+    widest placement moves.  `pool_shape` is the stacked pool's."""
+    _, n_kv, _, ps, d = pool_shape
+    item = jnp.dtype(dtype).itemsize
+    tile = _kv_write_tile(ps, dtype, page_in_lanes)
+    new = n_kv * d * (-(-chunk // 16) * 16 * item if page_in_lanes
+                      else chunk * 4)
+    return slots * (_kv_write_tiles(chunk, tile) * 4 * n_kv * tile * d * item
+                    + 2 * new)
+
+
+def _kv_write_tiles(chunk: int, tile: int) -> int:
+    """Tiles a chunk of `chunk` rows can straddle (C = 16 on 16-row
+    tiles: two, maybe on two pages): the write's grid steps a slot."""
+    return (chunk + tile - 2) // tile + 1
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -500,10 +609,9 @@ def _kv_write_pallas(kpool, vpool, k_new, v_new, lay, page_tables,
     ps = kpool.shape[3]
     maxp = page_tables.shape[1]
     tile = _kv_write_tile(ps, kpool.dtype, page_in_lanes)
-    # tiles a chunk of C rows can straddle (C = 16 on 16-row tiles: two,
-    # maybe on two pages); the surplus steps of a chunk that straddles
-    # fewer repeat its last block index and are skipped in the kernel
-    n_tiles = (C + tile - 2) // tile + 1
+    # the surplus steps of a chunk that straddles fewer tiles than the
+    # grid allows repeat its last block index and are skipped in the kernel
+    n_tiles = _kv_write_tiles(C, tile)
 
     def new_map(b, j, pt, st, nt, lay):
         return (b, 0, 0, 0)
@@ -559,13 +667,16 @@ def _kv_write_pallas(kpool, vpool, k_new, v_new, lay, page_tables,
 def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
                            start_pos, window=None, scale=None,
                            k_scales=None, v_scales=None, use_kernel=None,
-                           layer=None, page_in_lanes=False, work_list=None):
+                           layer=None, page_in_lanes=False, work_list=None,
+                           heads_per_row: int = 1):
     """Mixed prefill/decode attention over a paged KV pool — one launch.
 
     q: (B, H, C, D) chunk queries (C = 1 for a pure-decode step);
     kpool/vpool: (Hkv, num_pages, page_size, D) — or, with ``layer=li``,
     the stacked (n_layers, Hkv, num_pages, page_size, D) pools of which
-    layer `li` is read (likewise the scale planes); page_tables:
+    layer `li` is read (likewise the scale planes); folded
+    (`fold_heads`) where ``heads_per_row = g > 1``: (.., Hkv / g,
+    num_pages, page_size, g * D); page_tables:
     (B, max_pages) int32 physical-page ids per logical page; ctx_lens:
     (B,) valid context length INCLUDING this chunk's tokens (already
     written to the pool); start_pos: (B,) absolute position of each
@@ -592,7 +703,11 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
         kpool, vpool, layer = kpool[None], vpool[None], 0
         k_scales = None if k_scales is None else k_scales[None]
         v_scales = None if v_scales is None else v_scales[None]
-    Hkv, ps = kpool.shape[1], kpool.shape[3]
+    g = int(heads_per_row)
+    Hkv, ps = kpool.shape[1] * g, kpool.shape[3]
+    if kpool.shape[-1] != g * D:
+        raise ValueError(f"a pool of {g} kv head(s) a row holds rows of "
+                         f"{g} x {D} lanes, got {kpool.shape[-1]}")
     if H % Hkv:
         raise ValueError(f"query heads ({H}) must be a multiple of pool "
                          f"kv heads ({Hkv})")
@@ -603,7 +718,7 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
         if quantized:
             raise ValueError("the Pallas paged-attention kernel takes an "
                              "fp pool; int8 pools use the reference path")
-        if not kernel_tileable(ps, D):
+        if not kernel_tileable(ps, g * D):
             raise ValueError(
                 f"paged-attention kernel cannot tile page_size={ps}, "
                 f"head_dim={D}: the page size must be a multiple of 8 and "
@@ -617,12 +732,14 @@ def ragged_paged_attention(q, kpool, vpool, page_tables, ctx_lens,
             q, kpool, vpool, _layer_scalar(layer), page_tables, ctx_lens,
             start_pos, *work_list, window=window,
             scale=float(scale) if scale is not None else 1.0 / math.sqrt(D),
-            page_in_lanes=bool(page_in_lanes), interpret=interpret_mode())
+            page_in_lanes=bool(page_in_lanes), heads_per_row=g,
+            interpret=interpret_mode())
     return paged_attention_reference(
         q, kpool[layer], vpool[layer], page_tables, ctx_lens, start_pos,
         window=window, scale=scale,
         k_scales=None if k_scales is None else k_scales[layer],
-        v_scales=None if v_scales is None else v_scales[layer])
+        v_scales=None if v_scales is None else v_scales[layer],
+        heads_per_row=g)
 
 
 # ---------------------------------------------------------------------------

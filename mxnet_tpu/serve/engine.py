@@ -258,6 +258,7 @@ class InferenceEngine:
         self.quant_info = None
         self._step_fns = {}       # chunk width C -> jitted step
         self._execs = {}          # chunk width C -> AOT executable
+        self._write_bytes = {}    # chunk width C -> `kv_write_bytes(C)`
         #: disaggregation role ('prefill' | 'decode' | 'both') — read by
         #: the scheduler (handoff detach) and the fleet router
         self.role = sc.role
@@ -326,7 +327,7 @@ class InferenceEngine:
             max(self._step_widths()), bonus, self.quantized)
         self.pools = KVPools.create(
             self.groups, sc.page_size, self.n_kv_heads, self.head_dim,
-            dtype=self._kv_dtype)
+            dtype=self._kv_dtype, tp=self.tp)
         if self.tp > 1:
             self._tp_shard_pools()
         #: the whole-context group's free list: the one whose pages a
@@ -539,6 +540,7 @@ class InferenceEngine:
         quantized = self.quantized
         pool_names = self.pools.names
         page_in_lanes = self.pools.pages_in_lanes()
+        heads_per_row = self.pools.heads_per_row
         top_k, top_p = sc.top_k, sc.top_p
         max_pos = cfg.max_position
         spec_k = sc.spec_tokens
@@ -552,7 +554,8 @@ class InferenceEngine:
             kv_fn = make_paged_kv_fn(pools, dict(zip(group_names, tables)),
                                      start_pos, num_tokens, ctx_lens, ps,
                                      quantized, page_in_lanes=page_in_lanes,
-                                     layer_plan=layer_plan, walks=walks)
+                                     layer_plan=layer_plan, walks=walks,
+                                     heads_per_row=heads_per_row)
             # padded rows may run past the table; clamp for the embedding
             # gather only (writes are masked, attention rows are ignored)
             pos = jnp.minimum(start_pos[:, None] + jnp.arange(C)[None, :],
@@ -880,7 +883,8 @@ class InferenceEngine:
             _tele.event("compile_start", kind="serve_step", chunk=C)
         t0 = time.perf_counter()
         c_span = _trace.get_tracer("serve").span(
-            "serve.compile", chunk=C) if _trace.enabled() else None
+            "serve.compile", chunk=C, **self._fold_tags()) \
+            if _trace.enabled() else None
         try:
             with _health.suppress_stalls("serve_compile"):
                 ex = fn.lower(*avals).compile()
@@ -903,6 +907,34 @@ class InferenceEngine:
             f"serve_step_c{C}@{id(self):x}", compiled, kind="serve_step",
             chunk=C, source=source,
             quantized=self.quantized)
+
+    def _fold_tags(self) -> dict:
+        """``kv_heads_per_row_<group>``: the kv heads a pool row of each
+        cache group holds (`KVPools.heads_per_row`; 1 where not folded) —
+        on the ``serve.compile`` span and in `stats()`."""
+        return {"kv_heads_per_row_" + g.name:
+                self.pools.heads_per_row[g.name] for g in self.groups}
+
+    def kv_write_bytes(self, C: int) -> int:
+        """HBM bytes the K/V write kernels of a step at chunk width C
+        move, reckoned from their block shapes and grid
+        (`paged_kv_write_bytes`, one call a layer, every slot); 0 where
+        the step scatters instead.  The ``kv_write_bytes`` tag on
+        ``serve.step``: what tells a reader the fold engaged."""
+        got = self._write_bytes.get(C)
+        if got is None:
+            from ..ops.pallas.paged_attention import (paged_kernel_route,
+                                                      paged_kv_write_bytes)
+            got = 0
+            if paged_kernel_route(self.quantized):
+                lanes = self.pools.pages_in_lanes()
+                for g in self.groups:
+                    a = self.pools.arrays[g.pool_names[0]]
+                    got += len(g.layers) * paged_kv_write_bytes(
+                        a.shape, a.dtype, self.serve_config.max_slots, C,
+                        lanes)
+            self._write_bytes[C] = got
+        return got
 
     def cost_features(self) -> dict:
         """{chunk_width: XLA cost-feature vector} for every compiled
@@ -987,12 +1019,13 @@ class InferenceEngine:
     def export_pages(self, page_ids) -> dict:
         """Host copies of the listed physical pages, every pool array
         (K + V + scale planes): ``{name: ndarray[..., n_pages, ...]}``
-        with the page dim at axis 2.  The prefill side of a cross-
+        with the page dim at axis 2, one kv head a row whatever this
+        pool's fold (`KVPools.unfold`).  The prefill side of a cross-
         process handoff — the fleet ships these as binary wire blobs."""
         ids = onp.asarray(page_ids, onp.int32)
         with self._device_lock:
-            return {name: onp.asarray(
-                        jax.device_get(self.pools.arrays[name][:, :, ids]))
+            return {name: onp.asarray(jax.device_get(self.pools.unfold(
+                        name, self.pools.arrays[name][:, :, ids])))
                     for name in self.pools.full_names}
 
     def install_pages(self, page_ids, arrays: dict) -> None:
@@ -1010,8 +1043,8 @@ class InferenceEngine:
             arrs = self.pools.arrays
             for name in self.pools.full_names:
                 arrs[name] = self._install_fn(
-                    arrs[name], ids,
-                    jnp.asarray(arrays[name], arrs[name].dtype))
+                    arrs[name], ids, self.pools.fold(
+                        name, jnp.asarray(arrays[name], arrs[name].dtype)))
 
     # ------------------------------------------------------------------
     # public API (delegates to the scheduler)
@@ -1089,6 +1122,7 @@ class InferenceEngine:
             **{"free_pages_" + g.name: g.allocator.free_pages
                for g in self.groups[1:]},
             "kv_pages_released": self.scheduler.kv_pages_released,
+            **self._fold_tags(),
             "page_occupancy": round(self.allocator.occupancy(), 4),
             "pool_bytes": self.pools.nbytes(),
             "weight_bytes": self.weight_bytes(),

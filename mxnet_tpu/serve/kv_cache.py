@@ -4,7 +4,20 @@ The serving analogue of the reference's memory pool (`src/storage/`): all
 KV memory for all concurrent requests lives in ONE preallocated device pool
 of fixed-size pages, `(n_layers, Hkv, num_pages, page_size, D)` per tensor
 (head-major: one kv head's page is a contiguous `(page_size, D)` tile, the
-block the paged-attention kernel DMAs).
+block the paged-attention kernel DMAs).  **Where D is under the 128 lanes
+and divides them**, ``g = 128 / D`` kv heads share a page row instead:
+`(n_layers, Hkv / g, num_pages, page_size, 128)`, kv head ``j * g + t`` in
+lanes ``t * D ..`` of row group j (`kv_heads_per_row` decides from the
+shapes alone; `KVPools.heads_per_row` records g a group).  The bytes are
+the same; what changes is the device's layout: a v5e keeps an unfolded
+``(page_size, 64)`` page with its rows in lanes, so one new token is a
+lane column through the whole page and the K/V write moved the whole page
+for it, where a 128-lane row keeps the rows in sublanes and the write
+moves one 16-row tile (PERF.md section 6).  The page axis stays axis 2
+whatever the fold, so page copies, the prefix cache and the handoff's
+page export/install index pages alike; the handoff's payload is always
+unfolded (`KVPools.unfold`), so engines that fold differently (a tp split
+that would cut a row group) still exchange pages.
 A sequence owns an ordered list of physical pages (its *page table*);
 logical token position ``p`` lives in page ``table[p // page_size]`` at
 offset ``p % page_size``.  Admission, growth, and eviction are pure
@@ -48,7 +61,9 @@ windowed group has let the prompt's pages go.
 
 ``kv_dtype="int8"`` stores the pool quantized (symmetric per-token-per-head
 int8 via `contrib/quantization.quantize_kv`) at ~4x less HBM per token;
-attention dequantizes only the gathered context.
+attention dequantizes only the gathered context.  An int8 pool is never
+folded: its scale planes hold one scale a (kv head, token), and it takes
+the references, which read any layout.
 
 **Shared pages & copy-on-write** (docs/serving.md "Speculative decoding &
 prefix caching"): every allocated page carries a reference count.  A page
@@ -80,12 +95,28 @@ from ..base import MXNetError
 __all__ = ["PageAllocator", "PrefixIndex", "KVPools", "make_paged_kv_fn",
            "NULL_PAGE", "FULL", "CacheGroup", "PageRun", "plan_cache_groups",
            "window_first_page", "window_walk_pages", "live_page_range",
-           "live_page_items"]
+           "live_page_items", "kv_heads_per_row"]
 
 NULL_PAGE = 0
 #: the `LayerSpec.cache_group` label of the group that keeps a sequence's
 #: whole context; a layer under any other label lives in a windowed group
 FULL = "full"
+#: lanes of a TPU vreg: a pool row of exactly this many keeps its page's
+#: rows in sublanes in XLA:TPU's default layout
+LANES = 128
+
+
+def kv_heads_per_row(n_kv_heads: int, head_dim: int, tp: int = 1,
+                     quantized: bool = False) -> int:
+    """How many kv heads share a pool row: ``LANES / head_dim`` where the
+    head dim is under the lanes and divides them and the kv heads of one
+    tp shard divide by that (a row group is never cut by a shard); 1
+    otherwise — a head dim of 128 or more, one kv head a shard (MQA), an
+    int8 pool (its scale planes are a head's)."""
+    if quantized or head_dim >= LANES or LANES % head_dim:
+        return 1
+    g = LANES // head_dim
+    return g if (n_kv_heads // max(1, tp)) % g == 0 else 1
 
 
 def window_first_page(cursor, window: int, page_size: int):
@@ -670,45 +701,52 @@ class KVPools:
     array a cache group (`CacheGroup.pool_names`), plus scale planes when
     quantized:
 
-    - ``k``/``v``: (the whole-context group's layers, Hkv, its pages,
-      page_size, D) `dtype`
-    - ``k_scale``/``v_scale``: the same without D, float32 (int8 pools
-      only; one symmetric scale per stored vector)
-    - ``k_<name>``/``v_<name>``: (the group's layers, Hkv, the group's
-      pages, page_size, D), one pair a windowed group
+    - ``k``/``v``: (the whole-context group's layers, Hkv / g, its pages,
+      page_size, g * D) `dtype`, ``g = heads_per_row[FULL]``
+    - ``k_scale``/``v_scale``: (layers, Hkv, pages, page_size) float32
+      (int8 pools only, never folded; one symmetric scale per stored
+      vector)
+    - ``k_<name>``/``v_<name>``: (the group's layers, Hkv / g, the
+      group's pages, page_size, g * D), one pair a windowed group
 
-    The arrays are exposed as a flat tuple (`as_tuple`) so the engine can
+    ``heads_per_row``: the g of each group, by name (`kv_heads_per_row`,
+    from the shapes and the tp degree the pools are sharded over).  The
+    arrays are exposed as a flat tuple (`as_tuple`) so the engine can
     pass them through a jitted step with ``donate_argnums`` and rebind the
     donated outputs (`replace`).
     """
 
     def __init__(self, arrays: Dict[str, jax.Array],
                  groups: Tuple[CacheGroup, ...], page_size: int,
-                 n_kv_heads: int, head_dim: int, quantized: bool):
+                 n_kv_heads: int, head_dim: int, quantized: bool,
+                 heads_per_row: Dict[str, int]):
         self.arrays = arrays
         self.groups = groups
         self.page_size = page_size
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.quantized = quantized
+        self.heads_per_row = heads_per_row
 
     @classmethod
     def create(cls, groups: Sequence[CacheGroup], page_size: int,
                n_kv_heads: int, head_dim: int,
-               dtype="float32") -> "KVPools":
+               dtype="float32", tp: int = 1) -> "KVPools":
         quantized = str(dtype) == "int8"
         store_dt = jnp.int8 if quantized else jnp.dtype(dtype)
-        arrays = {}
+        arrays, fold = {}, {}
         for g in groups:
-            shape = (len(g.layers), n_kv_heads, g.num_pages, page_size,
-                     head_dim)
+            fold[g.name] = n = kv_heads_per_row(n_kv_heads, head_dim, tp,
+                                                quantized)
+            shape = (len(g.layers), n_kv_heads // n, g.num_pages, page_size,
+                     n * head_dim)
             for name in g.pool_names:
                 arrays[name] = jnp.zeros(shape, store_dt)
                 if quantized:
                     arrays[name + "_scale"] = jnp.zeros(shape[:-1],
                                                         jnp.float32)
         return cls(arrays, tuple(groups), page_size, n_kv_heads, head_dim,
-                   quantized)
+                   quantized, fold)
 
     @property
     def num_pages(self) -> int:
@@ -726,7 +764,7 @@ class KVPools:
         """Rebind to the donated step outputs (same metadata)."""
         return KVPools(dict(zip(self.names, values)), self.groups,
                        self.page_size, self.n_kv_heads, self.head_dim,
-                       self.quantized)
+                       self.quantized, self.heads_per_row)
 
     @property
     def full_names(self):
@@ -734,6 +772,24 @@ class KVPools:
         allocator indexes (page copies, handoff export/install)."""
         rest = {n for g in self.groups[1:] for n in g.pool_names}
         return tuple(n for n in self.names if n not in rest)
+
+    def _folded(self, name: str) -> int:
+        """g of array `name` (1 for a scale plane, never folded)."""
+        for g in self.groups:
+            if name in g.pool_names:
+                return self.heads_per_row[g.name]
+        return 1
+
+    def unfold(self, name: str, x):
+        """Pages of array `name` (page axis 2) as one kv head a row,
+        whatever this engine's fold: the handoff's payload."""
+        from ..ops.pallas.paged_attention import unfold_heads
+        return unfold_heads(x, self._folded(name))
+
+    def fold(self, name: str, x):
+        """`unfold`'s payload in this engine's layout of array `name`."""
+        from ..ops.pallas.paged_attention import fold_heads
+        return fold_heads(x, self._folded(name))
 
     def pages_in_lanes(self) -> bool:
         """Does the device keep a page's rows in lanes (a (D, page_size)
@@ -749,7 +805,8 @@ class KVPools:
 
 def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
                      num_tokens, ctx_lens, page_size: int, quantized: bool,
-                     page_in_lanes: bool = False, *, layer_plan, walks):
+                     page_in_lanes: bool = False, *, layer_plan, walks,
+                     heads_per_row: Optional[Dict[str, int]] = None):
     """Build the `kv_fn` closure `transformer_step` calls per layer inside
     the jitted serving step: write the chunk's new K/V into the paged
     pool, then attend over each slot's pages via
@@ -784,12 +841,14 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
     the attention walks a work list of live (slot, page) pairs
     (`live_page_items`), built here once a (group, window) and shared by
     its layers: at most ``walks[group name]`` pages a slot
-    (`CacheGroup.walk`).
+    (`CacheGroup.walk`).  `heads_per_row`: `KVPools.heads_per_row`, the
+    kv heads a pool row of each group holds (1 where not given).
     """
     from ..ops.pallas.paged_attention import (
         paged_kernel_route, paged_kv_write, ragged_paged_attention)
 
     kernel = paged_kernel_route(quantized)
+    fold = heads_per_row or {}
     # one work list a cache group (and window): its layers share both
     work_lists = {}
 
@@ -797,16 +856,18 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
         group, gi, win = layer_plan[li]
         kn, vn = _pool_names(group)
         table = tables[group]
+        g = fold.get(group, 1)
         with jax.named_scope("mx.serve.pool_write"):
             if kernel:
                 pools[kn], pools[vn] = paged_kv_write(
                     pools[kn], pools[vn], k_new, v_new, gi, table,
                     start_pos, num_tokens, null_page=NULL_PAGE,
-                    page_in_lanes=page_in_lanes)
+                    page_in_lanes=page_in_lanes, heads_per_row=g)
             else:
                 scatter_kv_write(pools, gi, k_new, v_new, table,
                                  start_pos, num_tokens, page_size,
-                                 quantized, names=(kn, vn))
+                                 quantized, names=(kn, vn),
+                                 heads_per_row=g)
         with jax.named_scope("mx.serve.paged_attn"):
             if kernel and (group, win) not in work_lists:
                 work_lists[group, win] = live_page_items(
@@ -814,7 +875,7 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
             return ragged_paged_attention(
                 q, pools[kn], pools[vn], table, ctx_lens,
                 start_pos, window=win, layer=gi,
-                page_in_lanes=page_in_lanes,
+                page_in_lanes=page_in_lanes, heads_per_row=g,
                 k_scales=pools["k_scale"] if quantized else None,
                 v_scales=pools["v_scale"] if quantized else None,
                 work_list=work_lists.get((group, win)))
@@ -824,13 +885,19 @@ def make_paged_kv_fn(pools: Dict[str, jax.Array], tables, start_pos,
 
 def scatter_kv_write(pools: Dict[str, jax.Array], li, k_new, v_new,
                      page_tables, start_pos, num_tokens, page_size: int,
-                     quantized: bool, names=("k", "v")) -> None:
-    """The XLA-scatter form of the K/V write (updates `pools` in place):
+                     quantized: bool, names=("k", "v"),
+                     heads_per_row: int = 1) -> None:
+    """The XLA-scatter form of the K/V write (updates `pools` in place; a
+    folded pool, ``heads_per_row = g``, takes the new rows folded the same
+    way, one ``g * D`` row a (token, row group)):
     the route of int8 pools and of every backend without the kernels, and
     the oracle `paged_kv_write` is tested against.  Next to a Mosaic
     custom call XLA:TPU relayouts the whole pool around a scatter of any
     form (my chip run, PR 21), which is why the kernel route has none."""
+    from ..ops.pallas.paged_attention import fold_heads
     ps = page_size
+    k_new = fold_heads(k_new, heads_per_row)
+    v_new = fold_heads(v_new, heads_per_row)
     B, Hkv, C, D = k_new.shape
     pos = start_pos[:, None] + jnp.arange(C)[None, :]      # (B, C)
     logical = jnp.minimum(pos // ps, page_tables.shape[1] - 1)

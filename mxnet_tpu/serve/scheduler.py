@@ -925,6 +925,7 @@ class ContinuousBatchingScheduler:
             kv_counts = {
                 tag: sum(len(s.runs[gi].pages) for s in actives)
                 for gi, tag in enumerate(self._kv_tags)}
+            kv_counts["kv_write_bytes"] = self.engine.kv_write_bytes(C)
             if _trace.capturing():
                 kv_counts.update(self._attn_items(arrays[2], arrays[4]))
 

@@ -818,14 +818,15 @@ def test_live_page_items_match_an_enumeration_of_random_batches(seed, window):
 @pytest.mark.parametrize("C", [1, 16])
 @pytest.mark.parametrize("widths,page_in_lanes", [
     ("gpt2", False), ("gpt2", True), ("afmoe_full", False),
-    ("afmoe_sliding", False)], ids=[
+    ("afmoe_sliding", False), ("gpt2_folded", False)], ids=[
         "gpt2-rows_in_sublanes", "gpt2-rows_in_lanes", "afmoe_full",
-        "afmoe_sliding"])
+        "afmoe_sliding", "gpt2-folded"])
 def test_paged_attention_kernel_matches_the_reference_at_published_widths(
         widths, page_in_lanes, C):
     """The exact kernel code (interpret mode) at the widths the benchmark
     serves, pages of 128: GPT-2-small's 12 heads of 64 over a table of 3,
-    in both page orientations (a v5e keeps that pool's rows in lanes);
+    in both page orientations of the unfolded pool, and folded two kv
+    heads a 128-lane row as the engine keeps it (`kv_heads_per_row`);
     `afmoe`'s 48 query / 8 kv heads of 128 over a table of 36, the full
     layer, and the sliding one under its window of 4095 with every
     released page of the table the null page and a work list of
@@ -837,7 +838,8 @@ def test_paged_attention_kernel_matches_the_reference_at_published_widths(
                                           window_first_page,
                                           window_walk_pages)
     ps, window = 128, None
-    if widths == "gpt2":
+    g = 2 if widths == "gpt2_folded" else 1
+    if widths.startswith("gpt2"):
         H, Hkv, D, maxp = 12, 12, 64, 3
         ctx = onp.asarray([0, 131, 384, 77])
     else:
@@ -867,13 +869,149 @@ def test_paged_attention_kernel_matches_the_reference_at_published_widths(
         assert walk == 34 < maxp
         work_list = live_page_items(ctx_d, start_d, window, ps, walk)
     got = pa.ragged_paged_attention(
-        q, kp, vp, jnp.asarray(table), ctx_d, start_d, window=window,
-        use_kernel=True, layer=1, page_in_lanes=page_in_lanes,
-        work_list=work_list)
+        q, pa.fold_heads(kp, g), pa.fold_heads(vp, g), jnp.asarray(table),
+        ctx_d, start_d, window=window, use_kernel=True, layer=1,
+        page_in_lanes=page_in_lanes, work_list=work_list, heads_per_row=g)
     assert not onp.asarray(got[0]).any()
     for b in range(1, B):
         onp.testing.assert_allclose(got[b, :, :nt[b]], want[b, :, :nt[b]],
                                     rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# folded pools: 128 / D kv heads side by side in the lanes of a page row
+# ---------------------------------------------------------------------------
+
+def _bits(x):
+    return onp.asarray(jax.lax.bitcast_convert_type(
+        x, jnp.uint16 if x.dtype == jnp.bfloat16 else jnp.uint32))
+
+
+@pytest.mark.parametrize("window", [None, 11], ids=["full", "sliding"])
+@pytest.mark.parametrize("rep", [1, 3], ids=["mha", "gqa3"])
+@pytest.mark.parametrize("C", [1, 16])
+@pytest.mark.parametrize("D", [64, 32], ids=["g2", "g4"])
+def test_folded_pool_write_then_attend_matches_the_references(D, C, rep,
+                                                              window):
+    """A bf16 pool folded ``g = 128 / D`` kv heads a row (g = 2 and 4):
+    `paged_kv_write` (interpret mode: the exact kernel code, 16-row tiles
+    of every row group) leaves it as the scatter route leaves it, bit for
+    bit, and as the scatter leaves the unfolded pool, folded; every row no
+    chunk row lands on, the null page's among them, is unchanged.  Then
+    `ragged_paged_attention` over the written pool equals the gather
+    reference over the unfolded one: C = 1 and C = 16 (a chunk straddling
+    two pages, one deep in a page), an idle slot that touches the null
+    page alone and comes out as exact zeros, three query heads a kv head,
+    a window with the released pages of the table the null page."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    from mxnet_tpu.serve.kv_cache import (NULL_PAGE, live_page_items,
+                                          scatter_kv_write,
+                                          window_first_page,
+                                          window_walk_pages)
+    g = 128 // D
+    L, li, Hkv, ps, maxp = 2, 1, 2 * g, 32, 3
+    rng = onp.random.RandomState(D + 3 * C + rep)
+    start = onp.asarray([0, ps - 5, 41, 0, 2 * ps + 3])
+    nt = onp.minimum([C, C, max(C - 3, 1), 0, C], C)
+    B = len(start)
+    npages = B * maxp + 2                   # the null page + one unowned
+    flat = {n: jnp.asarray(rng.standard_normal((L, Hkv, npages, ps, D)),
+                           jnp.bfloat16) for n in "kv"}
+    table = onp.asarray(1 + rng.permutation(B * maxp).reshape(B, maxp),
+                        onp.int32)
+    if window is not None:
+        for b in range(B):
+            table[b, :int(window_first_page(int(start[b]), window, ps))] = \
+                NULL_PAGE
+        assert table[4, 0] == NULL_PAGE             # released by then
+    kn, vn = (jnp.asarray(rng.standard_normal((B, Hkv, C, D)), jnp.float32)
+              for _ in "kv")
+    pt, st, n_t = (jnp.asarray(x, jnp.int32) for x in (table, start, nt))
+
+    folded = {n: pa.fold_heads(flat[n], g) for n in "kv"}
+    assert folded["k"].shape == (L, Hkv // g, npages, ps, 128)
+    assert (pa.unfold_heads(folded["k"], g) == flat["k"]).all()
+    want = dict(folded)
+    scatter_kv_write(want, li, kn, vn, pt, st, n_t, ps, False,
+                     heads_per_row=g)
+    scatter_kv_write(flat, li, kn, vn, pt, st, n_t, ps, False)
+    got = dict(zip("kv", jax.jit(
+        lambda k, v: pa.paged_kv_write(k, v, kn, vn, li, pt, st, n_t,
+                                       null_page=NULL_PAGE,
+                                       heads_per_row=g))(
+        folded["k"], folded["v"])))
+    written = onp.zeros((npages, ps), bool)
+    for b in range(B):
+        for p in range(start[b], start[b] + nt[b]):
+            written[table[b, min(p // ps, maxp - 1)], p % ps] = True
+    assert written[NULL_PAGE].sum() == 0
+    real = onp.arange(npages) != NULL_PAGE
+    for n in "kv":
+        gb, ob = _bits(got[n]), _bits(folded[n])
+        onp.testing.assert_array_equal(gb[:, :, real],
+                                       _bits(want[n])[:, :, real])
+        onp.testing.assert_array_equal(
+            gb[:, :, real], _bits(pa.fold_heads(flat[n], g))[:, :, real])
+        onp.testing.assert_array_equal(gb[li][:, ~written],
+                                       ob[li][:, ~written])
+        onp.testing.assert_array_equal(gb[1 - li], ob[1 - li])
+        assert (gb[li][:, written] != ob[li][:, written]).all(-1).any()
+
+    q = jnp.asarray(rng.standard_normal((B, Hkv * rep, C, D)), jnp.float32)
+    ctx = st + n_t
+    ref = pa.paged_attention_reference(
+        q, flat["k"][li].astype(jnp.float32),
+        flat["v"][li].astype(jnp.float32), pt, ctx, st, window=window)
+    k32, v32 = (got[n].astype(jnp.float32) for n in "kv")
+    onp.testing.assert_array_equal(pa.paged_attention_reference(
+        q, k32[li], v32[li], pt, ctx, st, window=window, heads_per_row=g),
+        ref)
+    work_list = None if window is None else live_page_items(
+        ctx, st, window, ps, window_walk_pages(window, 16, ps))
+    out = jax.jit(lambda q, k, v: pa.ragged_paged_attention(
+        q, k, v, pt, ctx, st, window=window, use_kernel=True, layer=li,
+        work_list=work_list, heads_per_row=g))(q, k32, v32)
+    assert out.shape == q.shape
+    for b in range(B):
+        if nt[b] == 0:
+            assert not onp.asarray(out[b]).any()
+        onp.testing.assert_allclose(out[b, :, :nt[b]], ref[b, :, :nt[b]],
+                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_kv,D,tp,kv_dtype,g", [
+    (12, 64, 1, "bfloat16", 2), (8, 32, 1, "bfloat16", 4),
+    (8, 128, 1, "bfloat16", 1), (3, 64, 1, "bfloat16", 1),
+    (1, 64, 1, "bfloat16", 1), (4, 64, 2, "float32", 2),
+    (2, 64, 2, "float32", 1), (12, 48, 1, "bfloat16", 1),
+    (12, 64, 1, "int8", 1)], ids=[
+        "gpt2", "d32", "d128", "odd_kv_heads", "mqa", "tp2_whole_rows",
+        "tp2_cuts_a_row", "d48", "int8"])
+def test_kv_heads_per_row_folds_whole_row_groups_only(n_kv, D, tp, kv_dtype,
+                                                      g):
+    """The fold follows from the shapes alone: ``128 / D`` kv heads a row
+    where D is under 128 and divides it and the kv heads of a tp shard
+    divide by that; one head a row for D = 128, for a count of kv heads
+    (an odd one, MQA's one, one a shard under tp = 2) that would cut a
+    row, for a D that does not divide 128, and for an int8 pool, whose
+    scale planes stay one a head.  `KVPools` records g a group, shapes
+    the arrays by it, and its `unfold` / `fold` carry a page payload to
+    one head a row and back."""
+    from mxnet_tpu.serve.kv_cache import (CacheGroup, KVPools,
+                                          PageAllocator, kv_heads_per_row)
+    assert kv_heads_per_row(n_kv, D, tp, kv_dtype == "int8") == g
+    pools = KVPools.create(
+        [CacheGroup("full", (0, 1), None, 5, 4, PageAllocator(5, 8))],
+        8, n_kv, D, dtype=kv_dtype, tp=tp)
+    assert pools.heads_per_row == {"full": g}
+    assert pools.arrays["k"].shape == (2, n_kv // g, 5, 8, g * D)
+    if kv_dtype == "int8":
+        assert pools.arrays["k_scale"].shape == (2, n_kv, 5, 8)
+        assert pools.unfold("k_scale", pools.arrays["k_scale"]) is \
+            pools.arrays["k_scale"]
+    x = _rand((2, n_kv, 3, 8, D))
+    assert pools.fold("k", x).shape == (2, n_kv // g, 3, 8, g * D)
+    assert (pools.unfold("v", pools.fold("v", x)) == x).all()
 
 
 def test_pages_in_lanes_reads_the_arrays_own_layout():

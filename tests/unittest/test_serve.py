@@ -356,6 +356,176 @@ def test_engine_kernel_route_streams_equal_reference_route(page_in_lanes,
     assert len(writes) >= 2 and set(writes) == {page_in_lanes}
 
 
+# a head dim under 128 lanes that divides them: 128 / D kv heads a pool row
+_FOLDED = {"g2": dict(hidden_size=128, num_heads=2),      # D = 64
+           "g4": dict(hidden_size=128, num_heads=4)}      # D = 32
+
+
+@pytest.mark.parametrize("fold", sorted(_FOLDED))
+def test_folded_pool_engine_streams_equal_generate_on_both_routes(
+        fold, monkeypatch):
+    """A model whose kv heads fold (`kv_cache.kv_heads_per_row`: g = 2
+    for D = 64, 4 for D = 32) serves from a `(layers, Hkv / g, pages,
+    page_size, 128)` pool on the reference route (XLA scatter + gather,
+    which read the fold) and on the kernel route (interpret mode: the
+    write and the attention with ``heads_per_row = g``), and both stream
+    what unbatched `generate` does: prefill chunks that straddle pages,
+    decode across a page boundary, an idle slot."""
+    from mxnet_tpu.ops.pallas import paged_attention as pa
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    m = _tiny_model(**_FOLDED[fold])
+    g = int(fold[1])
+    rng = onp.random.RandomState(6)
+    prompts = [rng.randint(0, 96, n).tolist() for n in (3, 21, 6)]
+    want = [_ref_generate(m, p, 14) for p in prompts]
+    sc = ServeConfig(max_slots=4, page_size=16, prefill_chunk=5, max_len=48)
+
+    def streams():
+        eng = InferenceEngine(m, sc)
+        assert eng.pools.heads_per_row == {"full": g}
+        assert eng.pools.arrays["k"].shape == (2, m.cfg.num_heads // g,
+                                               eng.pools.num_pages, 16, 128)
+        assert eng.stats()["kv_heads_per_row_full"] == g
+        hs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        eng.run_until_idle()
+        return [h.result(timeout=0) for h in hs]
+
+    assert streams() == want
+    folds = []
+    kernel_write = pa.paged_kv_write
+
+    def counted(*a, **kw):
+        folds.append(kw["heads_per_row"])
+        return kernel_write(*a, **kw)
+
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(pa, "paged_kv_write", counted)
+    assert streams() == want
+    assert len(folds) >= 2 and set(folds) == {g}
+
+
+def test_folded_pool_copy_page_and_page_export_install_round_trip():
+    """On a pool folded two kv heads a row the page axis is still axis 2:
+    `copy_page` copies one page of every layer and nothing else;
+    `export_pages` hands over one kv head a row (the payload a handoff
+    ships, whatever either side's fold), the pool's own pages unfolded;
+    `install_pages` of that payload into another engine lands the same
+    bytes, which export again unchanged."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import unfold_heads
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    m = _tiny_model(**_FOLDED["g2"])
+    sc = ServeConfig(max_slots=2, page_size=16, max_len=48)
+    e1, e2 = InferenceEngine(m, sc), InferenceEngine(m, sc)
+    rng = onp.random.RandomState(2)
+    for name, a in e1.pools.arrays.items():
+        e1.pools.arrays[name] = jnp.asarray(rng.standard_normal(a.shape),
+                                            a.dtype)
+    before = {n: onp.asarray(a) for n, a in e1.pools.arrays.items()}
+    e1.copy_page(3, 5)
+    for n, a in e1.pools.arrays.items():
+        a = onp.asarray(a)
+        onp.testing.assert_array_equal(a[:, :, 5], before[n][:, :, 3])
+        others = onp.arange(a.shape[2]) != 5
+        onp.testing.assert_array_equal(a[:, :, others],
+                                       before[n][:, :, others])
+    out = e1.export_pages([2, 5])
+    assert set(out) == {"k", "v"}
+    for n, x in out.items():
+        assert x.shape == (2, 2, 2, 16, 64)
+        onp.testing.assert_array_equal(x, onp.asarray(unfold_heads(
+            jax.device_get(e1.pools.arrays[n][:, :, jnp.asarray([2, 5])]),
+            2)))
+    e2.install_pages([4, 1], out)
+    for n in out:
+        onp.testing.assert_array_equal(
+            onp.asarray(e2.pools.arrays[n])[:, :, [4, 1]],
+            onp.asarray(e1.pools.arrays[n])[:, :, [2, 5]])
+    back = e2.export_pages([4, 1])
+    for n in out:
+        onp.testing.assert_array_equal(back[n], out[n])
+
+
+@pytest.mark.parametrize("fold,g_tp2", [("g2", 2), ("g4", 1)],
+                         ids=["both_fold", "tp2_cuts_a_row"])
+def test_tp_sharded_folded_pool_engine_bit_identical(fold, g_tp2):
+    """tp = 2 over a pool that folds: with 2 kv heads of 64 a shard the
+    shards keep whole rows (g = 2 on both engines); with 2 kv heads of 32
+    a shard a row of four would be cut, so the tp = 2 engine keeps one
+    head a row while tp = 1 folds four.  Greedy streams stay
+    bit-identical to tp = 1 either way, and a page payload exported by
+    one engine installs into the other."""
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    kw = dict(_FOLDED[fold], num_heads=4, hidden_size=256 if g_tp2 == 2
+              else 128)
+    m = _tiny_model(**kw)
+    prompts = [[1, 2, 3, 4, 5], [7, 8, 9], [10, 20, 30, 40]]
+    sc = dict(max_slots=2, page_size=16, max_len=48)
+    e1 = InferenceEngine(m, ServeConfig(**sc, tp=1), seed=0)
+    e2 = InferenceEngine(m, ServeConfig(**sc, tp=2), seed=0)
+    assert e2.tp == 2
+    assert e1.pools.heads_per_row == {"full": 128 * 4 // kw["hidden_size"]}
+    assert e2.pools.heads_per_row == {"full": g_tp2}
+    for p in prompts:
+        assert e2.generate(p, 10, greedy=True) == \
+            e1.generate(p, 10, greedy=True)
+    # pages 1 and 2 hold what e1's streams wrote: hand them over
+    pages = [1, 2]
+    out = e1.export_pages(pages)
+    e2.install_pages(pages, out)
+    back = e2.export_pages(pages)
+    for n in out:
+        onp.testing.assert_array_equal(back[n], out[n])
+
+
+def test_kv_write_bytes_counts_the_write_kernels_blocks(monkeypatch):
+    """The ``kv_write_bytes`` tag on every traced ``serve.step``, and
+    ``kv_heads_per_row_full`` on ``serve.compile``: on the kernel route
+    the bytes `paged_kv_write`'s grid moves, reckoned here from the block
+    shapes of a folded f32 pool (8-row tiles, 128 lanes, one row group):
+    layers x slots x (tiles x (K, V) x (in, out) x block + K and V's new
+    rows); on the reference route, which scatters, 0."""
+    from mxnet_tpu import tracing
+    from mxnet_tpu.serve import InferenceEngine, ServeConfig
+    m = _tiny_model(**_FOLDED["g2"])
+    sc = ServeConfig(max_slots=3, page_size=16, prefill_chunk=4, max_len=48)
+
+    def tags():
+        tracing.reset()
+        tracing.enable()
+        try:
+            eng = InferenceEngine(m, sc)
+            eng.warmup()
+            for p in ([1, 2, 3, 4, 5, 6, 7], [9, 8]):
+                eng.submit(p, max_new_tokens=3)
+            eng.run_until_idle()
+            spans = tracing.get_tracer("serve").spans()
+        finally:
+            tracing.disable()
+            tracing.reset()
+        compiles = [s.tags for s in spans if s.name == "serve.compile"]
+        assert compiles and all(t["kv_heads_per_row_full"] == 2
+                                for t in compiles)
+        return {s.tags["chunk"]: s.tags["kv_write_bytes"]
+                for s in spans if s.name == "serve.step"}
+
+    assert tags() == {1: 0, 4: 0}
+    monkeypatch.setenv("MXTPU_PALLAS", "kernel")
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    layers, slots, rows, lanes, tile, f32 = 2, 3, 1, 128, 8, 4
+
+    def reckoned(C):
+        tiles = (C + tile - 2) // tile + 1
+        block = rows * tile * lanes * f32
+        new = rows * C * lanes * f32
+        return layers * slots * (tiles * 2 * 2 * block + 2 * new)
+
+    assert tags() == {1: reckoned(1), 4: reckoned(4)}
+    assert reckoned(1) == 2 * 3 * (16384 + 1024)
+
+
 def test_scheduler_admit_fifo_and_evict_youngest():
     """Admission is FIFO; page pressure evicts the YOUNGEST-admitted
     active (recompute preemption), which re-queues at the front and

@@ -287,22 +287,33 @@ def test_paged_kv_write_kernel_lowers_for_tpu_at_gpt2_shapes(dtype, C,
 
 def _gpt2_width_engine(monkeypatch, page_in_lanes, num_layers=1):
     """An engine at GPT-2-small widths (one layer unless told), traced as
-    a TPU would trace it (the dispatch consults `jax.default_backend`)
-    with the pool in the given orientation.  Depth and vocab are cut:
-    neither shapes the pool's calls."""
+    a TPU would trace it (the dispatch consults `jax.default_backend`).
+    Rows in sublanes: GPT-2's own 12 kv heads of 64, folded two a 128-lane
+    row (`kv_heads_per_row`), which a v5e keeps row-major.  Rows in
+    lanes: a pool the engine cannot fold (3 kv heads of 64: a row of two
+    would be cut), in the orientation a v5e keeps it.  Depth and vocab
+    are cut: neither shapes the pool's calls."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.gpt import GPTConfig, GPTForCausalLM
     from mxnet_tpu.serve import InferenceEngine, ServeConfig
     from mxnet_tpu.serve.kv_cache import KVPools
     cfg = GPTConfig(dtype="bfloat16", dropout=0.0, num_layers=num_layers,
-                    vocab_size=1024)
+                    vocab_size=1024,
+                    num_kv_heads=3 if page_in_lanes else None)
     model = GPTForCausalLM(cfg)
     model.initialize()
     model(mx.np.array([[1, 2]], dtype="int32"))    # eager init on the cpu
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(KVPools, "pages_in_lanes",
                         lambda self: page_in_lanes)
-    return InferenceEngine(model, ServeConfig(max_len=1024))
+    sc = ServeConfig(max_len=1024)
+    if page_in_lanes:       # four times the pages: 12 kv heads' bytes
+        sc = ServeConfig(max_len=1024, num_pages=4 * (
+            sc.max_slots * 1024 // sc.page_size + 1))
+    eng = InferenceEngine(model, sc)
+    assert eng.pools.arrays["k"].shape[1::3] == (
+        (3, 64) if page_in_lanes else (6, 128))
+    return eng
 
 
 @pytest.mark.parametrize("page_in_lanes", [False, True],
@@ -323,8 +334,10 @@ def test_gpt2_small_serve_step_lowers_with_paged_kernel(monkeypatch,
     n_layers = 1
     assert eng.serve_config.page_size == LANES
     pool = eng.pools.arrays["k"]
-    # tensor<1x12x65x128x64xbf16>, in either order of the last two dims
-    dims = "x".join(map(str, pool.shape[:3])) + "x(128x64|64x128)xbf16"
+    # tensor<1x6x65x128x128xbf16>, or 1x3x65 and the last two dims in
+    # either order
+    dims = "x".join(map(str, pool.shape[:3])) + "x(128x%d|%dx128)xbf16" % (
+        pool.shape[-1], pool.shape[-1])
     writes_pool = re.compile(
         r"stablehlo\.(scatter|dynamic_update_slice)[^\n]*tensor<" + dims)
     for C in eng._step_widths():
@@ -575,7 +588,9 @@ def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
     width holds no `copy` of a pool-sized array (the logical transposes
     around the calls are bitcasts) and its temporaries are a fraction of a
     pool.  Fed the other orientation the same pools cost a whole-pool copy
-    at each end of the step, which is what `pages_in_lanes` is for."""
+    at each end of the step, which is what `pages_in_lanes` is for.  The
+    pool here is one the engine cannot fold (3 kv heads of 64): a folded
+    one keeps its rows in sublanes."""
     import re
     from jax.experimental.layout import Format, Layout
     n_layers = 3
@@ -606,6 +621,71 @@ def test_compiled_serve_step_copies_no_pool_in_the_devices_layout(
         assert not pool_copy.search(txt), (C, pool_copy.search(txt))
         assert compiled.memory_analysis().temp_size_in_bytes \
             < pool.size * pool.dtype.itemsize // 4
+
+
+def test_compiled_folded_serve_step_copies_no_pool(monkeypatch, v5e_chip):
+    """GPT-2-small's pool folded two kv heads a 128-lane row, `(layers, 6,
+    pages, 128, 128)`, pinned row-major at both ends of the step (the
+    layout a v5e gives a minor dim of 128): the compiled module of every
+    width holds the two paged calls a layer, named for the metrics that
+    find them, and no `copy` of a pool-sized array."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    n_layers = 2
+    eng = _gpt2_width_engine(monkeypatch, False, n_layers)
+    pool = eng.pools.arrays["k"]
+    assert eng.pools.heads_per_row == {"full": 2}
+    pool_fmt = Format(Layout(major_to_minor=(0, 1, 2, 3, 4)), v5e_chip)
+
+    def described(x):
+        fmt = pool_fmt if x.shape == pool.shape else v5e_chip
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=fmt)
+
+    pool_copy = re.compile(
+        r"= bf16\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
+    for C in eng._step_widths():
+        fn = jax.jit(eng._step_fn(C).__wrapped__, donate_argnums=(1,),
+                     out_shardings=((pool_fmt, pool_fmt), v5e_chip,
+                                    v5e_chip))
+        avals = jax.tree_util.tree_map(described, eng._step_avals(C))
+        txt = fn.trace(*avals).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        assert txt.count("tpu_custom_call") == 2 * n_layers
+        for kernel in ("ragged_paged_attention", "paged_kv_write"):
+            assert len(set(re.findall(
+                r"%%(%s\.\d+|%s) = " % (kernel, kernel), txt))) == n_layers
+        assert not pool_copy.search(txt), (C, pool_copy.search(txt))
+
+
+@pytest.mark.parametrize("C", [1, 16])
+def test_folded_paged_kernels_compile_for_v5e_at_gpt2_widths(v5e_chip, C):
+    """Both paged kernels over GPT-2-small's folded pool (12 kv heads of
+    64, two a 128-lane row), 64 slots and a table of 8, compiled by Mosaic
+    and XLA:TPU for the described chip: the write's block is one 16-row
+    tile of the six row groups, the attention's one page of them."""
+    from mxnet_tpu.ops.pallas.paged_attention import (paged_kv_write,
+                                                      ragged_paged_attention)
+    H, D, B, maxp, ps = 12, 64, 64, 8, 128
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def f(q, kn, vn, kp, vp, pt, ctx, start, nt):
+        kp, vp = paged_kv_write(kp, vp, kn, vn, 1, pt, start, nt,
+                                heads_per_row=2)
+        return ragged_paged_attention(
+            q, kp, vp, pt, ctx, start, use_kernel=True, layer=1,
+            heads_per_row=2), kp, vp
+
+    pool = described((2, H // 2, 65, ps, 2 * D), jnp.bfloat16)
+    new = described((B, H, C, D), jnp.float32)
+    ints = described((B,), jnp.int32)
+    txt = jax.jit(f).trace(
+        described((B, H, C, D), jnp.float32), new, new, pool, pool,
+        described((B, maxp), jnp.int32), ints, ints, ints).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert txt.count("tpu_custom_call") == 2
+    assert "ragged_paged_attention" in txt and "paged_kv_write" in txt
 
 
 def test_gspmd_mesh_step_takes_references_and_shard_map_keeps_kernels(
